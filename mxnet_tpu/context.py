@@ -6,11 +6,13 @@ Reference: `include/mxnet/base.h:90-175` (`Context{dev_type, dev_id}`) and
 TPU-first design: a Context names a *logical* device `(dev_type, dev_id)` and
 resolves lazily to a `jax.Device`.  `mx.tpu(i)` is the accelerator context (the
 reference's `mx.gpu(i)` maps here — `gpu` is kept as an alias so reference
-scripts run unchanged).  When the requested platform is absent (e.g. tests run
-on a forced multi-device CPU host), a context transparently resolves onto the
-default platform's device list, which is exactly how the reference's tests map
-`ctx_group`s onto cpu(0)/cpu(1) to exercise multi-device code paths without a
-cluster (`tests/python/unittest/test_model_parallel.py:13-31`).
+scripts run unchanged).  FOR TESTS ONLY, a `tpu` context resolves onto the
+default backend's device list whatever its platform, so multi-device code
+paths run on a forced multi-device CPU host the way the reference's tests map
+`ctx_group`s onto cpu(0)/cpu(1)
+(`tests/python/unittest/test_model_parallel.py:13-31`).  Nothing here says
+which platform that was: code that needs the chip (`chip_smoke.py`,
+`bench.py`) checks `ctx.jax_device().platform` itself and fails otherwise.
 """
 from __future__ import annotations
 
@@ -61,9 +63,11 @@ class Context:
     def jax_device(self):
         """Resolve to a concrete `jax.Device`.
 
-        tpu -> accelerator devices of the default backend; cpu -> cpu backend.
-        Falls back to the default backend's devices when the requested platform
-        is unavailable so multi-device logic is testable on a host-only mesh.
+        tpu -> devices of the DEFAULT backend; cpu -> cpu backend.  The
+        default backend is the TPU wherever one is attached; without one
+        (the CPU test mesh) `mx.tpu(i)` is CPU device i.  That resolution
+        exists for tests: callers that must run on the chip check the
+        returned device's ``platform``.
         In a multi-process job, contexts address THIS process's devices
         (copying a host value onto another process's device is impossible —
         global placement happens through shardings, not contexts).

@@ -29,10 +29,15 @@ from __future__ import annotations
 
 import functools
 import math
+import os as _os
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ._spmd import call_local, out_struct
 
 _NEG_INF = -1e30
 
@@ -71,22 +76,9 @@ def _use_pallas(q, kv_len=None):
     return _stream_residency_fits(s, q.shape[-1], itemsize)
 
 
-try:  # pallas is TPU-only in some builds; import lazily and gate on backend
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-    # pre-rename jax spells CompilerParams "TPUCompilerParams"; a local
-    # alias covers both without mutating jax's namespace
-    _CompilerParams = getattr(pltpu, "CompilerParams", None) \
-        or pltpu.TPUCompilerParams
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
-
 # MXNET_PALLAS_INTERPRET=1 runs every pallas_call through the interpreter
 # so the CPU test mesh can execute the real kernel bodies (not just the
 # jnp fallbacks) — the CI answer to "a kernel regression ships green"
-import os as _os
-
 _INTERPRET = _os.environ.get("MXNET_PALLAS_INTERPRET", "0") == "1"
 
 
@@ -186,12 +178,13 @@ def _flash_fwd_pallas(q, k, v, q_off, k_off, scale, causal,
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, sq_p, d), q.dtype),
-            jax.ShapeDtypeStruct((b, h, sq_p, 128), jnp.float32),
+            out_struct((b, h, sq_p, d), q.dtype, qp, kp, vp, q_off, k_off),
+            out_struct((b, h, sq_p, 128), jnp.float32,
+                       qp, kp, vp, q_off, k_off),
         ],
         # every program is independent (the K loop is inside the kernel):
         # let Mosaic parallelize/pipeline freely across the whole grid
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",) * 3),
         cost_estimate=pl.CostEstimate(
             flops=4 * b * h * sq_p * skv_p * d,
@@ -422,8 +415,9 @@ def _flash_bwd_pallas(scale, causal, block_q, block_k, res, grads):
             out_specs=pl.BlockSpec((1, 1, block_q, d),
                                    lambda i, j, k_, qo, ko: (i, j, k_, 0)),
         ),
-        out_shape=jax.ShapeDtypeStruct((b, h, sq_p, d), q.dtype),
-        compiler_params=_CompilerParams(
+        out_shape=out_struct((b, h, sq_p, d), q.dtype,
+                             qp, kp, vp, dop, lsep, deltap, qo, ko),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",) * 3),
         cost_estimate=pl.CostEstimate(
             flops=6 * b * h * sq_p * skv_p * d,
@@ -461,10 +455,12 @@ def _flash_bwd_pallas(scale, causal, block_q, block_k, res, grads):
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, skv_p, d), k.dtype),
-            jax.ShapeDtypeStruct((b, h, skv_p, d), v.dtype),
+            out_struct((b, h, skv_p, d), k.dtype,
+                       qp, kp, vp, dop, lsep, deltap, qo, ko),
+            out_struct((b, h, skv_p, d), v.dtype,
+                       qp, kp, vp, dop, lsep, deltap, qo, ko),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",) * 3),
         cost_estimate=pl.CostEstimate(
             flops=8 * b * h * sq_p * skv_p * d,
@@ -664,10 +660,10 @@ def _flash_fwd_pallas_ds(q, k, v, q_off, k_off, scale, causal,
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, d, sq_p), q.dtype),
-            jax.ShapeDtypeStruct((b, h, 1, sq_p), jnp.float32),
+            out_struct((b, h, d, sq_p), q.dtype, qp, kp, vp, q_off, k_off),
+            out_struct((b, h, 1, sq_p), jnp.float32, qp, kp, vp, q_off, k_off),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         cost_estimate=pl.CostEstimate(
@@ -844,8 +840,9 @@ def _flash_bwd_pallas_ds(scale, causal, block_q, block_k, res, grads):
                                    (i, j, 0, k_)),
             scratch_shapes=[pltpu.VMEM((d, block_q), jnp.float32)],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, h, d, sq_p), q.dtype),
-        compiler_params=_CompilerParams(
+        out_shape=out_struct((b, h, d, sq_p), q.dtype,
+                             qp, kp, vp, dop, lsep, deltap, qo, ko),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         cost_estimate=pl.CostEstimate(
@@ -888,10 +885,12 @@ def _flash_bwd_pallas_ds(scale, causal, block_q, block_k, res, grads):
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, d, skv_p), k.dtype),
-            jax.ShapeDtypeStruct((b, h, d, skv_p), v.dtype),
+            out_struct((b, h, d, skv_p), k.dtype,
+                       qp, kp, vp, dop, lsep, deltap, qo, ko),
+            out_struct((b, h, d, skv_p), v.dtype,
+                       qp, kp, vp, dop, lsep, deltap, qo, ko),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         cost_estimate=pl.CostEstimate(
@@ -1025,10 +1024,11 @@ def _flash_fwd_pallas_bsd(q, k, v, q_off, k_off, scale, causal,
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((b, sq_p, e), q.dtype),
-            jax.ShapeDtypeStruct((b, num_heads, sq_p, 128), jnp.float32),
+            out_struct((b, sq_p, e), q.dtype, qp, kp, vp, q_off, k_off),
+            out_struct((b, num_heads, sq_p, 128), jnp.float32,
+                       qp, kp, vp, q_off, k_off),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",) * 3),
         cost_estimate=pl.CostEstimate(
             flops=4 * b * num_heads * sq_p * skv_p * d,
@@ -1208,8 +1208,9 @@ def _flash_bwd_pallas_bsd(scale, causal, block_q, block_k, num_heads,
             out_specs=pl.BlockSpec((1, block_q, d),
                                    lambda i, j, k_, qo, ko: (i, k_, j)),
         ),
-        out_shape=jax.ShapeDtypeStruct((b, sq_p, e), q.dtype),
-        compiler_params=_CompilerParams(
+        out_shape=out_struct((b, sq_p, e), q.dtype,
+                             qp, kp, vp, dop, lsep, deltap, qo, ko),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",) * 3),
         cost_estimate=pl.CostEstimate(
             flops=6 * b * num_heads * sq_p * skv_p * d,
@@ -1247,10 +1248,12 @@ def _flash_bwd_pallas_bsd(scale, causal, block_q, block_k, num_heads,
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((b, skv_p, e), k.dtype),
-            jax.ShapeDtypeStruct((b, skv_p, e), v.dtype),
+            out_struct((b, skv_p, e), k.dtype,
+                       qp, kp, vp, dop, lsep, deltap, qo, ko),
+            out_struct((b, skv_p, e), v.dtype,
+                       qp, kp, vp, dop, lsep, deltap, qo, ko),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",) * 3),
         cost_estimate=pl.CostEstimate(
             flops=8 * b * num_heads * sq_p * skv_p * d,
@@ -1383,10 +1386,11 @@ def _flash_fwd_pallas_bsd_gs(q, k, v, q_off, k_off, scale, causal,
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((b, sq_p, e), q.dtype),
-            jax.ShapeDtypeStruct((b, num_heads, sq_p, 128), jnp.float32),
+            out_struct((b, sq_p, e), q.dtype, qp, kp, vp, q_off, k_off),
+            out_struct((b, num_heads, sq_p, 128), jnp.float32,
+                       qp, kp, vp, q_off, k_off),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         cost_estimate=pl.CostEstimate(
@@ -1559,8 +1563,9 @@ def _flash_bwd_pallas_bsd_gs(scale, causal, block_q, block_k, num_heads,
                 (1, block_q, d), lambda i, j, k_, kb, qo, ko: (i, k_, j)),
             scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, sq_p, e), q.dtype),
-        compiler_params=_CompilerParams(
+        out_shape=out_struct((b, sq_p, e), q.dtype,
+                             qp, kp, vp, dop, lsep, deltap, qo, ko),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         cost_estimate=pl.CostEstimate(
@@ -1603,10 +1608,12 @@ def _flash_bwd_pallas_bsd_gs(scale, causal, block_q, block_k, num_heads,
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((b, skv_p, e), k.dtype),
-            jax.ShapeDtypeStruct((b, skv_p, e), v.dtype),
+            out_struct((b, skv_p, e), k.dtype,
+                       qp, kp, vp, dop, lsep, deltap, qo, ko),
+            out_struct((b, skv_p, e), v.dtype,
+                       qp, kp, vp, dop, lsep, deltap, qo, ko),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         cost_estimate=pl.CostEstimate(
@@ -1646,9 +1653,8 @@ def _bsd_eligible(q, num_heads):
         return False  # lane slicing must be tile-aligned
     if jax.default_backend() != "tpu" and not _INTERPRET:
         forced = _os.environ.get("MXNET_FLASH_IMPL")
-        if forced not in ("pallas_hsd", "pallas_ds", "pallas_bsd"):
-            return False
-    return _HAS_PALLAS
+        return forced in ("pallas_hsd", "pallas_ds", "pallas_bsd")
+    return True
 
 
 def _bsd_loop_fits_vmem(q, num_heads, kv_len):
@@ -1771,10 +1777,6 @@ def flash_attention_bsd(q, k, v, num_heads, *, causal=False, scale=None,
         # honor the pin with the same readable-failure contract as
         # _pick_impl: never silently hand a pinned A/B run to the jnp
         # fallback (that would mislabel recorded evidence)
-        if not _HAS_PALLAS:
-            raise RuntimeError(
-                "MXNET_FLASH_IMPL=pallas_bsd but jax.experimental.pallas "
-                "is unavailable in this build")
         if not _bsd_eligible(q, num_heads) \
                 or q.shape[1] * skv < 512 * 512:
             import warnings
@@ -1824,9 +1826,9 @@ def flash_attention_bsd(q, k, v, num_heads, *, causal=False, scale=None,
     block_q, block_k = _auto_blocks(block_q, block_k, impl)
     q_off = jnp.asarray(q_offset, jnp.float32)
     k_off = jnp.asarray(k_offset, jnp.float32)
-    out, lse = _flash_bsd(q, k, v, q_off, k_off, float(scale),
-                          bool(causal), int(block_q), int(block_k),
-                          int(num_heads), impl)
+    static = (float(scale), bool(causal), int(block_q), int(block_k),
+              int(num_heads), impl)
+    out, lse = _call_flash(_flash_bsd, (q, k, v, q_off, k_off), static)
     return (out, lse) if with_lse else out
 
 
@@ -1889,9 +1891,20 @@ def _flash_bwd_rule(scale, causal, block_q, block_k, impl, res, grads):
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
+def _call_flash(fn, operands, static):
+    """``fn(*operands, *static)`` with impl = ``static[-1]``; a Pallas impl
+    runs per device, q/k/v split with the batch and the position offsets
+    shared (see `_spmd.call_local`)."""
+    if not static[-1].startswith("pallas"):
+        return fn(*operands, *static)
+    return call_local(lambda *a: fn(*a, *static), operands,
+                      (True, True, True, False, False), (True, True),
+                      interpreted=_INTERPRET)
+
+
 def _pick_impl(q, kv_len):
     """Static kernel choice (trace-time).  Size gate from on-chip
-    measurement (scripts/diag_round3.py attnbwd): at S=1024 the Pallas
+    measurement (the round-3 attnbwd diagnostic): at S=1024 the Pallas
     backward beats the jnp scan 10x, but below ~512x512 the kernel
     launches + boundary copies cost more than the scan's few fused blocks
     (0.5 ms jnp vs 3.6 ms pallas at 512x384).  MXNET_FLASH_LAYOUT=ds
@@ -1902,11 +1915,6 @@ def _pick_impl(q, kv_len):
             # A pin bypasses the gates below; fail/warn readably instead of
             # erroring deep inside Mosaic on a non-TPU backend or an
             # over-VMEM-cap shape (round-4 advisor finding).
-            if not _HAS_PALLAS:
-                raise RuntimeError(
-                    "MXNET_FLASH_IMPL=%s but jax.experimental.pallas is "
-                    "unavailable in this build — unset the pin or use "
-                    "MXNET_FLASH_IMPL=jnp" % forced)
             if not _use_pallas(q, kv_len=kv_len):
                 import warnings
 
@@ -1918,7 +1926,7 @@ def _pick_impl(q, kv_len):
                     "lower or spill" % (forced, jax.default_backend(),
                                         q.shape[-1], kv_len))
         return forced
-    if not (_HAS_PALLAS and _use_pallas(q, kv_len=kv_len)):
+    if not _use_pallas(q, kv_len=kv_len):
         return "jnp"
     if q.shape[2] * kv_len < 512 * 512:
         return "jnp"
@@ -1980,6 +1988,6 @@ def flash_attention(q, k, v, *, causal=False, scale=None,
     block_q, block_k = _auto_blocks(block_q, block_k, impl)
     q_off = jnp.asarray(q_offset, jnp.float32)
     k_off = jnp.asarray(k_offset, jnp.float32)
-    out, lse = _flash(q, k, v, q_off, k_off, float(scale), bool(causal),
-                      int(block_q), int(block_k), impl)
+    static = (float(scale), bool(causal), int(block_q), int(block_k), impl)
+    out, lse = _call_flash(_flash, (q, k, v, q_off, k_off), static)
     return (out, lse) if with_lse else out

@@ -64,35 +64,25 @@ Everywhere else (CPU test meshes, tiny vocabs) the same math runs as a
 from __future__ import annotations
 
 import functools
+import os as _os
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ._spmd import call_local, out_struct, reduce_like, vary_alike
 
 _NEG_INF = -1e30
 
-
-try:  # pallas is TPU-only in some builds
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    # pre-rename jax spells CompilerParams "TPUCompilerParams"; a local
-    # alias covers both without mutating jax's namespace
-    _CompilerParams = getattr(pltpu, "CompilerParams", None) \
-        or pltpu.TPUCompilerParams
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
-
 # MXNET_PALLAS_INTERPRET=1: run kernels through the interpreter so CPU CI
 # executes the real kernel bodies (see flash_attention.py)
-import os as _os
-
 _INTERPRET = _os.environ.get("MXNET_PALLAS_INTERPRET", "0") == "1"
 
 
 def _use_pallas(x, w):
-    if not _HAS_PALLAS or (jax.default_backend() != "tpu"
-                            and not _INTERPRET):
+    if jax.default_backend() != "tpu" and not _INTERPRET:
         return False
     n, d = x.shape
     v = w.shape[0]
@@ -179,7 +169,7 @@ def _fwd_pallas(x, w, b, label, grad_scale, ignore_label, use_ignore,
     nll, lse = pl.pallas_call(
         kernel,
         grid=(num_j, num_i),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         in_specs=[
             pl.BlockSpec((block_n, d), lambda j, i: (i, 0)),
@@ -192,8 +182,8 @@ def _fwd_pallas(x, w, b, label, grad_scale, ignore_label, use_ignore,
             pl.BlockSpec((1, block_n), lambda j, i: (0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((1, np_), jnp.float32),
-            jax.ShapeDtypeStruct((1, np_), jnp.float32),
+            out_struct((1, np_), jnp.float32, xp, wp, bp, lblp),
+            out_struct((1, np_), jnp.float32, xp, wp, bp, lblp),
         ],
         scratch_shapes=[
             pltpu.VMEM((num_i, block_n), jnp.float32),
@@ -319,7 +309,7 @@ def _bwd_pallas(x, w, b, label, lse, grad_scale, ignore_label, use_ignore,
             pl.BlockSpec((1, block_n), lambda i, j: (0, i)),
         ],
         out_specs=pl.BlockSpec((block_n, d), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((np_, d), x.dtype),
+        out_shape=out_struct((np_, d), x.dtype, xp, wp, bp, lblp, lsep),
         scratch_shapes=[pltpu.VMEM((block_n, d), jnp.float32)],
         cost_estimate=pl.CostEstimate(
             flops=4 * np_ * vp_ * d,
@@ -345,8 +335,8 @@ def _bwd_pallas(x, w, b, label, lse, grad_scale, ignore_label, use_ignore,
             pl.BlockSpec((1, block_v), lambda j, i: (0, j)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((vp_, d), w.dtype),
-            jax.ShapeDtypeStruct((1, vp_), w.dtype),
+            out_struct((vp_, d), w.dtype, xp, wp, bp, lblp, lsep),
+            out_struct((1, vp_), w.dtype, xp, wp, bp, lblp, lsep),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_v, d), jnp.float32),
@@ -403,7 +393,8 @@ def _fwd_jnp(x, w, b, label, grad_scale, ignore_label, use_ignore, block_v):
             jnp.exp(s - m_new[:, None]), axis=1)
         return (m_new, l, a), None
 
-    # derive the carry from x so its type matches under shard_map
+    # derive the carry from x so its type matches under shard_map (the
+    # callers give every operand one type: `_spmd.vary_alike`)
     z = jnp.zeros_like(xf[:, 0])
     (m, l, a), _ = lax.scan(
         body, (z + _NEG_INF, z, z),
@@ -539,7 +530,7 @@ def _fwd_sp_pallas(x, w, b, label, block_n, block_v):
     lse, a, dxp = pl.pallas_call(
         kernel,
         grid=(num_i, num_j),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         in_specs=[
             pl.BlockSpec((block_n, d), lambda i, j: (i, 0)),
@@ -553,9 +544,9 @@ def _fwd_sp_pallas(x, w, b, label, block_n, block_v):
             pl.BlockSpec((block_n, d), lambda i, j: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((1, np_), jnp.float32),
-            jax.ShapeDtypeStruct((1, np_), jnp.float32),
-            jax.ShapeDtypeStruct((np_, d), jnp.float32),
+            out_struct((1, np_), jnp.float32, xp, wp, bp, lblp),
+            out_struct((1, np_), jnp.float32, xp, wp, bp, lblp),
+            out_struct((np_, d), jnp.float32, xp, wp, bp, lblp),
         ],
         scratch_shapes=[
             pltpu.VMEM((1, block_n), jnp.float32),
@@ -606,6 +597,7 @@ def _fwd_sp_jnp(x, w, b, label, block_v):
 
 
 def _fwd_sp_impl(x, w, b, label, block_n, block_v):
+    x, w, b, label = vary_alike(x, w, b, label)
     if _use_pallas(x, w):
         return _fwd_sp_pallas(x, w, b, label, block_n, block_v)
     return _fwd_sp_jnp(x, w, b, label, block_v)
@@ -721,8 +713,8 @@ def _bwd_dw_rs_pallas(x, w, b, label, lse, r, block_n, block_v):
             pl.BlockSpec((1, block_v), lambda j, i: (0, j)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((vp_, d), w.dtype),
-            jax.ShapeDtypeStruct((1, vp_), w.dtype),
+            out_struct((vp_, d), w.dtype, xp, wp, bp, lblp, lsep, rp),
+            out_struct((1, vp_), w.dtype, xp, wp, bp, lblp, lsep, rp),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_v, d), jnp.float32),
@@ -761,7 +753,7 @@ def _bwd_dx_rs_pallas(x, w, b, label, lse, r, block_n, block_v):
             pl.BlockSpec((1, block_n), lambda i, j: (0, i)),
         ],
         out_specs=pl.BlockSpec((block_n, d), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((np_, d), x.dtype),
+        out_shape=out_struct((np_, d), x.dtype, xp, wp, bp, lblp, lsep, rp),
         scratch_shapes=[pltpu.VMEM((block_n, d), jnp.float32)],
         cost_estimate=pl.CostEstimate(
             flops=4 * np_ * vp_ * d,
@@ -820,12 +812,14 @@ def _bwd_dx_rs_jnp(x, w, b, label, lse, r, block_v):
 
 
 def _bwd_dw_rs_impl(x, w, b, label, lse, r, block_n, block_v):
+    x, w, b, label, lse, r = vary_alike(x, w, b, label, lse, r)
     if _use_pallas(x, w):
         return _bwd_dw_rs_pallas(x, w, b, label, lse, r, block_n, block_v)
     return _bwd_dw_rs_jnp(x, w, b, label, lse, r, block_v)
 
 
 def _bwd_dx_rs_impl(x, w, b, label, lse, r, block_n, block_v):
+    x, w, b, label, lse, r = vary_alike(x, w, b, label, lse, r)
     if _use_pallas(x, w):
         return _bwd_dx_rs_pallas(x, w, b, label, lse, r, block_n, block_v)
     return _bwd_dx_rs_jnp(x, w, b, label, lse, r, block_v)
@@ -886,7 +880,8 @@ def _fused_ce_sp_bwd_rule(grad_scale, ignore_label, use_ignore, block_n,
     x, w, b, label, lse, r, dx = res
     lbl = label.astype(jnp.int32)
     dw, db = _bwd_dw_rs_impl(x, w, b, lbl, lse, r, block_n, block_v)
-    return dx, dw, db.astype(b.dtype), _label_zero_cot(label)
+    return (reduce_like(dx, x), reduce_like(dw, w),
+            reduce_like(db.astype(b.dtype), b), _label_zero_cot(label))
 
 
 _fused_ce_sp.defvjp(_fused_ce_sp_fwd_rule, _fused_ce_sp_bwd_rule)
@@ -969,7 +964,8 @@ def _fused_ce_vs_bwd_rule(axis, grad_scale, ignore_label, use_ignore,
         dx = lax.psum(
             _bwd_dx_rs_impl(x, w, b, lbl_loc, lse_g, r, block_n, block_v)
             .astype(jnp.float32), axis).astype(x.dtype)
-    return dx, dw, db.astype(b.dtype), _label_zero_cot(label)
+    return (reduce_like(dx, x), reduce_like(dw, w),
+            reduce_like(db.astype(b.dtype), b), _label_zero_cot(label))
 
 
 _fused_ce_vs.defvjp(_fused_ce_vs_fwd_rule, _fused_ce_vs_bwd_rule)
@@ -1014,7 +1010,7 @@ def _fused_ce(x, w, b, label, grad_scale, ignore_label, use_ignore,
 
 def _fused_ce_fwd_impl(x, w, b, label, grad_scale, ignore_label, use_ignore,
                        block_n, block_v):
-    lbl = label.astype(jnp.int32)
+    x, w, b, lbl = vary_alike(x, w, b, label.astype(jnp.int32))
     if _use_pallas(x, w):
         return _fwd_pallas(x, w, b, lbl, grad_scale, ignore_label,
                            use_ignore, block_n, block_v)
@@ -1034,13 +1030,13 @@ def _fused_ce_bwd_rule(grad_scale, ignore_label, use_ignore, block_n,
     # loss-head contract (`softmax_output-inl.h` Backward): the incoming
     # cotangent is ignored; grad_scale is baked into dl
     x, w, b, label, lse = res
-    lbl = label.astype(jnp.int32)
+    ops = vary_alike(x, w, b, label.astype(jnp.int32), lse)
     if _use_pallas(x, w):
-        dx, dw, db = _bwd_pallas(x, w, b, lbl, lse, grad_scale,
-                                 ignore_label, use_ignore, block_n, block_v)
+        dx, dw, db = _bwd_pallas(*ops, grad_scale, ignore_label,
+                                 use_ignore, block_n, block_v)
     else:
-        dx, dw, db = _bwd_jnp(x, w, b, lbl, lse, grad_scale, ignore_label,
-                              use_ignore, block_v)
+        dx, dw, db = _bwd_jnp(*ops, grad_scale, ignore_label, use_ignore,
+                              block_v)
     if jnp.issubdtype(label.dtype, jnp.integer):
         # integer primals take a float0 cotangent under jax.grad/vjp
         import numpy as _np
@@ -1050,7 +1046,8 @@ def _fused_ce_bwd_rule(grad_scale, ignore_label, use_ignore, block_n,
         dlabel = _np.zeros(label.shape, _dtypes.float0)
     else:
         dlabel = jnp.zeros_like(label)
-    return dx, dw, db.astype(b.dtype), dlabel
+    return (reduce_like(dx, x), reduce_like(dw, w),
+            reduce_like(db.astype(b.dtype), b), dlabel)
 
 
 _fused_ce.defvjp(_fused_ce_fwd_rule, _fused_ce_bwd_rule)
@@ -1087,6 +1084,13 @@ def fused_softmax_ce(x, weight, bias, label, *, grad_scale=1.0,
         # axes type matches under shard_map
         bias = weight[:, 0] * 0
     fn = _fused_ce_sp if single_pass_enabled() else _fused_ce
-    return fn(x, weight, bias, label, float(grad_scale),
-              float(ignore_label), bool(use_ignore), int(block_n),
-              int(block_v))
+    static = (float(grad_scale), float(ignore_label), bool(use_ignore),
+              int(block_n), int(block_v))
+    operands = (x, weight, bias, label)
+    if not _use_pallas(x, weight):
+        return fn(*operands, *static)
+    # the kernels run per device: tokens split with the batch, the head
+    # shared (`_spmd.call_local`)
+    return call_local(lambda *a: fn(*a, *static), operands,
+                      (True, False, False, True), True,
+                      interpreted=_INTERPRET)

@@ -19,27 +19,28 @@ import os
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental import pallas as pl
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
+from ._spmd import call_local, out_struct
 
 _BLOCK_ROWS = 256
 
+# MXNET_PALLAS_INTERPRET=1: run the kernels through the interpreter so the
+# CPU mesh executes the real kernel bodies (see flash_attention.py)
+_INTERPRET = os.environ.get("MXNET_PALLAS_INTERPRET", "0") == "1"
 
-def _use_pallas(x2d):
+
+def _use_pallas(x):
     # MXNET_LN_IMPL pins the choice (pallas/jnp) — needed when AOT-
     # compiling for a TPU topology from a CPU process, where the backend
     # check would silently swap the jnp body into the lowered program
     forced = os.environ.get("MXNET_LN_IMPL")
-    if forced == "pallas":
-        return _HAS_PALLAS and x2d.shape[-1] % 128 == 0
     if forced == "jnp":
         return False
-    return (_HAS_PALLAS and jax.default_backend() == "tpu"
-            and x2d.shape[-1] % 128 == 0)
+    if forced != "pallas" and jax.default_backend() != "tpu" \
+            and not _INTERPRET:
+        return False
+    return x.shape[-1] % 128 == 0
 
 
 # -- kernels ---------------------------------------------------------------
@@ -108,10 +109,11 @@ def _fwd_pallas(x2d, gamma, beta, eps):
             pl.BlockSpec((_BLOCK_ROWS, 1), lambda i: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(xp.shape, x2d.dtype),
-            jax.ShapeDtypeStruct((xp.shape[0], 1), jnp.float32),
-            jax.ShapeDtypeStruct((xp.shape[0], 1), jnp.float32),
+            out_struct(xp.shape, x2d.dtype, xp, gamma, beta),
+            out_struct((xp.shape[0], 1), jnp.float32, xp, gamma, beta),
+            out_struct((xp.shape[0], 1), jnp.float32, xp, gamma, beta),
         ],
+        interpret=_INTERPRET,
     )(xp, gamma.reshape(1, -1), beta.reshape(1, -1))
     return y[:rows], mean[:rows], rstd[:rows]
 
@@ -140,10 +142,11 @@ def _bwd_pallas(x2d, gamma, mean, rstd, dy2d):
             pl.BlockSpec((1, n), lambda i: (0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(xp.shape, x2d.dtype),
-            jax.ShapeDtypeStruct((1, n), jnp.float32),
-            jax.ShapeDtypeStruct((1, n), jnp.float32),
+            out_struct(xp.shape, x2d.dtype, xp, gamma, meanp, rstdp, dyp),
+            out_struct((1, n), jnp.float32, xp, gamma, meanp, rstdp, dyp),
+            out_struct((1, n), jnp.float32, xp, gamma, meanp, rstdp, dyp),
         ],
+        interpret=_INTERPRET,
     )(xp, gamma.reshape(1, -1), meanp, rstdp, dyp)
     return dx[:rows], dg[0], db[0]
 
@@ -175,9 +178,18 @@ def _bwd_jnp(x2d, gamma, mean, rstd, dy2d):
 # -- public op -------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def layer_norm(x, gamma, beta, eps=1e-5):
     """y = (x - mean)/sqrt(var+eps) * gamma + beta over the last axis."""
+    if not _use_pallas(x):
+        return _layer_norm(x, gamma, beta, eps)
+    # the kernel runs per device: rows split with the batch, gamma/beta
+    # shared (`_spmd.call_local`)
+    return call_local(lambda *a: _layer_norm(*a, eps), (x, gamma, beta),
+                      (True, False, False), True, interpreted=_INTERPRET)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _layer_norm(x, gamma, beta, eps):
     return _ln_fwd(x, gamma, beta, eps)[0]
 
 
@@ -207,4 +219,4 @@ def _ln_bwd_vjp(eps, res, dy):
             db.astype(gamma.dtype))
 
 
-layer_norm.defvjp(_ln_fwd_vjp, _ln_bwd_vjp)
+_layer_norm.defvjp(_ln_fwd_vjp, _ln_bwd_vjp)

@@ -17,17 +17,10 @@ from __future__ import annotations
 import numpy as np
 
 import jax
+from jax import shard_map  # noqa: F401  (the package imports it from here)
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..base import MXNetError
-
-# one version-compat home for shard_map (jax>=0.8 moved it out of
-# experimental); everything in this package imports it from here
-try:
-    from jax import shard_map as _sm
-    shard_map = _sm.shard_map if hasattr(_sm, "shard_map") else _sm
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
 
 _current_mesh = None
 

@@ -68,10 +68,7 @@ def init_from_env(coordinator=None, num_processes=None, process_id=None):
     # env default (read at backend init) and the live config.  Only the
     # CPU backend reads this, so it is harmless on TPU jobs.
     os.environ.setdefault("JAX_CPU_COLLECTIVES_IMPLEMENTATION", "gloo")
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception as e:  # older jax / no gloo build: TPU doesn't need it
-        logging.warning("cpu collectives config not applied: %s", e)
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(coordinator_address=coordinator,
                                num_processes=num_processes,
                                process_id=process_id)

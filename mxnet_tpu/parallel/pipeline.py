@@ -83,14 +83,9 @@ class PipelineParallel:
         # sidesteps it with identical math
         total0 = jnp.zeros((1,), jnp.float32)
         # carries flow through ppermute/psum, so they are device-varying
-        # over the pipe axis; the init must carry the same type.  pcast
-        # replaced the deprecated pvary in jax 0.9.
-        if hasattr(jax.lax, "pcast"):
-            zero = jax.lax.pcast(zero, ax, to="varying")
-            total0 = jax.lax.pcast(total0, ax, to="varying")
-        elif "pvary" in dir(jax.lax):
-            zero = jax.lax.pvary(zero, (ax,))
-            total0 = jax.lax.pvary(total0, (ax,))
+        # over the pipe axis; the init must carry the same type
+        zero = jax.lax.pcast(zero, ax, to="varying")
+        total0 = jax.lax.pcast(total0, ax, to="varying")
 
         def tick(carry, t):
             buf, total = carry

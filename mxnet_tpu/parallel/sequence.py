@@ -37,17 +37,6 @@ from ..ops.pallas_kernels import flash_attention
 _NEG_INF = -1e30
 
 
-def _axis_size(axis_name):
-    """Static size of a mesh axis from inside shard_map.  `lax.axis_size`
-    only exists in newer jax; on older runtimes the axis environment's
-    size lookup (exposed as `core.axis_frame`) returns the same int."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    from jax import core
-
-    return core.axis_frame(axis_name)
-
-
 def ring_attention(q, k, v, axis_name, *, causal=False, scale=None):
     """Exact attention over a sequence sharded on mesh axis ``axis_name``.
 
@@ -57,7 +46,7 @@ def ring_attention(q, k, v, axis_name, *, causal=False, scale=None):
     """
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     b, h, s_loc, d = q.shape
 
@@ -101,7 +90,7 @@ def ulysses_attention(q, k, v, axis_name, *, causal=False, scale=None):
     device holds heads/n full-sequence heads, dense flash attention runs
     locally, and the output is re-sharded back to sequence-parallel.
     """
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     h = q.shape[1]
     if h % n != 0:
         raise ValueError(

@@ -136,7 +136,7 @@ from .. import tracing
 from ..base import MXNetError
 from ..context import Context
 from ..executor import AotCache
-from ..parallel.mesh import mesh_signature, submeshes
+from ..parallel.mesh import MeshContext, mesh_signature, submeshes
 from ..quant.codec import resolve as quant_resolve
 from .handoff import HandoffLanding, HandoffTicket, disagg_enabled
 from .journal import RequestJournal, journal_enabled
@@ -626,7 +626,7 @@ class ServingEngine:
             # incarnation's already-quantized device params straight
             # through (quantize_params is idempotent)
             params = model.quantize_params(params)
-        jarr = getattr(jax, "Array", ())
+        jarr = jax.Array
         if self._mesh is not None:
             # the trainer's auto-param-sharding rules, applied at load:
             # tensor-parallel projections/head/expert banks, replicated
@@ -1224,8 +1224,22 @@ class ServingEngine:
             return jax.jit(prog, donate_argnums=donate)
         m = {"repl": self._device, "cache": self._cache_sharding()}
         sh = tuple(m[o] for o in outs)
-        return jax.jit(prog, donate_argnums=donate,
+        return jax.jit(self._scoped(prog), donate_argnums=donate,
                        out_shardings=sh if len(sh) > 1 else sh[0])
+
+    def _scoped(self, prog):
+        """``prog`` with this replica's mesh scoped over its trace, so the
+        Pallas kernels — which GSPMD cannot partition — run per device
+        under shard_map (ops/pallas_kernels/_spmd.py).  Single-device
+        engines get ``prog`` back."""
+        if self._mesh is None:
+            return prog
+
+        def scoped(*args):
+            with MeshContext(self._mesh):
+                return prog(*args)
+
+        return scoped
 
     def _moe_out(self, tape):
         """The MoE programs' extra output: the launch's per-expert

@@ -333,7 +333,7 @@ class ModelDrafter(Drafter):
             # idempotent: the self-draft path shares the engine's
             # already-quantized device params
             params = self.model.quantize_params(params)
-        jarr = getattr(jax, "Array", ())
+        jarr = jax.Array
         self._dparams = {k: v if isinstance(v, jarr)
                          else engine._put(np.asarray(v))
                          for k, v in params.items()}
@@ -367,7 +367,7 @@ class ModelDrafter(Drafter):
                 # its step only writes proposal k's own draft K/V
                 return toks[:k].T, pool
 
-            fn = jax.jit(prog, donate_argnums=(1,))
+            fn = jax.jit(e._scoped(prog), donate_argnums=(1,))
             z = e._put(np.zeros((b,), np.int32))
             tables = e._put(np.zeros((b, e._n_table), np.int32))
             samp = tuple(e._put(a) for a in e._sample_placeholders(b))
@@ -385,7 +385,7 @@ class ModelDrafter(Drafter):
                     params, pool, tokens, start, length, tables)
                 return pool
 
-            fn = jax.jit(prog, donate_argnums=(1,))
+            fn = jax.jit(e._scoped(prog), donate_argnums=(1,))
             toks = e._put(np.zeros((1, s), np.int32))
             zero = e._put(np.zeros((1,), np.int32))
             one = e._put(np.ones((1,), np.int32))

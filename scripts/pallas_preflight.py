@@ -26,7 +26,10 @@ def _maxerr(a, b):
 
 
 def run(verbose=True):
-    """Returns {"status": "pass"|"skip: ..."|"FAIL: ...", checks...}."""
+    """Returns {"status": "pass"|"FAIL: ...", checks...}; off the chip the
+    status is a FAIL naming the backend found — there is nothing to
+    compare there, and a gate that reports success without running is no
+    gate."""
     import jax
     import jax.numpy as jnp
 
@@ -34,14 +37,13 @@ def run(verbose=True):
     from mxnet_tpu.ops.pallas_kernels import fused_ce_mod as fc
 
     if jax.default_backend() != "tpu":
-        return {"status": "skip: backend is %s" % jax.default_backend()}
-    if not fa._HAS_PALLAS:
-        return {"status": "skip: pallas unavailable"}
+        return {"status": "FAIL: backend is %s, the kernels need a TPU"
+                % jax.default_backend()}
     try:
         return _run_checks(jax, jnp, fa, fc, verbose)
     except Exception as e:
         # past the backend gate an exception IS a kernel regression
-        # (compile error, signature drift): report FAIL, never skip
+        # (compile error, signature drift)
         return {"status": "FAIL: preflight raised %s: %s"
                 % (type(e).__name__, str(e)[:300])}
 
@@ -100,7 +102,7 @@ def _run_checks(jax, jnp, fa, fc, verbose):
         # kernel assumes would slip through them.  Tolerance is loosened
         # (1.5e-1 vs 3e-2): the fwd's tolerated ulp-level differences
         # compound through bf16 rounding cliffs in p=exp(s-lse) — the
-        # round-5 relay campaign measured ~0.106 here on healthy kernels
+        # round-5 chip campaign measured ~0.106 here on healthy kernels
         # — while a genuine residual-contract break (wrong lse scale,
         # stale o) lands orders of magnitude higher.
         res_self = (q, k, v, o_p, lse_p, zero, zero)
@@ -160,7 +162,7 @@ def _run_checks(jax, jnp, fa, fc, verbose):
         # kernel's own (o_b, lse_b) compounds the fwd's tolerated ulp-
         # level differences through bf16 rounding cliffs in p=exp(s-lse),
         # which the 1e-3 relative floor then inflates into on-chip "dv
-        # err 0.106"-style false failures (seen round 5, relay campaign).
+        # err 0.106"-style false failures (seen in the round-5 chip campaign).
         res_b = (qb, kb, vb, merge(o_j), lse_j, zero, zero)
         dq_b, dk_b, dv_b = jax.jit(
             lambda res, grads, c=causal: fa._flash_bwd_pallas_bsd(
@@ -261,4 +263,4 @@ def _run_checks(jax, jnp, fa, fc, verbose):
 if __name__ == "__main__":
     result = run()
     print(result)
-    sys.exit(0 if result["status"].startswith(("pass", "skip")) else 1)
+    sys.exit(0 if result["status"] == "pass" else 1)

@@ -67,14 +67,17 @@ $CPU_ENV python tools/launch.py -n 2 \
     --num-epochs 10 --lr 0.05 --kv-store dist_sync 2>&1 | tee /tmp/nightly_dist.log
 check_val /tmp/nightly_dist.log 0.98 "mnist lenet dist_sync"
 
-# -- bench smoke on the CPU mesh -----------------------------------------
-env PYTHONPATH= JAX_PLATFORMS=cpu \
-    XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    BENCH_BATCH=8 BENCH_IMAGE=64 BENCH_STEPS=2 BENCH_REPS=1 \
-    TBENCH_LAYERS=1 TBENCH_EMBED=64 TBENCH_HEADS=2 TBENCH_SEQ=64 \
-    TBENCH_BATCH=8 TBENCH_VOCAB=128 TBENCH_STEPS=2 TBENCH_REPS=1 \
-    TBENCH_DTYPE=float32 \
-    python bench.py
+# -- the chip's own programs refuse to run without it ----------------------
+# `python bench.py` (the training benchmark) and `python chip_smoke.py` are
+# measurements and proofs of the chip: on the CPU mesh both must exit
+# non-zero and print no result (tests/test_bench_store.py,
+# tests/test_chip_smoke.py hold the same in tier-1)
+for prog in bench.py chip_smoke.py; do
+    if out=$(env PYTHONPATH= JAX_PLATFORMS=cpu python "$prog" 2>/dev/null); then
+        echo "$prog ran without a TPU"; exit 1
+    fi
+    [ -z "$out" ] || { echo "$prog printed a result without a TPU"; exit 1; }
+done
 
 # -- input-pipeline overlap gate (docs/data_pipeline.md) ------------------
 # throttled-iterator synthetic: the device prefetcher must beat the

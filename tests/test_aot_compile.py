@@ -1,11 +1,21 @@
-"""AOT TPU-target compilation as CI (ADR-11).
+"""Compiles for a described TPU v5e, with no chip (ADR-11).
 
-`SPMDTrainer(abstract=True).lower_step()` compiles the full fused train
-step against an abstract v5e topology using the local libtpu — no
-device.  That makes Mosaic lowering of every Pallas kernel family a CI
-property instead of an on-chip-only one: a kernel that stops lowering
-(tile shapes, layouts, scratch misuse) fails HERE, not at bench time.
-Tiny shapes keep each compile to seconds.
+The TPU compiler is installed here and compiles for a chip that is
+described, not attached (`jax.experimental.topologies`).  That makes Mosaic
+lowering of every Pallas kernel on the main path a property of the test
+suite at the widths the chip runs — (8,128)/(16,128) tiling, scoped-VMEM
+limits, `shard_map` typing — instead of something the first chip run
+discovers.  A compile that passes is not a chip run: nothing executes and
+nothing here says anything about results or speed.
+
+All of it lives in this one file and loads libtpu only from the module-scoped
+``topo`` fixture: the library belongs to one process, so every compile runs
+in the test's own process (no child), and nothing touches the topology at
+import or collection time.
+
+The kernels gate on `jax.default_backend() == "tpu"`; from a CPU process the
+``on_tpu`` fixture steers that check, so each compile takes exactly the
+kernels and shape gates the chip would.
 """
 import os
 
@@ -13,43 +23,233 @@ import numpy as np
 import pytest
 
 import jax
+import jax.numpy as jnp
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
 
-pytestmark = pytest.mark.skipif(
-    os.environ.get("MXNET_SKIP_AOT_TESTS", "0") == "1",
-    reason="AOT compile tests disabled")
 
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
 
-def _topo_mesh():
-    from mxnet_tpu.base import MXNetError
-    from mxnet_tpu.test_utils import aot_v5e_mesh
-
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else logs to /tmp
     try:
-        return aot_v5e_mesh()
-    except MXNetError as e:  # no local libtpu / unsupported jaxlib
-        pytest.skip(str(e)[:140])
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu here, or it is held by another process
+        pytest.skip("no v5e:2x2 topology can be described here: %s"
+                    % str(e)[:200])
 
 
-def _compile_lm(mesh, monkeypatch, attn_layout="bhsd", bsd_kernel=None,
-                fused=False):
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """The kernels' backend gates see a TPU; no pin, so the shape gates
+    decide as they would on the chip."""
+    for pin in ("MXNET_FLASH_IMPL", "MXNET_FLASH_BSD_KERNEL", "MXNET_LN_IMPL",
+                "MXNET_FLASH_LAYOUT", "MXNET_FLASH_BWD", "MXNET_CE_SHARD"):
+        monkeypatch.delenv(pin, raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _compile(fn, *shapes):
+    comp = jax.jit(fn).lower(*shapes).compile()
+    return comp, comp.as_text().count("tpu_custom_call")
+
+
+def _bf16(shape, sharding):
+    return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=sharding)
+
+
+# -- the one-chip kernels at the widths the chip runs ----------------------
+
+
+@pytest.mark.parametrize("heads,head_dim", [(6, 128), (12, 64)])
+def test_flash_hsd_fwd_bwd_at_flagship_width(on_tpu, one_chip, heads,
+                                             head_dim):
+    from mxnet_tpu.ops.pallas_kernels import flash_attention
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True)
+                       .astype(jnp.float32))
+
+    sh = _bf16((32, heads, 1024, head_dim), one_chip)
+    _, kernels = _compile(jax.grad(loss, argnums=(0, 1, 2)), sh, sh, sh)
+    assert kernels == 3  # forward, dq, dk/dv
+
+
+@pytest.mark.parametrize("batch,seq,kernels_are", [
+    (32, 1024, "loop"),     # whole K/V resident in VMEM
+    (4, 8192, "stream"),    # past the residency cap: grid-streamed blocks
+])
+def test_flash_bsd_fwd_bwd_at_flagship_width(on_tpu, one_chip, batch, seq,
+                                             kernels_are):
+    from mxnet_tpu.ops.pallas_kernels import flash_attention_mod as fa
+
+    q = jnp.zeros((batch, seq, 768), jnp.bfloat16)
+    assert fa._bsd_structure(q, 6, seq) == kernels_are
+
+    def loss(q, k, v):
+        return jnp.sum(fa.flash_attention_bsd(q, k, v, 6, causal=True)
+                       .astype(jnp.float32))
+
+    sh = _bf16((batch, seq, 768), one_chip)
+    _, kernels = _compile(jax.grad(loss, argnums=(0, 1, 2)), sh, sh, sh)
+    assert kernels == 3
+
+
+@pytest.mark.parametrize("single_pass,kernels_expected", [
+    ("1", 2),   # stats+residual forward, dW/db backward
+    ("0", 3),   # 5-pass structure: forward, dx, dW/db
+])
+def test_fused_ce_fwd_bwd_at_flagship_width(on_tpu, one_chip, monkeypatch,
+                                            single_pass, kernels_expected):
+    from mxnet_tpu.ops.pallas_kernels import fused_softmax_ce
+
+    monkeypatch.setenv("MXNET_CE_SINGLE_PASS", single_pass)
+    n = d_vocab = 32768
+
+    def loss(x, w, label):
+        return jnp.sum(fused_softmax_ce(x, w, None, label))
+
+    x = _bf16((n, 768), one_chip)
+    w = _bf16((d_vocab, 768), one_chip)
+    label = jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_chip)
+    _, kernels = _compile(jax.grad(loss, argnums=(0, 1)), x, w, label)
+    assert kernels == kernels_expected
+
+
+def test_layer_norm_fwd_bwd_at_flagship_width(on_tpu, one_chip):
+    from mxnet_tpu.ops.pallas_kernels.layer_norm import layer_norm
+
+    def loss(x, g, b):
+        return jnp.sum(layer_norm(x, g, b, 1e-5).astype(jnp.float32))
+
+    x = _bf16((32768, 768), one_chip)
+    g = jax.ShapeDtypeStruct((768,), jnp.float32, sharding=one_chip)
+    _, kernels = _compile(jax.grad(loss, argnums=(0, 1, 2)), x, g, g)
+    assert kernels == 2
+
+
+# -- four described devices: kernels under shard_map ------------------------
+
+
+def test_sharded_fused_ce_compiles_for_four_devices(on_tpu, topo):
+    """The vocab-sharded head as `FusedSoftmaxCE` runs it on a 2x2 mesh:
+    tokens over "data", vocabulary over "model", forward and backward."""
+    from mxnet_tpu.ops.pallas_kernels.fused_ce import \
+        fused_softmax_ce_sharded
+    from mxnet_tpu.parallel.mesh import shard_map
+
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"))
+    head = shard_map(
+        lambda x, w, lbl: fused_softmax_ce_sharded(x, w, None, lbl, "model"),
+        mesh=mesh, in_specs=(P("data", None), P("model", None), P("data")),
+        out_specs=P("data"))
+
+    def loss(x, w, lbl):
+        return jnp.sum(head(x, w, lbl))
+
+    n = vocab = 32768
+    x = _bf16((n, 768), NamedSharding(mesh, P("data", None)))
+    w = _bf16((vocab, 768), NamedSharding(mesh, P("model", None)))
+    lbl = jax.ShapeDtypeStruct((n,), jnp.float32,
+                               sharding=NamedSharding(mesh, P("data")))
+    comp, kernels = _compile(jax.grad(loss, argnums=(0, 1)), x, w, lbl)
+    assert kernels == 2
+    # the lse reduce, the dx partials and the data-axis sum of dW ride the
+    # mesh; nothing gathers the head
+    txt = comp.as_text()
+    assert "all-reduce" in txt and "all-gather(" not in txt
+
+
+def test_ring_attention_compiles_for_four_devices(on_tpu, topo):
+    from mxnet_tpu.parallel import ring_attention
+    from mxnet_tpu.parallel.mesh import shard_map
+
+    mesh = Mesh(np.array(topo.devices), ("seq",))
+    spec = P(None, None, "seq")
+    ring = shard_map(
+        lambda q, k, v: ring_attention(q, k, v, "seq", causal=True),
+        mesh=mesh, in_specs=(spec,) * 3, out_specs=spec)
+
+    def loss(q, k, v):
+        return jnp.sum(ring(q, k, v).astype(jnp.float32) ** 2)
+
+    sh = _bf16((1, 6, 8192, 128), NamedSharding(mesh, spec))
+    comp, kernels = _compile(jax.grad(loss, argnums=(0, 1, 2)), sh, sh, sh)
+    assert kernels == 3
+    assert "collective-permute" in comp.as_text()
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_sharded_serving_programs_compile_for_four_devices(on_tpu, topo,
+                                                           program):
+    """A sub-mesh serving replica's programs (`ServingEngine` with a Mesh):
+    params and the paged K/V pool sharded by the model's own rules, the
+    trace scoped to the mesh as `ServingEngine._scoped` does — the fused
+    LayerNorm then runs per device instead of being refused as not
+    partitionable."""
+    from mxnet_tpu.base import bfloat16
+    from mxnet_tpu.parallel.mesh import MeshContext
+    from mxnet_tpu.serving import TransformerKVModel
+
+    L, V, S, E, bs, n_blocks = 2, 32768, 1024, 768, 16, 256
+    model = TransformerKVModel(V, S, num_layers=L, num_heads=6, num_embed=E,
+                               use_bias=False, dtype=bfloat16)
+    mesh = Mesh(np.array(topo.devices), ("model",))
+    repl = NamedSharding(mesh, P())
+    shardings = model.param_shardings(mesh)
+    params = {n: _bf16(s, shardings[n])
+              for n, s in model.param_shapes().items()}
+    kv = model.kv_shardings(mesh)[0]
+    pool = _bf16((L, 2, n_blocks, bs, E), kv)
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=repl)
+
+    if program == "prefill":
+        def prog(params, pool, tokens, start, length, tables):
+            with MeshContext(mesh):
+                logits, pool = model.prefill_paged(params, pool, tokens,
+                                                   start, length, tables)
+            return jnp.argmax(logits, axis=-1), pool
+
+        args = (ints(1, 128), ints(1), ints(1), ints(1, S // bs))
+    else:
+        def prog(params, pool, token, pos, tables):
+            with MeshContext(mesh):
+                logits, pool = model.decode_paged(params, pool, token, pos,
+                                                  tables)
+            return jnp.argmax(logits, axis=-1), pool
+
+        args = (ints(8), ints(8), ints(8, S // bs))
+    comp = jax.jit(prog, donate_argnums=(1,),
+                   out_shardings=(repl, kv)).lower(params, pool,
+                                                   *args).compile()
+    assert comp.as_text().count("tpu_custom_call") == 2 * L + 1
+
+
+# -- whole train steps (toy widths: seconds each) ---------------------------
+
+
+def _lm_trainer(mesh, attn_layout="bhsd", fused=False, heads=2, batch=4):
     from mxnet_tpu import models
     from mxnet_tpu.base import bfloat16
     from mxnet_tpu.parallel import SPMDTrainer
 
-    monkeypatch.setenv(
-        "MXNET_FLASH_IMPL",
-        "pallas_bsd" if attn_layout == "bsd" else "pallas_hsd")
-    monkeypatch.setenv("MXNET_LN_IMPL", "pallas")
-    if bsd_kernel:
-        monkeypatch.setenv("MXNET_FLASH_BSD_KERNEL", bsd_kernel)
-    B, S, D, H, V = 4, 512, 256, 2, 512
+    B, S, D, V = batch, 512, 256, 2048
     net = models.get_transformer_lm(
-        vocab_size=V, seq_len=S, num_layers=1, num_heads=H, num_embed=D,
+        vocab_size=V, seq_len=S, num_layers=1, num_heads=heads, num_embed=D,
         fused_head=fused, attn_layout=attn_layout)
-    tr = SPMDTrainer(net, mesh,
-                     data_shapes={"data": (B, S), "softmax_label": (B, S)},
-                     lr=1e-3, optimizer="adam", dtype=bfloat16,
-                     adam_v_dtype="bfloat16", abstract=True)
-    return tr.lower_step(batch_dtypes={"data": "int32"})
+    return SPMDTrainer(net, mesh,
+                       data_shapes={"data": (B, S), "softmax_label": (B, S)},
+                       lr=1e-3, optimizer="adam", dtype=bfloat16,
+                       adam_v_dtype="bfloat16", abstract=True)
 
 
 # The head-split marker: the bf16 (B, H, S, d) activation shape.
@@ -61,8 +261,9 @@ def _compile_lm(mesh, monkeypatch, attn_layout="bhsd", bsd_kernel=None,
 _HEAD_SPLIT_SHAPE = "bf16[4,2,512,128]"
 
 
-def test_aot_compiles_hsd_kernels(monkeypatch):
-    comp = _compile_lm(_topo_mesh(), monkeypatch)
+def test_aot_compiles_hsd_kernels(on_tpu, topo):
+    mesh = Mesh(np.array(topo.devices[:1]), ("data",))
+    comp = _lm_trainer(mesh).lower_step(batch_dtypes={"data": "int32"})
     txt = comp.as_text()
     assert "tpu_custom_call" in txt  # Pallas kernels really lowered
     # canary for the bsd test's negative assertion: this really is how
@@ -73,8 +274,10 @@ def test_aot_compiles_hsd_kernels(monkeypatch):
     assert ca.get("bytes accessed", 0) > 0
 
 
-def test_aot_compiles_bsd_loop_kernels(monkeypatch):
-    comp = _compile_lm(_topo_mesh(), monkeypatch, attn_layout="bsd")
+def test_aot_compiles_bsd_loop_kernels(on_tpu, topo):
+    mesh = Mesh(np.array(topo.devices[:1]), ("data",))
+    comp = _lm_trainer(mesh, attn_layout="bsd").lower_step(
+        batch_dtypes={"data": "int32"})
     txt = comp.as_text()
     assert "tpu_custom_call" in txt
     # the transposeless property: no bf16 head-split activation anywhere
@@ -82,10 +285,27 @@ def test_aot_compiles_bsd_loop_kernels(monkeypatch):
     assert _HEAD_SPLIT_SHAPE not in txt
 
 
-def test_aot_compiles_bsd_stream_kernels(monkeypatch):
-    comp = _compile_lm(_topo_mesh(), monkeypatch, attn_layout="bsd",
-                       bsd_kernel="stream", fused=True)
+def test_aot_compiles_bsd_stream_kernels(on_tpu, topo, monkeypatch):
+    monkeypatch.setenv("MXNET_FLASH_BSD_KERNEL", "stream")
+    mesh = Mesh(np.array(topo.devices[:1]), ("data",))
+    comp = _lm_trainer(mesh, attn_layout="bsd", fused=True).lower_step(
+        batch_dtypes={"data": "int32"})
     assert "tpu_custom_call" in comp.as_text()
+
+
+@pytest.mark.parametrize("shard_head", ["0", "1"])
+def test_aot_compiles_dp_tp_step_for_four_devices(on_tpu, topo, monkeypatch,
+                                                  shard_head):
+    """The whole step on a 2x2 mesh: GSPMD cannot partition a Mosaic
+    kernel, so LayerNorm, attention and the replicated head each run per
+    device under shard_map (`_spmd.call_local`), the vocab-sharded head
+    under its own."""
+    monkeypatch.setenv("MXNET_CE_SHARD", shard_head)
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"))
+    comp = _lm_trainer(mesh, attn_layout="bsd", fused=True).lower_step(
+        batch_dtypes={"data": "int32"})
+    # 3 LayerNorms fwd+bwd, attention fwd + 2 bwd, head fwd + dW
+    assert comp.as_text().count("tpu_custom_call") == 11
 
 
 def test_abstract_trainer_refuses_lower_without_abstract():

@@ -29,7 +29,6 @@ def q_of(s, d, dtype=jnp.bfloat16):
 @pytest.fixture
 def tpu_backend(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(fa, "_HAS_PALLAS", True)
 
 
 def test_cpu_backend_routes_to_jnp(monkeypatch):
@@ -92,11 +91,11 @@ def test_pin_pallas_respected_on_ok_shape(tpu_backend, monkeypatch):
         assert fa._pick_impl(q_of(1024, 64), 1024) == "pallas_ds"
 
 
-def test_pin_without_pallas_is_a_readable_error(monkeypatch):
-    monkeypatch.setenv("MXNET_FLASH_IMPL", "pallas_hsd")
-    monkeypatch.setattr(fa, "_HAS_PALLAS", False)
-    with pytest.raises(RuntimeError, match="MXNET_FLASH_IMPL"):
-        fa._pick_impl(q_of(1024, 64), 1024)
+def test_pallas_is_imported_unconditionally():
+    """No availability flag: on the installed packages the Pallas imports
+    work or the program is broken, and an import error is an error."""
+    assert not hasattr(fa, "_HAS_PALLAS")
+    assert fa.pl.pallas_call and fa.pltpu.CompilerParams
 
 
 def test_pin_on_rejected_shape_warns_but_honors_pin(tpu_backend,
@@ -125,12 +124,21 @@ def test_block_size_env_override(monkeypatch):
     assert captured["blocks"] == (512, 64)
 
 
-def test_bsd_pin_error_without_pallas(monkeypatch):
-    monkeypatch.setenv("MXNET_FLASH_IMPL", "pallas_bsd")
-    monkeypatch.setattr(fa, "_HAS_PALLAS", False)
+@pytest.mark.parametrize("pin,expect", [(None, False), ("jnp", False),
+                                        ("pallas_bsd", True),
+                                        ("pallas_hsd", True)])
+def test_bsd_eligibility_off_chip_follows_the_pin(monkeypatch, pin, expect):
+    """Off the chip the bsd kernels are eligible only under a Pallas pin
+    (the AOT-compile-from-CPU case); nothing else makes them so."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    monkeypatch.setattr(fa, "_INTERPRET", False)
+    if pin is None:
+        monkeypatch.delenv("MXNET_FLASH_IMPL", raising=False)
+    else:
+        monkeypatch.setenv("MXNET_FLASH_IMPL", pin)
     q = jnp.zeros((1, 1024, 256), jnp.bfloat16)
-    with pytest.raises(RuntimeError, match="pallas_bsd"):
-        fa.flash_attention_bsd(q, q, q, 2)
+    assert fa._bsd_eligible(q, 2) is expect
+    assert fa._bsd_eligible(q, 4) is False   # head_dim 64: never
 
 
 def test_bsd_pin_warns_on_rejected_shape(monkeypatch):

@@ -517,10 +517,13 @@ def test_ce_shard_zero_steady_state_retraces(monkeypatch):
     assert len(after) == before, after[before:]
 
 
-def test_fused_ce_inside_shard_map():
+@pytest.mark.parametrize("cast_weight", [True, False])
+def test_fused_ce_inside_shard_map(cast_weight):
     """The long-context configuration: tokens sharded over a mesh axis,
-    fused head inside shard_map with a pvaried replicated weight; dW must
-    psum back to the replicated gradient of the unsharded computation."""
+    fused head inside shard_map with a replicated weight — cast to varying
+    by the caller (shard_map's transpose then psums dW) or used as it is
+    (the head's own vjp rule psums it); either way dW must equal the
+    replicated gradient of the unsharded computation."""
     from jax.sharding import PartitionSpec as P
 
     from mxnet_tpu.parallel import make_mesh
@@ -535,8 +538,8 @@ def test_fused_ce_inside_shard_map():
 
     def sharded_loss(x_, w_):
         def local(xs, wr, ys):
-            if hasattr(jax.lax, "pvary"):
-                wr = jax.lax.pvary(wr, ("seq",))
+            if cast_weight:
+                wr = jax.lax.pcast(wr, ("seq",), to="varying")
             return fused_softmax_ce(xs, wr, None, ys,
                                     grad_scale=1.0 / n, block_v=8)
 

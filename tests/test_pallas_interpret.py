@@ -20,8 +20,6 @@ from mxnet_tpu.ops.pallas_kernels import fused_ce_mod as fc
 
 @pytest.fixture()
 def interpret(monkeypatch):
-    if not fa._HAS_PALLAS:
-        pytest.skip("pallas unavailable")
     monkeypatch.setattr(fa, "_INTERPRET", True)
     monkeypatch.setattr(fc, "_INTERPRET", True)
 

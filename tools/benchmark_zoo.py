@@ -16,6 +16,8 @@ import numpy as np
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
+import chip_env  # noqa: E402  (tools/ is on the path: script dir, or bench.py)
+
 
 CONFIGS = {
     # name: (builder kwargs, data shapes builder, unit)
@@ -80,6 +82,10 @@ def main():
     ap.add_argument("--steps", type=int, default=10)
     ap.add_argument("--reps", type=int, default=2)
     args = ap.parse_args()
+    devices = chip_env.require_tpu()
+    chip_env.enable_compile_cache()
+    print("device: %d x %s (%s)" % (len(devices), devices[0].device_kind,
+                                   devices[0].platform))
     print("%-14s %10s %14s" % ("model", "batch", "images/sec/chip"))
     for name in args.models.split(","):
         name = name.strip()

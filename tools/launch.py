@@ -13,6 +13,16 @@ Usage:
 Each worker gets DMLC_ROLE=worker, DMLC_RANK, DMLC_NUM_WORKER,
 DMLC_PS_ROOT_URI/PORT; the server process runs the kvstore server loop and
 exits on kStopServer (sent by rank 0 teardown).
+
+Devices: a chip belongs to one process.  This launcher never imports jax,
+so it holds none; the server processes are pinned to the CPU backend
+(`JAX_PLATFORMS=cpu` — they only sum host arrays) so they cannot take a chip
+from a worker.  Every local worker opens the default backend, so on one
+host the worker count is the chip count: `-n 1` on a one-chip machine, and
+on a multi-chip host the command itself must bind each worker to its own
+chip (it can key on DMLC_RANK).  More workers than chips fail or hang at
+backend start-up.  On the CPU test mesh (`JAX_PLATFORMS=cpu` in the
+environment) any worker count runs.
 """
 from __future__ import annotations
 
@@ -102,11 +112,15 @@ def main():
     num_servers = max(1, args.num_servers)
     server_cmd = [sys.executable, "-c",
                   "from mxnet_tpu.parallel.dist import run_server; run_server()"]
-    for sid in range(num_servers):
+    def server_env(sid):
         senv = dict(base_env)
         senv["DMLC_ROLE"] = "server"
         senv["DMLC_SERVER_ID"] = str(sid)
-        procs.append(subprocess.Popen(server_cmd, env=senv))
+        senv["JAX_PLATFORMS"] = "cpu"  # a server must never hold a chip
+        return senv
+
+    for sid in range(num_servers):
+        procs.append(subprocess.Popen(server_cmd, env=server_env(sid)))
 
     extra_keys = {kv.partition("=")[0] for kv in args.env}
     for rank in range(args.num_workers):
@@ -149,10 +163,8 @@ def main():
                           "(%d restart(s) left)"
                           % (sid, s.returncode, restarts_left - 1),
                           file=sys.stderr, flush=True)
-                    senv = dict(base_env)
-                    senv["DMLC_ROLE"] = "server"
-                    senv["DMLC_SERVER_ID"] = str(sid)
-                    procs[sid] = subprocess.Popen(server_cmd, env=senv)
+                    procs[sid] = subprocess.Popen(server_cmd,
+                                                  env=server_env(sid))
                     restarts_left -= 1
             time.sleep(0.2)
 
