@@ -1,0 +1,10 @@
+"""`pytest benchmark/tests` — the yardstick's own tests, not tier-1.  They run
+on the CPU at the configurations' `tiny` sizes."""
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
