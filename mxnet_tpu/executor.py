@@ -164,7 +164,12 @@ def _build_graph_fn(symbol: Symbol):
                 else None
             )
             octx = OpCtx(is_train=is_train, rng=key)
-            outs, aux_up = node.op.apply(octx, node.params, inputs, aux_in)
+            # the node's name on its operations, forward and backward
+            # (`transpose(jvp(<node>))`), in HLO metadata and so in a
+            # profiler trace's `tf_op`
+            with jax.named_scope(node.name):
+                outs, aux_up = node.op.apply(octx, node.params, inputs,
+                                             aux_in)
             for i, o in enumerate(outs):
                 env[(id(node), i)] = o
             for i, u in enumerate(aux_up):
@@ -782,7 +787,9 @@ class Executor:
                     if getattr(node.op, "need_rng", False)
                     else None
                 )
-                outs, _ = node.op.apply(OpCtx(is_train, key), node.params, inputs, aux_in)
+                with jax.named_scope(node.name):
+                    outs, _ = node.op.apply(OpCtx(is_train, key),
+                                            node.params, inputs, aux_in)
                 for i, o in enumerate(outs):
                     env[(id(node), i)] = o
             seq += 1

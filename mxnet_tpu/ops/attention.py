@@ -130,6 +130,7 @@ class DotProductAttention(OpDef):
 register(DotProductAttention, aliases=("Attention",))
 
 
+@jax.named_scope("decode_attention")
 def decode_attention(q, k_cache, v_cache, pos, num_heads, *, scale=None):
     """Single-token attention over a per-sequence K/V cache (serving decode
     step).
@@ -188,6 +189,7 @@ def decode_attention(q, k_cache, v_cache, pos, num_heads, *, scale=None):
     return out.reshape(b, e).astype(q.dtype)
 
 
+@jax.named_scope("kv_gather")
 def gather_paged_kv(pool, block_tables):
     """Materialize per-row K or V context from a paged block pool.
 
@@ -214,6 +216,7 @@ def gather_paged_kv(pool, block_tables):
     return pool[block_tables.astype(jnp.int32)].reshape(b, m * bs, e)
 
 
+@jax.named_scope("kv_gather")
 def gather_paged_scales(scales, block_tables):
     """Materialize per-row dequantization scales from a paged scale pool
     (the int8-KV companion of `gather_paged_kv`).
@@ -254,6 +257,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, pos, num_heads,
     return decode_attention(q, kc, vc, pos, num_heads, scale=scale)
 
 
+@jax.named_scope("chunk_attention")
 def chunk_attention(q, k_cache, v_cache, start, num_heads, *, scale=None):
     """Chunked-prefill attention: a c-token query chunk at absolute
     positions ``start .. start+c-1`` attends to the cached prefix plus
@@ -309,6 +313,7 @@ def chunk_attention(q, k_cache, v_cache, start, num_heads, *, scale=None):
     return out.reshape(b, c, e).astype(q.dtype)
 
 
+@jax.named_scope("verify_attention")
 def verify_attention(q, k_cache, v_cache, start, length, num_heads, *,
                      scale=None):
     """Length-masked multi-query verify attention (speculative decoding).

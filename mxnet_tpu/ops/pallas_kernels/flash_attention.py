@@ -192,6 +192,7 @@ def _flash_fwd_pallas(q, k, v, q_off, k_off, scale, causal,
             transcendentals=b * h * sq_p * skv_p,
         ),
         interpret=_INTERPRET,
+        name="flash_fwd",
     )(jnp.asarray([q_off], jnp.int32), jnp.asarray([k_off], jnp.int32),
       qp, kp, vp)
     lse = lse[..., 0]  # drop the broadcast lane dim
@@ -426,6 +427,7 @@ def _flash_bwd_pallas(scale, causal, block_q, block_k, res, grads):
             transcendentals=b * h * sq_p * skv_p,
         ),
         interpret=_INTERPRET,
+        name="flash_bwd_dq",
     )(qo, ko, qp, kp, vp, dop, lsep, deltap)
 
     dk, dv = pl.pallas_call(
@@ -469,6 +471,7 @@ def _flash_bwd_pallas(scale, causal, block_q, block_k, res, grads):
             transcendentals=b * h * sq_p * skv_p,
         ),
         interpret=_INTERPRET,
+        name="flash_bwd_dkv",
     )(qo, ko, qp, kp, vp, dop, lsep, deltap)
 
     if pad_q:
@@ -672,6 +675,7 @@ def _flash_fwd_pallas_ds(q, k, v, q_off, k_off, scale, causal,
             transcendentals=b * h * sq_p * skv_p,
         ),
         interpret=_INTERPRET,
+        name="flash_fwd",
     )(jnp.asarray([q_off], jnp.int32), jnp.asarray([k_off], jnp.int32),
       qp, kp, vp)
     lse = lse[:, :, 0]
@@ -852,6 +856,7 @@ def _flash_bwd_pallas_ds(scale, causal, block_q, block_k, res, grads):
             transcendentals=b * h * sq_p * skv_p,
         ),
         interpret=_INTERPRET,
+        name="flash_bwd_dq",
     )(qo, ko, qp, kp, vp, dop, lsep, deltap)
 
     dk, dv = pl.pallas_call(
@@ -900,6 +905,7 @@ def _flash_bwd_pallas_ds(scale, causal, block_q, block_k, res, grads):
             transcendentals=b * h * sq_p * skv_p,
         ),
         interpret=_INTERPRET,
+        name="flash_bwd_dkv",
     )(qo, ko, qp, kp, vp, dop, lsep, deltap)
 
     if pad_q:
@@ -1036,6 +1042,7 @@ def _flash_fwd_pallas_bsd(q, k, v, q_off, k_off, scale, causal,
             transcendentals=b * num_heads * sq_p * skv_p,
         ),
         interpret=_INTERPRET,
+        name="flash_fwd",
     )(jnp.asarray([q_off], jnp.int32), jnp.asarray([k_off], jnp.int32),
       qp, kp, vp)
     lse = lse[..., 0]
@@ -1219,6 +1226,7 @@ def _flash_bwd_pallas_bsd(scale, causal, block_q, block_k, num_heads,
             transcendentals=b * num_heads * sq_p * skv_p,
         ),
         interpret=_INTERPRET,
+        name="flash_bwd_dq",
     )(qo, ko, qp, kp, vp, dop, lsep, deltap)
 
     dk, dv = pl.pallas_call(
@@ -1262,6 +1270,7 @@ def _flash_bwd_pallas_bsd(scale, causal, block_q, block_k, num_heads,
             transcendentals=b * num_heads * sq_p * skv_p,
         ),
         interpret=_INTERPRET,
+        name="flash_bwd_dkv",
     )(qo, ko, qp, kp, vp, dop, lsep, deltap)
 
     if pad_q:
@@ -1400,6 +1409,7 @@ def _flash_fwd_pallas_bsd_gs(q, k, v, q_off, k_off, scale, causal,
             transcendentals=b * num_heads * sq_p * skv_p,
         ),
         interpret=_INTERPRET,
+        name="flash_fwd",
     )(jnp.asarray([q_off], jnp.int32), jnp.asarray([k_off], jnp.int32),
       qp, kp, vp)
     lse = lse[..., 0]
@@ -1575,6 +1585,7 @@ def _flash_bwd_pallas_bsd_gs(scale, causal, block_q, block_k, num_heads,
             transcendentals=b * num_heads * sq_p * skv_p,
         ),
         interpret=_INTERPRET,
+        name="flash_bwd_dq",
     )(qo, ko, qp, kp, vp, dop, lsep, deltap)
 
     dk, dv = pl.pallas_call(
@@ -1623,6 +1634,7 @@ def _flash_bwd_pallas_bsd_gs(scale, causal, block_q, block_k, num_heads,
             transcendentals=b * num_heads * sq_p * skv_p,
         ),
         interpret=_INTERPRET,
+        name="flash_bwd_dkv",
     )(qo, ko, qp, kp, vp, dop, lsep, deltap)
 
     if pad_q:

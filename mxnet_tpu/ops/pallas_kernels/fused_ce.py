@@ -197,6 +197,7 @@ def _fwd_pallas(x, w, b, label, grad_scale, ignore_label, use_ignore,
             transcendentals=np_ * vp_,
         ),
         interpret=_INTERPRET,
+        name="fused_ce_fwd",
     )(xp, wp, bp.reshape(1, -1), lblp.reshape(1, -1))
     return nll[0, :n], lse[0, :n]
 
@@ -318,6 +319,7 @@ def _bwd_pallas(x, w, b, label, lse, grad_scale, ignore_label, use_ignore,
             transcendentals=np_ * vp_,
         ),
         interpret=_INTERPRET,
+        name="fused_ce_bwd_dx",
     )(xp, wp, bp, lblp, lsep)
 
     dw, db = pl.pallas_call(
@@ -349,6 +351,7 @@ def _bwd_pallas(x, w, b, label, lse, grad_scale, ignore_label, use_ignore,
             transcendentals=np_ * vp_,
         ),
         interpret=_INTERPRET,
+        name="fused_ce_bwd_dw",
     )(xp, wp, bp, lblp, lsep)
 
     if pad_n:
@@ -562,6 +565,7 @@ def _fwd_sp_pallas(x, w, b, label, block_n, block_v):
             transcendentals=np_ * vp_,
         ),
         interpret=_INTERPRET,
+        name="fused_ce_fwd",
     )(xp, wp, bp, lblp)
     return lse[0, :n], a[0, :n], dxp[:n]
 
@@ -727,6 +731,7 @@ def _bwd_dw_rs_pallas(x, w, b, label, lse, r, block_n, block_v):
             transcendentals=np_ * vp_,
         ),
         interpret=_INTERPRET,
+        name="fused_ce_bwd_dw",
     )(xp, wp, bp, lblp, lsep, rp)
     if vp_ != v:
         dw, db = dw[:v], db[:, :v]
@@ -762,6 +767,7 @@ def _bwd_dx_rs_pallas(x, w, b, label, lse, r, block_n, block_v):
             transcendentals=np_ * vp_,
         ),
         interpret=_INTERPRET,
+        name="fused_ce_bwd_dx",
     )(xp, wp, bp, lblp, lsep, rp)
     return dx[:n] if np_ != n else dx
 

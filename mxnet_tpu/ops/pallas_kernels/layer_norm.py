@@ -114,6 +114,7 @@ def _fwd_pallas(x2d, gamma, beta, eps):
             out_struct((xp.shape[0], 1), jnp.float32, xp, gamma, beta),
         ],
         interpret=_INTERPRET,
+        name="layer_norm_fwd",
     )(xp, gamma.reshape(1, -1), beta.reshape(1, -1))
     return y[:rows], mean[:rows], rstd[:rows]
 
@@ -147,6 +148,7 @@ def _bwd_pallas(x2d, gamma, mean, rstd, dy2d):
             out_struct((1, n), jnp.float32, xp, gamma, meanp, rstdp, dyp),
         ],
         interpret=_INTERPRET,
+        name="layer_norm_bwd",
     )(xp, gamma.reshape(1, -1), meanp, rstdp, dyp)
     return dx[:rows], dg[0], db[0]
 

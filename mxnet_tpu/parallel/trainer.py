@@ -306,7 +306,9 @@ class SPMDTrainer:
             outs, vjp, new_aux = jax.vjp(f, params, has_aux=True)
             cot = tuple(jnp.ones_like(o) for o in outs)
             (grads,) = vjp(cot)
-            new_params, new_momenta = opt_update(params, grads, momenta, lr)
+            with jax.named_scope("optimizer"):
+                new_params, new_momenta = opt_update(params, grads, momenta,
+                                                     lr)
             aux_out = dict(zip(self.aux_names, new_aux))
             return new_params, new_momenta, aux_out, outs
 
@@ -342,8 +344,9 @@ class SPMDTrainer:
                 outs, vjp, new_aux = jax.vjp(f, params, has_aux=True)
                 cot = tuple(jnp.ones_like(o) for o in outs)
                 (grads,) = vjp(cot)
-                new_params, new_momenta = opt_update(params, grads, momenta,
-                                                     lr)
+                with jax.named_scope("optimizer"):
+                    new_params, new_momenta = opt_update(params, grads,
+                                                         momenta, lr)
                 aux_out = dict(zip(self.aux_names, new_aux))
                 return (new_params, new_momenta, aux_out), ()
 
@@ -429,15 +432,19 @@ class SPMDTrainer:
                             scope=telemetry.watch_scope(self.symbol))
 
     def step(self, batch):
-        """One fused train step.  Returns the graph outputs."""
+        """One fused train step.  Returns the graph outputs.  In a
+        profiler trace the step is a `train_step` span (with its number)
+        on the host's line."""
         self._nstep += 1
-        rng = jax.random.fold_in(self._base_key, self._nstep)
-        dev_batch = self.shard_batch(batch)
-        self._watch_retrace("trainer.step", dev_batch)
-        self.params, self.momenta, self.aux, outs = self._step(
-            self.params, self.momenta, self.aux, dev_batch,
-            rng, jnp.float32(self.lr)
-        )
+        with jax.profiler.StepTraceAnnotation("train_step",
+                                              step_num=self._nstep):
+            rng = jax.random.fold_in(self._base_key, self._nstep)
+            dev_batch = self.shard_batch(batch)
+            self._watch_retrace("trainer.step", dev_batch)
+            self.params, self.momenta, self.aux, outs = self._step(
+                self.params, self.momenta, self.aux, dev_batch,
+                rng, jnp.float32(self.lr)
+            )
         return outs
 
     def run_steps(self, batch, nsteps):

@@ -125,25 +125,10 @@ def trace(logdir, create_perfetto_link=False):
         jax.profiler.stop_trace()
 
 
-def start(logdir):
-    """Imperative form of `trace` (reference `MXSetProfilerState(1)` shape)."""
-    global _active_logdir
-    if _active_logdir is not None:
-        raise MXNetError("profiler already active (%s)" % _active_logdir)
-    jax.profiler.start_trace(logdir)
-    _active_logdir = logdir
-
-
-def stop():
-    global _active_logdir
-    if _active_logdir is None:
-        raise MXNetError("profiler not active")
-    _active_logdir = None
-    jax.profiler.stop_trace()
-
-
 def annotate(name):
-    """Named span visible on the xprof timeline (host + device)."""
+    """Named span on the host's line of the profiler's trace, on the same
+    clock as the device's operations.  Costs a flag test when no trace is
+    being taken."""
     return jax.profiler.TraceAnnotation(name)
 
 

@@ -326,6 +326,11 @@ class TransformerKVModel:
             y = y + params[name + "_bias"]
         return y
 
+    @jax.named_scope("attn_out")
+    def _attn_out(self, params, attn, p):
+        return self._proj(params, attn, p + "attn_out")
+
+    @jax.named_scope("ffn")
     def _ffn(self, params, h2, p, tape=None):
         """Layer ``p``'s FFN over flattened (n, e) rows: the dense
         gelu(ffn1) @ ffn2 pair, or — when the geometry is MoE
@@ -365,6 +370,7 @@ class TransformerKVModel:
         return jnp.einsum("ned,ne->nd", y,
                           onehot * gate[:, None]).astype(h2.dtype)
 
+    @jax.named_scope("embed")
     def _embed(self, params, tokens):
         """Token embedding lookup — under weight quant the gathered int8
         rows dequantize by their per-row (per-vocab-entry) scale, so the
@@ -378,6 +384,7 @@ class TransformerKVModel:
                  * jnp.take(qs, ids, axis=0)[..., None]).astype(self.dtype)
         return x
 
+    @jax.named_scope("lm_head")
     def _head(self, params, x):
         return self._proj(params, layer_norm(
             x, params["final_ln_gamma"], params["final_ln_beta"], self.eps),
@@ -411,16 +418,17 @@ class TransformerKVModel:
             hn = layer_norm(x, params[p + "ln1_gamma"],
                             params[p + "ln1_beta"], self.eps)
             hf = hn.reshape(-1, e)
-            q = self._proj(params, hf, p + "q").reshape(b, s, e)
-            k = self._proj(params, hf, p + "k").reshape(b, s, e)
-            v = self._proj(params, hf, p + "v").reshape(b, s, e)
+            with jax.named_scope("qkv_proj"):
+                q = self._proj(params, hf, p + "q").reshape(b, s, e)
+                k = self._proj(params, hf, p + "k").reshape(b, s, e)
+                v = self._proj(params, hf, p + "v").reshape(b, s, e)
             kv.append(jnp.stack([k, v]))
             # (b, s, e) -> (b, h, s, hd): the training kernels' layout
             def heads(t):
                 return t.reshape(b, s, h, e // h).transpose(0, 2, 1, 3)
             attn = flash_attention(heads(q), heads(k), heads(v), causal=True)
             attn = attn.transpose(0, 2, 1, 3).reshape(-1, e)
-            x = x + self._proj(params, attn, p + "attn_out").reshape(b, s, e)
+            x = x + self._attn_out(params, attn, p).reshape(b, s, e)
             hn = layer_norm(x, params[p + "ln2_gamma"],
                             params[p + "ln2_beta"], self.eps)
             x = x + self._ffn(params, hn.reshape(-1, e), p,
@@ -452,18 +460,21 @@ class TransformerKVModel:
             p = "layer%d_" % i
             hn = layer_norm(x, params[p + "ln1_gamma"],
                             params[p + "ln1_beta"], self.eps)
-            q = self._proj(params, hn, p + "q")
-            k = self._proj(params, hn, p + "k")
-            v = self._proj(params, hn, p + "v")
+            with jax.named_scope("qkv_proj"):
+                q = self._proj(params, hn, p + "q")
+                k = self._proj(params, hn, p + "k")
+                v = self._proj(params, hn, p + "v")
             # scatter this step's K/V rows, then gather the bucket's slots.
             # Duplicate indices only occur among padding rows (shared trash
             # slot), whose values are never attended.
-            cache = cache.at[i, 0, slots, pos].set(k.astype(cache.dtype))
-            cache = cache.at[i, 1, slots, pos].set(v.astype(cache.dtype))
-            kc = cache[i, 0, slots]  # (b, S_max, e)
-            vc = cache[i, 1, slots]
+            with jax.named_scope("kv_scatter"):
+                cache = cache.at[i, 0, slots, pos].set(k.astype(cache.dtype))
+                cache = cache.at[i, 1, slots, pos].set(v.astype(cache.dtype))
+            with jax.named_scope("kv_gather"):
+                kc = cache[i, 0, slots]  # (b, S_max, e)
+                vc = cache[i, 1, slots]
             attn = decode_attention(q, kc, vc, pos, self.num_heads)
-            x = x + self._proj(params, attn, p + "attn_out")
+            x = x + self._attn_out(params, attn, p)
             hn = layer_norm(x, params[p + "ln2_gamma"],
                             params[p + "ln2_beta"], self.eps)
             x = x + self._ffn(params, hn, p, tape=moe_tape)
@@ -493,6 +504,25 @@ class TransformerKVModel:
     def _pack_pool(self, pool, scales):
         return pool if scales is None else (pool, scales)
 
+    @jax.named_scope("kv_scatter")
+    def _scatter_kv(self, pool, scales, layer, at, k, v):
+        """Write one layer's new K and V rows into the pool at ``at`` (a
+        (block,) index for whole blocks, a (block, offset) pair for single
+        rows) — under KV quantization quantize-on-write, one scale per
+        cached token row.  Returns (pool, scales)."""
+        if scales is None:
+            pool = pool.at[(layer, 0) + at].set(k.astype(pool.dtype))
+            pool = pool.at[(layer, 1) + at].set(v.astype(pool.dtype))
+            return pool, None
+        kq, ks = quantize_rows(k, self.kv_quant)
+        vq, vs = quantize_rows(v, self.kv_quant)
+        pool = pool.at[(layer, 0) + at].set(kq)
+        pool = pool.at[(layer, 1) + at].set(vq)
+        scales = scales.at[(layer, 0) + at].set(ks)
+        scales = scales.at[(layer, 1) + at].set(vs)
+        return pool, scales
+
+    @jax.named_scope("kv_gather")
     def _gather_ctx(self, pool, scales, layer, which, tables):
         """Materialize one layer's K (or V) context through the block
         tables, dequantizing in-graph when the pool stores int8: the
@@ -645,9 +675,10 @@ class TransformerKVModel:
             hn = layer_norm(x, params[p + "ln1_gamma"],
                             params[p + "ln1_beta"], self.eps)
             hf = hn.reshape(-1, e)
-            q = self._proj(params, hf, p + "q").reshape(b, c, e)
-            k = self._proj(params, hf, p + "k").reshape(b, c, e)
-            v = self._proj(params, hf, p + "v").reshape(b, c, e)
+            with jax.named_scope("qkv_proj"):
+                q = self._proj(params, hf, p + "q").reshape(b, c, e)
+                k = self._proj(params, hf, p + "k").reshape(b, c, e)
+                v = self._proj(params, hf, p + "v").reshape(b, c, e)
             # scatter the chunk's K/V rows into their blocks, THEN gather
             # the whole context so the chunk attends to itself too.
             # Rows past `length` write garbage into the chunk's own
@@ -655,22 +686,12 @@ class TransformerKVModel:
             # start+length first and every mask is `j <= own position`.
             kw = k.reshape(b, nb, bs, e)
             vw = v.reshape(b, nb, bs, e)
-            if scales is None:
-                pool = pool.at[i, 0, blk].set(kw.astype(pool.dtype))
-                pool = pool.at[i, 1, blk].set(vw.astype(pool.dtype))
-            else:
-                # quantize-on-write: one scale per cached token row
-                kq, ks = quantize_rows(kw, self.kv_quant)
-                vq, vs = quantize_rows(vw, self.kv_quant)
-                pool = pool.at[i, 0, blk].set(kq)
-                pool = pool.at[i, 1, blk].set(vq)
-                scales = scales.at[i, 0, blk].set(ks)
-                scales = scales.at[i, 1, blk].set(vs)
+            pool, scales = self._scatter_kv(pool, scales, i, (blk,), kw, vw)
             kc = self._gather_ctx(pool, scales, i, 0, tables)  # (b,m*bs,e)
             vc = self._gather_ctx(pool, scales, i, 1, tables)
             attn = chunk_attention(q, kc, vc, start, h)
-            x = x + self._proj(params, attn.reshape(-1, e),
-                               p + "attn_out").reshape(b, c, e)
+            x = x + self._attn_out(params, attn.reshape(-1, e),
+                                   p).reshape(b, c, e)
             hn = layer_norm(x, params[p + "ln2_gamma"],
                             params[p + "ln2_beta"], self.eps)
             x = x + self._ffn(params, hn.reshape(-1, e), p,
@@ -715,25 +736,24 @@ class TransformerKVModel:
             p = "layer%d_" % i
             hn = layer_norm(x, params[p + "ln1_gamma"],
                             params[p + "ln1_beta"], self.eps)
-            q = self._proj(params, hn, p + "q")
-            k = self._proj(params, hn, p + "k")
-            v = self._proj(params, hn, p + "v")
+            with jax.named_scope("qkv_proj"):
+                q = self._proj(params, hn, p + "q")
+                k = self._proj(params, hn, p + "k")
+                v = self._proj(params, hn, p + "v")
+            pool, scales = self._scatter_kv(pool, scales, i, (blk, off),
+                                            k, v)
             if scales is None:
-                pool = pool.at[i, 0, blk, off].set(k.astype(pool.dtype))
-                pool = pool.at[i, 1, blk, off].set(v.astype(pool.dtype))
-                attn = paged_decode_attention(q, pool[i, 0], pool[i, 1],
-                                              tables, pos, self.num_heads)
+                # the layer's slice of the pool fuses with the gather:
+                # taken inside the scope, or the fused copy has no name
+                with jax.named_scope("kv_gather"):
+                    k_pool, v_pool = pool[i, 0], pool[i, 1]
+                attn = paged_decode_attention(q, k_pool, v_pool, tables,
+                                              pos, self.num_heads)
             else:
-                kq, ks = quantize_rows(k, self.kv_quant)
-                vq, vs = quantize_rows(v, self.kv_quant)
-                pool = pool.at[i, 0, blk, off].set(kq)
-                pool = pool.at[i, 1, blk, off].set(vq)
-                scales = scales.at[i, 0, blk, off].set(ks)
-                scales = scales.at[i, 1, blk, off].set(vs)
                 kc = self._gather_ctx(pool, scales, i, 0, tables)
                 vc = self._gather_ctx(pool, scales, i, 1, tables)
                 attn = decode_attention(q, kc, vc, pos, self.num_heads)
-            x = x + self._proj(params, attn, p + "attn_out")
+            x = x + self._attn_out(params, attn, p)
             hn = layer_norm(x, params[p + "ln2_gamma"],
                             params[p + "ln2_beta"], self.eps)
             x = x + self._ffn(params, hn, p, tape=moe_tape)
@@ -866,27 +886,20 @@ class TransformerKVModel:
             hn = layer_norm(x, params[p + "ln1_gamma"],
                             params[p + "ln1_beta"], self.eps)
             hf = hn.reshape(-1, e)
-            q = self._proj(params, hf, p + "q").reshape(b, c, e)
-            k = self._proj(params, hf, p + "k").reshape(b, c, e)
-            v = self._proj(params, hf, p + "v").reshape(b, c, e)
+            with jax.named_scope("qkv_proj"):
+                q = self._proj(params, hf, p + "q").reshape(b, c, e)
+                k = self._proj(params, hf, p + "k").reshape(b, c, e)
+                v = self._proj(params, hf, p + "v").reshape(b, c, e)
             # scatter the whole fed span, then gather the context: the
             # draft tokens attend to each other causally, exactly as
             # sequential decode would have cached them one by one
-            if scales is None:
-                pool = pool.at[i, 0, blk, off].set(k.astype(pool.dtype))
-                pool = pool.at[i, 1, blk, off].set(v.astype(pool.dtype))
-            else:
-                kq, ks = quantize_rows(k, self.kv_quant)
-                vq, vs = quantize_rows(v, self.kv_quant)
-                pool = pool.at[i, 0, blk, off].set(kq)
-                pool = pool.at[i, 1, blk, off].set(vq)
-                scales = scales.at[i, 0, blk, off].set(ks)
-                scales = scales.at[i, 1, blk, off].set(vs)
+            pool, scales = self._scatter_kv(pool, scales, i, (blk, off),
+                                            k, v)
             kc = self._gather_ctx(pool, scales, i, 0, tables)
             vc = self._gather_ctx(pool, scales, i, 1, tables)
             attn = verify_attention(q, kc, vc, pos, length, h)
-            x = x + self._proj(params, attn.reshape(-1, e),
-                               p + "attn_out").reshape(b, c, e)
+            x = x + self._attn_out(params, attn.reshape(-1, e),
+                                   p).reshape(b, c, e)
             hn = layer_norm(x, params[p + "ln2_gamma"],
                             params[p + "ln2_beta"], self.eps)
             x = x + self._ffn(params, hn.reshape(-1, e), p,
@@ -895,6 +908,7 @@ class TransformerKVModel:
             b, c, self.vocab_size)
         return logits, self._pack_pool(pool, scales)
 
+    @jax.named_scope("kv_scatter")
     def write_prefill(self, cache, kv, length, slots):
         """Scatter a prefill's (num_layers, 2, b, s, embed) K/V block into
         the cache at ``slots`` (rows 0..s-1; s <= S_max).  ``length`` is

@@ -131,6 +131,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from .. import chaos
+from .. import profiler
 from .. import telemetry
 from .. import tracing
 from ..base import MXNetError
@@ -153,6 +154,15 @@ from .errors import (ServeError, ServeTimeout, ServeOverload,
 
 def _env_flag(name, default="1"):
     return os.environ.get(name, default).lower() not in ("0", "false", "no")
+
+
+def _phase(name):
+    """One phase of a scheduler iteration as a `sched.<name>` span on the
+    scheduler thread's line of the profiler's trace (docs/observability.md
+    "In the profiler's trace"): on the device trace's clock by
+    construction, a flag test when no trace is being taken.  The three
+    decode bodies use the same name where they do the same thing."""
+    return profiler.annotate("sched." + name)
 
 
 class _EngineFatal(Exception):
@@ -796,6 +806,7 @@ class ServingEngine:
         self._qlock = threading.Lock()
         self._qcond = threading.Condition(self._qlock)
         self._admitting = 0       # popped off _queue, prefill in flight
+        self._iter = {}           # the iteration's counts, anew each step()
         self._active = {}         # slot -> _Seq (insertion-ordered)
         self._free = list(range(self.max_batch))
         self._stopped = threading.Event()
@@ -917,8 +928,9 @@ class ServingEngine:
         identical sequences.  Greedy-only programs argmax (bit-for-bit
         the PR-7 tail)."""
         if not self._sampling:
-            return self._quant_guard(
-                logits, jnp.argmax(logits, axis=-1).astype(jnp.int32))
+            with jax.named_scope("sampler"):
+                greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return self._quant_guard(logits, greedy)
         temp, top_k, top_p, seed = samp
         return self._quant_guard(
             logits, sample_tokens(logits, temp, top_k, top_p, seed,
@@ -937,7 +949,8 @@ class ServingEngine:
                             pool) + self._moe_out(tape)
 
                 fn = self._jit(prog, (1,), ("repl", "cache")
-                               + ("repl",) * self._moe)
+                               + ("repl",) * self._moe,
+                               "serve_prefill_s%d" % s_bucket)
                 toks = self._put(np.zeros((1, s_bucket), np.int32))
                 zero = self._put(np.zeros((1,), np.int32))
                 one = self._put(np.ones((1,), np.int32))
@@ -959,7 +972,8 @@ class ServingEngine:
                         cache) + self._moe_out(tape)
 
             fn = self._jit(prog, (1,), ("repl", "cache")
-                           + ("repl",) * self._moe)
+                           + ("repl",) * self._moe,
+                           "serve_prefill_s%d" % s_bucket)
             toks = self._put(np.zeros((1, s_bucket), np.int32))
             one = self._put(np.ones((1,), np.int32))
             samp = tuple(self._put(a) for a in self._sample_placeholders(1))
@@ -979,7 +993,8 @@ class ServingEngine:
                             pool) + self._moe_out(tape)
 
                 fn = self._jit(prog, (1,), ("repl", "cache")
-                               + ("repl",) * self._moe)
+                               + ("repl",) * self._moe,
+                               "serve_decode_b%d" % b_bucket)
                 z = self._put(np.zeros((b_bucket,), np.int32))
                 tables = self._put(np.zeros((b_bucket, self._n_table),
                                             np.int32))
@@ -1000,7 +1015,8 @@ class ServingEngine:
                         cache) + self._moe_out(tape)
 
             fn = self._jit(prog, (1,), ("repl", "cache")
-                           + ("repl",) * self._moe)
+                           + ("repl",) * self._moe,
+                           "serve_decode_b%d" % b_bucket)
             z = self._put(np.zeros((b_bucket,), np.int32))
             samp = tuple(self._put(a)
                          for a in self._sample_placeholders(b_bucket))
@@ -1031,7 +1047,8 @@ class ServingEngine:
                 return (toks, pool) + self._moe_out(tape)
 
             fn = self._jit(prog, (1,), ("repl", "cache")
-                           + ("repl",) * self._moe)
+                           + ("repl",) * self._moe,
+                           "serve_mega_b%d" % b_bucket)
             z = self._put(np.zeros((b_bucket,), np.int32))
             tables = self._put(np.zeros((b_bucket, self._n_table),
                                         np.int32))
@@ -1049,8 +1066,9 @@ class ServingEngine:
         verified prefix is bit-identical to the non-speculative path."""
         b, c, v = logits.shape
         if not self._sampling:
-            return self._quant_guard(
-                logits, jnp.argmax(logits, axis=-1).astype(jnp.int32))
+            with jax.named_scope("sampler"):
+                greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return self._quant_guard(logits, greedy)
         newpos = pos.astype(jnp.int32)[:, None] + 1 + \
             jnp.arange(c, dtype=jnp.int32)[None]
         temp, top_k, top_p, seed = (jnp.repeat(a, c, axis=0) for a in samp)
@@ -1082,7 +1100,8 @@ class ServingEngine:
                         pool) + self._moe_out(tape)
 
             fn = self._jit(prog, (1,), ("repl", "cache")
-                           + ("repl",) * self._moe)
+                           + ("repl",) * self._moe,
+                           "serve_verify_b%d" % b_bucket)
             toks = self._put(np.zeros((b_bucket, c), np.int32))
             z = self._put(np.zeros((b_bucket,), np.int32))
             one = self._put(np.ones((b_bucket,), np.int32))
@@ -1114,7 +1133,7 @@ class ServingEngine:
             def prog(pool, src, dst):
                 return self.model.copy_block(pool, src, dst)
 
-            fn = self._jit(prog, (0,), ("cache",))
+            fn = self._jit(prog, (0,), ("cache",), "serve_cow")
             z = self._put(np.zeros((1,), np.int32))
             return fn.lower(self._cache, z, z).compile()
 
@@ -1140,7 +1159,7 @@ class ServingEngine:
             def prog(pool, dst, data):
                 return self.model.write_block(pool, dst, data)
 
-            fn = self._jit(prog, (0,), ("cache",))
+            fn = self._jit(prog, (0,), ("cache",), "serve_restore_k%d" % kb)
             z = self._put(np.zeros((kb,), np.int32))
             d = self._put_run(self.model.block_run_placeholder(
                 kb, self.block_size))
@@ -1212,34 +1231,38 @@ class ServingEngine:
             return (psh, ssh)
         return psh
 
-    def _jit(self, prog, donate, outs):
-        """`jax.jit` with EXPLICIT output shardings on a sub-mesh
-        replica — the pjit leg of the tentpole: the donated cache comes
-        back in its input sharding (anything else would defeat
-        donation) and token/count outputs land replicated for the
-        host's one-fetch-per-step discipline.  ``outs`` names each
-        output: "repl" or "cache".  Single-device engines build the
-        exact PR-19 jit — byte-identical programs."""
+    def _jit(self, prog, donate, outs, name):
+        """`jax.jit` of ``prog`` under ``name`` — the profiler's "XLA
+        Modules" line then reads `jit_serve_decode_b32(...)`, so a decode
+        launch is told from a prefill chunk — with EXPLICIT output
+        shardings on a sub-mesh replica: the donated cache comes back in
+        its input sharding (anything else would defeat donation) and
+        token/count outputs land replicated for the host's
+        one-fetch-per-step discipline.  ``outs`` names each output:
+        "repl" or "cache"."""
+        prog = self._scoped(prog, name)
         if self._mesh is None:
             return jax.jit(prog, donate_argnums=donate)
         m = {"repl": self._device, "cache": self._cache_sharding()}
         sh = tuple(m[o] for o in outs)
-        return jax.jit(self._scoped(prog), donate_argnums=donate,
+        return jax.jit(prog, donate_argnums=donate,
                        out_shardings=sh if len(sh) > 1 else sh[0])
 
-    def _scoped(self, prog):
-        """``prog`` with this replica's mesh scoped over its trace, so the
-        Pallas kernels — which GSPMD cannot partition — run per device
-        under shard_map (ops/pallas_kernels/_spmd.py).  Single-device
-        engines get ``prog`` back."""
-        if self._mesh is None:
-            return prog
+    def _scoped(self, prog, name):
+        """``prog`` as the function to jit, called ``name`` (the jitted
+        program's name in a profiler trace) — with this replica's mesh
+        scoped over its trace, so the Pallas kernels — which GSPMD cannot
+        partition — run per device under shard_map
+        (ops/pallas_kernels/_spmd.py).  Single-device engines get
+        ``prog`` itself back."""
+        fn = prog
+        if self._mesh is not None:
+            def fn(*args):
+                with MeshContext(self._mesh):
+                    return prog(*args)
 
-        def scoped(*args):
-            with MeshContext(self._mesh):
-                return prog(*args)
-
-        return scoped
+        fn.__name__ = fn.__qualname__ = name
+        return fn
 
     def _moe_out(self, tape):
         """The MoE programs' extra output: the launch's per-expert
@@ -2037,7 +2060,8 @@ class ServingEngine:
         """Cheap pool gauges on every allocator touch; the per-block
         fill map behind `blocks_frag` only when ``full`` (once per
         scheduler iteration — it walks every held block, which is not
-        free at large batch x depth)."""
+        free at large batch x depth), which then returns (blocks held by
+        a sequence, blocks the prefix cache parks alone)."""
         if not self._paged:
             return
         free = self._alloc.free_blocks
@@ -2083,6 +2107,7 @@ class ServingEngine:
                 telemetry.set_gauge(
                     self._gauge + "prefix_hit_rate",
                     round(self.stats["prefix_tokens"] / float(looked), 4))
+        return len(filled), parked
 
     def _rebuild_cache(self, reason):
         """The donated K/V buffer was consumed by a failed launch: every
@@ -3339,26 +3364,82 @@ class ServingEngine:
         inside the span, so the gauge collapses toward the walk/launch
         residue.  Only iterations that actually launched accumulate (an
         idle or admission-only iteration has no decode loop to
-        attribute).  Returns the number of sequences still active
-        (0 = idle)."""
+        attribute), and each leaves one replica-scoped `iteration` record
+        in the span store (docs/observability.md "Request tracing").  The
+        whole body is a `sched.iteration` span in a profiler trace.
+        Returns the number of sequences still active (0 = idle)."""
         t0 = time.perf_counter()
         h0 = self.stats["hidden_s"]
+        c0 = self.stats["prefill_chunks"]
         # fold settled expert-load rows (all but the newest — it may
         # still be in flight) into the per-expert gauges
         self._drain_moe()
-        if self._mega_m and not self._spec:
-            n = self._step_mega()
-        else:
-            n = self._step()
+        self._iter = {}
+        with _phase("iteration"):
+            if self._mega_m and not self._spec:
+                n = self._step_mega()
+            else:
+                n = self._step()
         dh = self.stats["hidden_s"] - h0
         if dh > 0:
-            wall = time.perf_counter() - t0
+            t1 = time.perf_counter()
+            wall = t1 - t0
             self.stats["wall_s"] += wall
             self.stats["host_s"] += max(0.0, wall - dh)
             telemetry.set_gauge(
                 self._gauge + "host_frac",
                 round(self.stats["host_s"] / self.stats["wall_s"], 4))
+            # the replica-scoped iteration record: the counts as taken
+            # where the work happened, in this iteration
+            tracing.add_span(
+                0, "iteration", self.name, t0, t1,
+                chunks=self.stats["prefill_chunks"] - c0, **self._iter)
         return n
+
+    def _grow(self):
+        """Cover the active rows' next positions with blocks, then the
+        iteration's full pool gauges."""
+        with _phase("grow"):
+            self._grow_active()
+            self._iter["blocks_live"], self._iter["blocks_parked"] = \
+                self._block_gauges(full=True)
+
+    def _advance_staged(self):
+        """Chunk dispatch.  Restores staged last iteration land BEFORE
+        new prefill chunks and admissions: their transfers already
+        overlapped the previous decode launch (handoff landings ride
+        the same two-stage overlap)."""
+        with _phase("prefill"):
+            self._advance_restores()
+            self._advance_landings()
+            self._advance_prefills()
+            self._stage_handoffs()
+
+    def _admit(self):
+        """Admit queued requests while a row is free."""
+        with _phase("admit"):
+            while self._free:
+                with self._qlock:
+                    req = self._queue.popleft() if self._queue else None
+                    if req is not None:
+                        self._admitting += 1
+                        self._qcond.notify_all()
+                if req is None:
+                    break
+                try:
+                    if req._cancelled or req.expired():
+                        # arrived expired between sweeps
+                        self._finish_dropped(req)
+                        continue
+                    if self._admit_one(req) is False:
+                        break  # block pool can't admit more this iteration
+                finally:
+                    with self._qlock:
+                        self._admitting -= 1
+            with self._qlock:
+                self._iter["queued"] = len(self._queue)
+            telemetry.set_gauge(self._gauge + "queue_depth",
+                                self._iter["queued"])
 
     def _step(self):
         """One single-step scheduler iteration: sweep deadlines/
@@ -3379,40 +3460,13 @@ class ServingEngine:
                 if evicted:
                     self._alloc.reclaim(evicted)
                     self._count_evictions(len(evicted))
-        self._sweep()
+        with _phase("sweep"):
+            self._sweep()
         if self._paged:
-            # restores staged last iteration land BEFORE new prefill
-            # chunks and admissions: their transfers already overlapped
-            # the previous decode launch (handoff landings ride the
-            # same two-stage overlap)
-            self._advance_restores()
-            self._advance_landings()
-            self._advance_prefills()
-            self._stage_handoffs()
-        while self._free:
-            with self._qlock:
-                req = self._queue.popleft() if self._queue else None
-                if req is not None:
-                    self._admitting += 1
-                    self._qcond.notify_all()
-            if req is None:
-                break
-            try:
-                if req._cancelled or req.expired():
-                    # arrived expired between sweeps
-                    self._finish_dropped(req)
-                    continue
-                if self._admit_one(req) is False:
-                    break  # block pool can't admit more this iteration
-            finally:
-                with self._qlock:
-                    self._admitting -= 1
-        with self._qlock:
-            telemetry.set_gauge(self._gauge + "queue_depth",
-                                len(self._queue))
+            self._advance_staged()
+        self._admit()
         if self._paged:
-            self._grow_active()
-            self._block_gauges(full=True)
+            self._grow()
         n = len(self._active)
         if n > self.stats["max_concurrent"]:
             self.stats["max_concurrent"] = n
@@ -3449,33 +3503,38 @@ class ServingEngine:
             return len(self._active) + self._pending_work()
         b = self._bucket_for(n, self.decode_buckets)
         seqs = [self._active[s] for s in slots]
-        token = np.zeros((b,), np.int32)
-        pos = np.zeros((b,), np.int32)
-        if self._paged:
-            tables = np.full((b, self._n_table), TRASH_BLOCK, np.int32)
-            for i, seq in enumerate(seqs):
-                token[i] = seq.last
-                pos[i] = seq.pos
-                tables[i, :len(seq.blocks)] = seq.blocks
-            extra, names = (self._put(tables),), ("token", "pos", "tables")
-        else:
-            slot_ids = np.full((b,), self.max_batch, np.int32)  # trash slot
-            for i, (slot, seq) in enumerate(zip(slots, seqs)):
-                token[i] = seq.last
-                pos[i] = seq.pos
-                slot_ids[i] = slot
-            extra, names = (self._put(slot_ids),), ("token", "pos", "slots")
-        samp = self._samp_device([s.req for s in seqs], b)
-        args = (self._put(token), self._put(pos)) + extra + samp
-        self._watch("decode", args,
-                    names + self._SAMPLE_NAMES[:len(samp)], b)
-        compiled = self._compiled_decode(b)
+        with _phase("pack"):
+            token = np.zeros((b,), np.int32)
+            pos = np.zeros((b,), np.int32)
+            if self._paged:
+                tables = np.full((b, self._n_table), TRASH_BLOCK, np.int32)
+                for i, seq in enumerate(seqs):
+                    token[i] = seq.last
+                    pos[i] = seq.pos
+                    tables[i, :len(seq.blocks)] = seq.blocks
+                extra, names = (self._put(tables),), \
+                    ("token", "pos", "tables")
+            else:
+                slot_ids = np.full((b,), self.max_batch, np.int32)  # trash
+                for i, (slot, seq) in enumerate(zip(slots, seqs)):
+                    token[i] = seq.last
+                    pos[i] = seq.pos
+                    slot_ids[i] = slot
+                extra, names = (self._put(slot_ids),), \
+                    ("token", "pos", "slots")
+            samp = self._samp_device([s.req for s in seqs], b)
+            args = (self._put(token), self._put(pos)) + extra + samp
+            self._watch("decode", args,
+                        names + self._SAMPLE_NAMES[:len(samp)], b)
+            compiled = self._compiled_decode(b)
+        self._iter.update(rows=n, bucket=b)
         t_launch = time.perf_counter()
         try:
             if chaos.serve_launch_error():
                 raise chaos.ChaosError("chaos: injected decode launch error")
-            nxt, self._cache = self._unpack(
-                compiled(self._params, self._cache, *args))
+            with _phase("launch"):
+                nxt, self._cache = self._unpack(
+                    compiled(self._params, self._cache, *args))
         except Exception as e:
             # scoped/transient: the donated cache survived — retry the
             # same decode next iteration, escalate after N consecutive
@@ -3483,7 +3542,8 @@ class ServingEngine:
             return len(self._active) + self._pending_work()
         self._launch_fails = 0
         t_fetch = time.perf_counter()
-        nxt = np.asarray(nxt)  # the one per-step host fetch (b ints)
+        with _phase("fetch"):
+            nxt = np.asarray(nxt)  # the one per-step host fetch (b ints)
         now = time.perf_counter()
         self.stats["fetch_wait_s"] += now - t_fetch
         self.stats["hidden_s"] += now - t_launch
@@ -3495,21 +3555,22 @@ class ServingEngine:
         telemetry.inc("serve.tokens", n)
         telemetry.inc("serve.decode_padded", b - n)
         telemetry.set_gauge(self._gauge + "batch_occupancy", n / float(b))
-        for i, (slot, seq) in enumerate(zip(slots, seqs)):
-            t = int(nxt[i])
-            if t < 0:
-                # quantization logit gate: never emit the flagged token
-                self._quant_trip_seq(slot, seq)
-                continue
-            finished = self._advance_one(seq, t)
-            if not finished and self._drafter is not None \
-                    and seq.ctx is not None:
-                # adaptive-fallback rounds still feed the drafter's
-                # store: a staggered twin drafts off this row's stream
-                self._drafter.observe(seq.ctx + [seq.last], 1)
-            if finished:
-                self._retire(slot, seq)
-            seq.req._publish()
+        with _phase("publish"):
+            for i, (slot, seq) in enumerate(zip(slots, seqs)):
+                t = int(nxt[i])
+                if t < 0:
+                    # quantization logit gate: never emit the flagged token
+                    self._quant_trip_seq(slot, seq)
+                    continue
+                finished = self._advance_one(seq, t)
+                if not finished and self._drafter is not None \
+                        and seq.ctx is not None:
+                    # adaptive-fallback rounds still feed the drafter's
+                    # store: a staggered twin drafts off this row's stream
+                    self._drafter.observe(seq.ctx + [seq.last], 1)
+                if finished:
+                    self._retire(slot, seq)
+                seq.req._publish()
         return len(self._active) + self._pending_work()
 
     def _step_mega(self):
@@ -3543,36 +3604,14 @@ class ServingEngine:
             # grow BEFORE launch: the megastep writes up to m positions
             # before the host sees any of them, so the whole span must
             # be covered (and exclusively owned) up front
-            self._grow_active()
-            self._block_gauges(full=True)
+            self._grow()
             inflight = self._launch_mega()
         # -- overlap window: host work the device no longer waits on --
         t_sweep = time.perf_counter()
-        self._sweep()
-        self._advance_restores()
-        self._advance_landings()
-        self._advance_prefills()
-        self._stage_handoffs()
-        while self._free:
-            with self._qlock:
-                req = self._queue.popleft() if self._queue else None
-                if req is not None:
-                    self._admitting += 1
-                    self._qcond.notify_all()
-            if req is None:
-                break
-            try:
-                if req._cancelled or req.expired():
-                    self._finish_dropped(req)
-                    continue
-                if self._admit_one(req) is False:
-                    break
-            finally:
-                with self._qlock:
-                    self._admitting -= 1
-        with self._qlock:
-            telemetry.set_gauge(self._gauge + "queue_depth",
-                                len(self._queue))
+        with _phase("sweep"):
+            self._sweep()
+        self._advance_staged()
+        self._admit()
         n = len(self._active)
         if n > self.stats["max_concurrent"]:
             self.stats["max_concurrent"] = n
@@ -3612,32 +3651,35 @@ class ServingEngine:
             return None
         b = self._bucket_for(nrows, self.decode_buckets)
         seqs = [self._active[s] for s in slots]
-        token = np.zeros((b,), np.int32)
-        pos = np.zeros((b,), np.int32)
-        left = np.zeros((b,), np.int32)
-        eos = np.full((b,), -1, np.int32)
-        tables = np.full((b, self._n_table), TRASH_BLOCK, np.int32)
-        for i, seq in enumerate(seqs):
-            token[i] = seq.last
-            pos[i] = seq.pos
-            left[i] = max(0, seq.req.max_new_tokens - seq.n_new)
-            if seq.req.eos_id is not None:
-                eos[i] = int(seq.req.eos_id)
-            tables[i, :len(seq.blocks)] = seq.blocks
-        samp = self._samp_device([s.req for s in seqs], b)
-        args = (self._put(token), self._put(pos), self._put(left),
-                self._put(eos), self._put(tables)) + samp
-        self._watch("megastep", args,
-                    ("token", "pos", "left", "eos", "tables")
-                    + self._SAMPLE_NAMES[:len(samp)], b)
-        compiled = self._compiled_mega(b)
+        with _phase("pack"):
+            token = np.zeros((b,), np.int32)
+            pos = np.zeros((b,), np.int32)
+            left = np.zeros((b,), np.int32)
+            eos = np.full((b,), -1, np.int32)
+            tables = np.full((b, self._n_table), TRASH_BLOCK, np.int32)
+            for i, seq in enumerate(seqs):
+                token[i] = seq.last
+                pos[i] = seq.pos
+                left[i] = max(0, seq.req.max_new_tokens - seq.n_new)
+                if seq.req.eos_id is not None:
+                    eos[i] = int(seq.req.eos_id)
+                tables[i, :len(seq.blocks)] = seq.blocks
+            samp = self._samp_device([s.req for s in seqs], b)
+            args = (self._put(token), self._put(pos), self._put(left),
+                    self._put(eos), self._put(tables)) + samp
+            self._watch("megastep", args,
+                        ("token", "pos", "left", "eos", "tables")
+                        + self._SAMPLE_NAMES[:len(samp)], b)
+            compiled = self._compiled_mega(b)
+        self._iter.update(rows=nrows, bucket=b)
         t_launch = time.perf_counter()
         try:
             if chaos.serve_launch_error():
                 raise chaos.ChaosError(
                     "chaos: injected megastep launch error")
-            out, self._cache = self._unpack(
-                compiled(self._params, self._cache, *args))
+            with _phase("launch"):
+                out, self._cache = self._unpack(
+                    compiled(self._params, self._cache, *args))
         except Exception as e:
             self._handle_launch_failure(e, "megastep")
             return None
@@ -3655,7 +3697,8 @@ class ServingEngine:
         already-finished)."""
         slots, seqs, out, nrows, b, t_launch = inflight
         t_fetch = time.perf_counter()
-        out = np.asarray(out)  # the one per-megastep host fetch
+        with _phase("fetch"):
+            out = np.asarray(out)  # the one per-megastep host fetch
         now = time.perf_counter()
         self.stats["fetch_wait_s"] += now - t_fetch
         # the launch->fetch span: every host cycle spent inside it
@@ -3672,37 +3715,38 @@ class ServingEngine:
         telemetry.set_gauge(self._gauge + "batch_occupancy",
                             nrows / float(b))
         emitted = retired = 0
-        for i, (slot, seq) in enumerate(zip(slots, seqs)):
-            if self._active.get(slot) is not seq:
-                # swept, preempted or vacated while in flight: its
-                # in-flight tokens drop on the floor; the journal still
-                # holds the pre-megastep position, so replay neither
-                # loses nor duplicates anything
-                continue
-            adv = 0
-            finished = tripped = False
-            for j in range(m):
-                t = int(out[i, j])
-                if t == -2:
-                    break
-                if t < 0:
-                    tripped = True
-                    break
-                finished = self._advance_one(seq, t)
-                adv += 1
-                if finished:
-                    break
-            emitted += adv
-            if tripped:
-                # quantization logit gate: never emit the flagged token
-                self._quant_trip_seq(slot, seq, "megastep")
-            elif finished:
-                retired += 1  # retirement decided in-graph, mid-scan
-                self._retire(slot, seq)
-            elif adv and self._drafter is not None \
-                    and seq.ctx is not None:
-                self._drafter.observe(seq.ctx + [seq.last], adv)
-            seq.req._publish()
+        with _phase("publish"):
+            for i, (slot, seq) in enumerate(zip(slots, seqs)):
+                if self._active.get(slot) is not seq:
+                    # swept, preempted or vacated while in flight: its
+                    # in-flight tokens drop on the floor; the journal still
+                    # holds the pre-megastep position, so replay neither
+                    # loses nor duplicates anything
+                    continue
+                adv = 0
+                finished = tripped = False
+                for j in range(m):
+                    t = int(out[i, j])
+                    if t == -2:
+                        break
+                    if t < 0:
+                        tripped = True
+                        break
+                    finished = self._advance_one(seq, t)
+                    adv += 1
+                    if finished:
+                        break
+                emitted += adv
+                if tripped:
+                    # quantization logit gate: never emit the flagged token
+                    self._quant_trip_seq(slot, seq, "megastep")
+                elif finished:
+                    retired += 1  # retirement decided in-graph, mid-scan
+                    self._retire(slot, seq)
+                elif adv and self._drafter is not None \
+                        and seq.ctx is not None:
+                    self._drafter.observe(seq.ctx + [seq.last], adv)
+                seq.req._publish()
         self.stats["tokens"] += emitted
         self.stats["megastep_tokens"] += emitted
         self.stats["ingraph_retired"] += retired
@@ -3817,64 +3861,72 @@ class ServingEngine:
         k = self._spec_k
         c = k + 1
         seqs = [self._active[r] for r in rows]
-        token = np.zeros((b, c), np.int32)
-        pos = np.zeros((b,), np.int32)
-        length = np.ones((b,), np.int32)
-        tables = np.full((b, self._n_table), TRASH_BLOCK, np.int32)
-        for i, seq in enumerate(seqs):
-            token[i, 0] = seq.last
-            pos[i] = seq.pos
-            length[i] = min(c, self.model.seq_len - seq.pos)
-            tables[i, :len(seq.blocks)] = seq.blocks
-        pos_d = self._put(pos)
-        tables_d = self._put(tables)
-        samp = self._samp_device([s.req for s in seqs], b)
-        tok0 = token[:, 0].copy()
-        dev = (self._put(tok0), pos_d, tables_d) \
-            if self._drafter.needs_device else None
-        drafts = self._drafter.propose(seqs, k, b, host=(tok0, pos, tables),
-                                       dev=dev, samp=samp)
-        if isinstance(drafts, tuple):
-            drafts, confident = drafts
-            if not np.asarray(confident)[:n].any():
-                # adaptive speculation: with no usable draft anywhere in
-                # the batch a verify could only advance one token per
-                # row — run the (cheaper) plain round instead; with
-                # megastep on the fallback fuses m steps (the megastep
-                # x speculation interlock: both paths run _advance_one
-                # and share the max(k+1, m) block-span bookkeeping)
-                if self._mega_m:
-                    return self._decode_mega()
-                return self._decode_plain()
-        if chaos.enabled() and chaos.serve_draft_junk():
-            # `draft_junk:P`: deterministically corrupt the round's
-            # proposals — parity must hold, only the accept rate drops
-            drafts = (np.asarray(drafts, np.int64) + 1
-                      + np.arange(k, dtype=np.int64)[None]) \
-                % self.model.vocab_size
-            self.stats["spec_junk_rounds"] += 1
-            telemetry.inc("serve.chaos_draft_junk")
-        token[:, 1:] = np.asarray(drafts, np.int32)[:b]
-        token_d = self._put(token)
-        length_d = self._put(length)
-        args = (token_d, pos_d, length_d, tables_d) + samp
-        self._watch("verify", args,
-                    ("tokens", "pos", "length", "tables")
-                    + self._SAMPLE_NAMES[:len(samp)], b)
-        compiled = self._compiled_verify(b)
+        with _phase("pack"):
+            token = np.zeros((b, c), np.int32)
+            pos = np.zeros((b,), np.int32)
+            length = np.ones((b,), np.int32)
+            tables = np.full((b, self._n_table), TRASH_BLOCK, np.int32)
+            for i, seq in enumerate(seqs):
+                token[i, 0] = seq.last
+                pos[i] = seq.pos
+                length[i] = min(c, self.model.seq_len - seq.pos)
+                tables[i, :len(seq.blocks)] = seq.blocks
+            pos_d = self._put(pos)
+            tables_d = self._put(tables)
+            samp = self._samp_device([s.req for s in seqs], b)
+            tok0 = token[:, 0].copy()
+            dev = (self._put(tok0), pos_d, tables_d) \
+                if self._drafter.needs_device else None
+            drafts = self._drafter.propose(seqs, k, b,
+                                           host=(tok0, pos, tables),
+                                           dev=dev, samp=samp)
+            usable = True
+            if isinstance(drafts, tuple):
+                drafts, confident = drafts
+                usable = np.asarray(confident)[:n].any()
+        if not usable:
+            # adaptive speculation: with no usable draft anywhere in
+            # the batch a verify could only advance one token per
+            # row — run the (cheaper) plain round instead; with
+            # megastep on the fallback fuses m steps (the megastep
+            # x speculation interlock: both paths run _advance_one
+            # and share the max(k+1, m) block-span bookkeeping)
+            if self._mega_m:
+                return self._decode_mega()
+            return self._decode_plain()
+        with _phase("pack"):
+            if chaos.enabled() and chaos.serve_draft_junk():
+                # `draft_junk:P`: deterministically corrupt the round's
+                # proposals — parity must hold, only the accept rate drops
+                drafts = (np.asarray(drafts, np.int64) + 1
+                          + np.arange(k, dtype=np.int64)[None]) \
+                    % self.model.vocab_size
+                self.stats["spec_junk_rounds"] += 1
+                telemetry.inc("serve.chaos_draft_junk")
+            token[:, 1:] = np.asarray(drafts, np.int32)[:b]
+            token_d = self._put(token)
+            length_d = self._put(length)
+            args = (token_d, pos_d, length_d, tables_d) + samp
+            self._watch("verify", args,
+                        ("tokens", "pos", "length", "tables")
+                        + self._SAMPLE_NAMES[:len(samp)], b)
+            compiled = self._compiled_verify(b)
+        self._iter.update(rows=n, bucket=b)
         t_launch = time.perf_counter()
         try:
             if chaos.serve_launch_error():
                 raise chaos.ChaosError("chaos: injected verify launch "
                                        "error")
-            out, self._cache = self._unpack(
-                compiled(self._params, self._cache, *args))
+            with _phase("launch"):
+                out, self._cache = self._unpack(
+                    compiled(self._params, self._cache, *args))
         except Exception as e:
             self._handle_launch_failure(e, "verify")
             return len(self._active) + self._pending_work()
         self._launch_fails = 0
         t_fetch = time.perf_counter()
-        out = np.asarray(out)  # (b, k+2): picks then n_accepted
+        with _phase("fetch"):
+            out = np.asarray(out)  # (b, k+2): picks then n_accepted
         now = time.perf_counter()
         self.stats["fetch_wait_s"] += now - t_fetch
         self.stats["hidden_s"] += now - t_launch
@@ -3888,50 +3940,51 @@ class ServingEngine:
         telemetry.set_gauge(self._gauge + "batch_occupancy", n / float(b))
         emitted_total = 0
         seqs_n_new = [s.n_new for s in seqs]
-        for i, (row, seq) in enumerate(zip(rows, seqs)):
-            # drafts past this row's in-range span can never be emitted
-            # (their K/V went to the trash block); clamp acceptance so
-            # the host loop below cannot walk into them
-            n_acc = min(int(out[i, c]), int(length[i]) - 1)
-            self.stats["spec_proposed"] += k
-            self._count("spec.proposed", k)
-            finished = False
-            tripped = False
-            acc_emitted = 0
-            for j in range(n_acc + 1):
-                t = int(out[i, j])
-                if t < 0:
-                    # quantization logit gate: tokens accepted BEFORE
-                    # the flagged position passed it (identical context
-                    # to sequential decode); the trip retires the row
-                    # into the exact-replay requeue from right here
-                    tripped = True
-                    break
-                emitted_total += 1
-                if j < n_acc:
-                    acc_emitted += 1
-                if self._advance_one(seq, t):
-                    finished = True
-                    break
-            # a trip discards the tail past the flagged position — the
-            # accept counters (and the accept_rate gauge the chaos runs
-            # watch) only count drafts that actually reached the output
-            n_counted = acc_emitted if tripped else n_acc
-            self.stats["spec_accepted"] += n_counted
-            if n_counted:
-                self._count("spec.accepted", n_counted)
-            if tripped:
-                self._quant_trip_seq(row, seq, "verify")
-            elif finished:
-                self._retire(row, seq)
-            else:
-                if seq.n_new > seqs_n_new[i]:
-                    # let a learning drafter see this row's fresh tokens
-                    # now (a concurrent twin drafts off them next round)
-                    self._drafter.observe(seq.ctx + [seq.last],
-                                          seq.n_new - seqs_n_new[i])
-                self._rewind_blocks(seq)
-            seq.req._publish()
+        with _phase("publish"):
+            for i, (row, seq) in enumerate(zip(rows, seqs)):
+                # drafts past this row's in-range span can never be emitted
+                # (their K/V went to the trash block); clamp acceptance so
+                # the host loop below cannot walk into them
+                n_acc = min(int(out[i, c]), int(length[i]) - 1)
+                self.stats["spec_proposed"] += k
+                self._count("spec.proposed", k)
+                finished = False
+                tripped = False
+                acc_emitted = 0
+                for j in range(n_acc + 1):
+                    t = int(out[i, j])
+                    if t < 0:
+                        # quantization logit gate: tokens accepted BEFORE
+                        # the flagged position passed it (identical context
+                        # to sequential decode); the trip retires the row
+                        # into the exact-replay requeue from right here
+                        tripped = True
+                        break
+                    emitted_total += 1
+                    if j < n_acc:
+                        acc_emitted += 1
+                    if self._advance_one(seq, t):
+                        finished = True
+                        break
+                # a trip discards the tail past the flagged position — the
+                # accept counters (and the accept_rate gauge the chaos runs
+                # watch) only count drafts that actually reached the output
+                n_counted = acc_emitted if tripped else n_acc
+                self.stats["spec_accepted"] += n_counted
+                if n_counted:
+                    self._count("spec.accepted", n_counted)
+                if tripped:
+                    self._quant_trip_seq(row, seq, "verify")
+                elif finished:
+                    self._retire(row, seq)
+                else:
+                    if seq.n_new > seqs_n_new[i]:
+                        # let a learning drafter see this row's fresh tokens
+                        # now (a concurrent twin drafts off them next round)
+                        self._drafter.observe(seq.ctx + [seq.last],
+                                              seq.n_new - seqs_n_new[i])
+                    self._rewind_blocks(seq)
+                seq.req._publish()
         self.stats["tokens"] += emitted_total
         telemetry.inc("serve.tokens", emitted_total)
         if self.stats["spec_proposed"]:

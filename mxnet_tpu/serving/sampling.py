@@ -64,6 +64,7 @@ def _mask_top_k_top_p(scaled, top_k, top_p):
     return jnp.where(scaled >= thr, scaled, -jnp.inf)
 
 
+@jax.named_scope("sampler")
 def sample_tokens(logits, temperature, top_k, top_p, seed, newpos):
     """One token per row from per-row sampling params, inside the
     compiled program.

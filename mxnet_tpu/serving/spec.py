@@ -367,7 +367,8 @@ class ModelDrafter(Drafter):
                 # its step only writes proposal k's own draft K/V
                 return toks[:k].T, pool
 
-            fn = jax.jit(e._scoped(prog), donate_argnums=(1,))
+            fn = jax.jit(e._scoped(prog, "serve_draft_propose_b%d" % b),
+                         donate_argnums=(1,))
             z = e._put(np.zeros((b,), np.int32))
             tables = e._put(np.zeros((b, e._n_table), np.int32))
             samp = tuple(e._put(a) for a in e._sample_placeholders(b))
@@ -385,7 +386,8 @@ class ModelDrafter(Drafter):
                     params, pool, tokens, start, length, tables)
                 return pool
 
-            fn = jax.jit(e._scoped(prog), donate_argnums=(1,))
+            fn = jax.jit(e._scoped(prog, "serve_draft_prefill_s%d" % s),
+                         donate_argnums=(1,))
             toks = e._put(np.zeros((1, s), np.int32))
             zero = e._put(np.zeros((1,), np.int32))
             one = e._put(np.ones((1,), np.int32))
@@ -399,10 +401,10 @@ class ModelDrafter(Drafter):
         e = self._engine
 
         def build():
-            def prog(pool, src, dst):
+            def serve_draft_cow(pool, src, dst):
                 return self.model.copy_block(pool, src, dst)
 
-            fn = jax.jit(prog, donate_argnums=(0,))
+            fn = jax.jit(serve_draft_cow, donate_argnums=(0,))
             z = e._put(np.zeros((1,), np.int32))
             return fn.lower(self._pool, z, z).compile()
 

@@ -29,11 +29,17 @@ Span model (docs/observability.md "Request tracing"):
 * **leaf spans** — one-shot child spans under the current interval
   (``prefill_chunk``, ``handoff_pack``, ``handoff_land``) and
   replica-scoped spans with trace id 0 (``megastep``, ``host_sweep``,
-  ``spec_round``) reusing the PR-16 launch→fetch stamps.
+  ``spec_round``) reusing the PR-16 launch→fetch stamps, and one
+  ``iteration`` span per scheduler iteration that launched, carrying the
+  counts taken where the work happens (rows, bucket, chunks, queued,
+  blocks_live, blocks_parked).
 
-Flight recorder: every replica keeps a bounded ring of the last N span
-closes and events (`MXNET_TRACE_RING`); `dump()` snapshots it into ONE
-atomic `flight_recorder` JSONL record on typed failures, chaos trips and
+The span store: every replica keeps a bounded ring of the last N span
+closes and events (`MXNET_TRACE_RING`, 16,384: minutes of serving).  It is
+process-wide and outlives `engine.stop()`, so `window()` hands a reader
+the records of a measured window after the engine is gone.  Flight
+recorder: `dump()` snapshots the ring's newest 256 records into ONE atomic
+`flight_recorder` JSONL record on typed failures, chaos trips and
 scheduler death, so chaos-gate postmortems stop being print-debugging.
 
 `MXNET_SERVE_TRACING=0` turns every call site into a no-op — bit-for-bit
@@ -45,13 +51,14 @@ import os
 import threading
 import time
 from collections import deque
+from itertools import islice
 
 from . import telemetry
 
 __all__ = [
     "PHASES", "ATTR_PHASES", "enabled", "tracer", "reset",
     "open_trace", "phase", "add_span", "finish", "on_finish",
-    "context", "adopt", "note", "dump", "snapshot", "spans",
+    "context", "adopt", "note", "dump", "snapshot", "window", "spans",
 ]
 
 # The phase taxonomy.  mxlint's span-drift rule checks every phase name
@@ -72,6 +79,7 @@ PHASES = (
     "megastep",       # replica: one m-step launch->fetch window
     "host_sweep",     # replica: the overlap-window host work
     "spec_round",     # replica: one draft->verify->accept round
+    "iteration",      # replica: one scheduler iteration that launched
     "gateway_send",   # leaf: gateway submit -> last SSE byte flushed
 )
 
@@ -80,6 +88,7 @@ ATTR_PHASES = ("queue_wait", "prefill", "replay", "restore_wait",
                "handoff_wait", "decode")
 
 _MAX_TRACES = 8192   # open-trace bookkeeping cap (leak backstop)
+_DUMP_TAIL = 256     # records of the ring a flight-recorder dump carries
 
 
 def enabled():
@@ -102,7 +111,7 @@ class Tracer:
         self._open = {}        # trace -> [sid, phase, t0, replica, attrs]
         self._acc = {}         # trace -> {phase: total seconds}
         self._rings = {}       # replica -> deque of span/event dicts
-        cap = int(os.environ.get("MXNET_TRACE_RING", "256")
+        cap = int(os.environ.get("MXNET_TRACE_RING", "16384")
                   if ring is None else ring)
         self._ring_cap = max(8, cap)
 
@@ -276,7 +285,8 @@ class Tracer:
         and emit it atomically (one sink write = one JSONL line) — the
         postmortem for typed failures, chaos trips and scheduler death."""
         with self._lock:
-            tail = list(self._rings.get(replica, ()))
+            ring = self._rings.get(replica, ())
+            tail = list(islice(ring, max(0, len(ring) - _DUMP_TAIL), None))
         rec = {"type": "flight_recorder", "replica": replica,
                "reason": reason, "time": time.time(), "n": len(tail),
                "ring_cap": self._ring_cap, "tail": tail}
@@ -289,6 +299,13 @@ class Tracer:
         """The replica's current recorder ring (tests)."""
         with self._lock:
             return list(self._rings.get(replica, ()))
+
+    def window(self, replica, t0, t1):
+        """The replica's span records that closed in [t0, t1), on the
+        `perf_counter` clock the spans are stamped with."""
+        with self._lock:
+            return [r for r in self._rings.get(replica, ())
+                    if r.get("type") == "span" and t0 <= r["t1"] < t1]
 
     def open_traces(self):
         """Trace ids with an unclosed root (tests: leak detection)."""
@@ -397,6 +414,12 @@ def snapshot(replica):
     if _TRACER is None:
         return []
     return _TRACER.snapshot(replica)
+
+
+def window(replica, t0, t1):
+    if _TRACER is None:
+        return []
+    return _TRACER.window(replica, t0, t1)
 
 
 def spans(records):

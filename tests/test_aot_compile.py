@@ -234,6 +234,41 @@ def test_sharded_serving_programs_compile_for_four_devices(on_tpu, topo,
     assert comp.as_text().count("tpu_custom_call") == 2 * L + 1
 
 
+def test_decode_pool_copy_is_named_by_the_tpu_compiler(on_tpu, one_chip):
+    """The TPU compiler fuses a layer's slice of the pool with the gather
+    through the block tables (`slice_bitcast_fusion`, a quarter of a decode
+    launch on the chip) and names the fusion after the slice: the slice has
+    to be taken inside the `kv_gather` scope, or the largest operation of a
+    decode launch reads as time under no scope (PERF.md, PR 25).  GPT-2
+    large's widths, one layer."""
+    import re
+
+    from mxnet_tpu.base import bfloat16
+    from mxnet_tpu.serving import TransformerKVModel
+
+    V, S, E, bs, n_blocks, b = 512, 1024, 1280, 16, 64, 4
+    model = TransformerKVModel(V, S, num_layers=1, num_heads=20, num_embed=E,
+                               dtype=bfloat16)
+    params = {n: _bf16(s, one_chip) for n, s in model.param_shapes().items()}
+    pool = _bf16((1, 2, n_blocks, bs, E), one_chip)
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    def serve_decode_b4(params, pool, token, pos, tables):
+        logits, pool = model.decode_paged(params, pool, token, pos, tables)
+        return jnp.argmax(logits, axis=-1), pool
+
+    text = jax.jit(serve_decode_b4, donate_argnums=(1,)).lower(
+        params, pool, ints(b), ints(b), ints(b, S // bs)).compile().as_text()
+    names = set(re.findall(r'op_name="jit\(serve_decode_b4\)/([^"]+)"', text))
+    assert {"kv_gather/squeeze", "kv_gather/gather",
+            "kv_scatter/scatter"} <= names
+    # none of the pool's own operations outside its scopes
+    assert not names & {"squeeze", "gather", "scatter", "dynamic_slice",
+                        "dynamic_update_slice"}
+
+
 # -- whole train steps (toy widths: seconds each) ---------------------------
 
 

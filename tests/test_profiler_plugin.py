@@ -24,7 +24,8 @@ def test_trace_writes_logdir(tmp_path):
 def test_nested_trace_rejected(tmp_path):
     with mx.profiler.trace(str(tmp_path / "a")):
         with pytest.raises(MXNetError):
-            mx.profiler.start(str(tmp_path / "b"))
+            with mx.profiler.trace(str(tmp_path / "b")):
+                pass
 
 
 def test_step_timer():

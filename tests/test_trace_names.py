@@ -1,0 +1,233 @@
+"""The names the program puts on its work (ISSUE-25, part B): every scope,
+program name and kernel name is found in what the programs lower to, so a
+refactor that drops one fails here, not in the next chip trace.
+
+`lower(...).as_text(debug_info=True)` carries the name stack of every
+operation as its location; a compiled program's text carries it as
+`op_name`, and its first line names the module as the profiler's "XLA
+Modules" line will."""
+import re
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import mxnet_tpu as mx
+from mxnet_tpu import models
+from mxnet_tpu.parallel import SPMDTrainer, make_mesh
+from mxnet_tpu.serving import ServingEngine, TransformerKVModel
+
+SERVING_SCOPES = {"embed", "qkv_proj", "kv_scatter", "kv_gather", "attn_out",
+                  "ffn", "lm_head", "sampler"}
+
+
+def _scopes(text):
+    """Every scope any operation of a compiled program's text lies under."""
+    out = set()
+    for name in re.findall(r'op_name="([^"]+)"', text):
+        out.update(re.sub(r"^(?:\w+\()+|\)+$", "", part)
+                   for part in name.split("/")[1:-1])
+    return out
+
+
+@pytest.fixture(scope="module")
+def engine():
+    model = TransformerKVModel(61, 32, num_layers=2, num_heads=2,
+                               num_embed=32)
+    return ServingEngine(model, model.init_params(np.random.RandomState(3)),
+                         max_batch=4, block_size=4, n_blocks=32,
+                         prefill_buckets=[8], decode_buckets=[2],
+                         megastep_steps=2, spec_k=2, name="names")
+
+
+@pytest.mark.parametrize("build,module,attention", [
+    ("_compiled_decode", "jit_serve_decode_b2", "decode_attention"),
+    ("_compiled_prefill", "jit_serve_prefill_s8", "chunk_attention"),
+    ("_compiled_mega", "jit_serve_mega_b2", "decode_attention"),
+    ("_compiled_verify", "jit_serve_verify_b2", "verify_attention"),
+])
+def test_serving_programs_carry_their_name_and_scopes(engine, build, module,
+                                                      attention):
+    bucket = 8 if build == "_compiled_prefill" else 2
+    if build == "_compiled_mega":
+        engine._mega_m = 2
+    if build == "_compiled_verify":
+        engine._spec_k = 2
+    text = getattr(engine, build)(bucket).as_text()
+    assert text.startswith("HloModule %s," % module)
+    assert _scopes(text) >= SERVING_SCOPES | {attention}
+
+
+def test_pool_programs_carry_their_names(engine):
+    assert engine._compiled_cow().as_text().startswith(
+        "HloModule jit_serve_cow,")
+    assert engine._compiled_restore(2).as_text().startswith(
+        "HloModule jit_serve_restore_k2,")
+
+
+def test_train_step_runs_each_node_and_the_optimizer_under_its_name():
+    net = models.get_transformer_lm(vocab_size=64, seq_len=16, num_layers=1,
+                                    num_heads=2, num_embed=32,
+                                    num_ffn_hidden=64, fused_head=True)
+    mesh = make_mesh(shape=(1,), axis_names=("data",),
+                     devices=jax.devices()[:1])
+    mx.random.seed(0)
+    trainer = SPMDTrainer(net, mesh, data_shapes={"data": (2, 16),
+                                                  "softmax_label": (2, 16)},
+                          optimizer="adam", abstract=True)
+    text = trainer.lower_step(
+        {"data": np.int32, "softmax_label": np.int32}).as_text()
+    assert text.startswith("HloModule jit_step,")
+    scopes = _scopes(text)
+    # forward and backward of a node both read as the node
+    assert scopes >= {"optimizer", "pred", "embed", "layer0_attn",
+                      "layer0_ffn1", "final_ln"}
+    assert re.search(r'op_name="jit\(step\)/transpose\(jvp\(pred\)\)/', text)
+    assert re.search(r'op_name="jit\(step\)/jvp\(layer0_ffn1\)/', text)
+
+
+def test_train_steps_reach_the_profilers_trace(tmp_path):
+    """`SPMDTrainer.step` is a `train_step` span on the host's plane of a
+    profiler trace, one a step: what `dispatch_ms.train` reads (with the
+    benchmark's raw reader, as it does)."""
+    import os
+    import sys
+
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from benchmark import xplane_raw
+    from benchmark.readers import span_ms
+
+    net = models.get_transformer_lm(vocab_size=64, seq_len=16, num_layers=1,
+                                    num_heads=2, num_embed=32,
+                                    num_ffn_hidden=64, fused_head=True)
+    mesh = make_mesh(shape=(1,), axis_names=("data",),
+                     devices=jax.devices()[:1])
+    mx.random.seed(0)
+    trainer = SPMDTrainer(net, mesh, data_shapes={"data": (2, 16),
+                                                  "softmax_label": (2, 16)},
+                          optimizer="adam")
+    batch = {"data": np.ones((2, 16), np.int32),
+             "softmax_label": np.ones((2, 16), np.int32)}
+    trainer.step(batch)                      # compiled outside the trace
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        for _ in range(3):
+            outs = trainer.step(batch)
+        jax.block_until_ready(outs)
+    finally:
+        jax.profiler.stop_trace()
+    path = xplane_raw.trace.find_xplane(str(tmp_path))
+    spans = [(s, d, m["name"].split("#")[0], line)
+             for plane in xplane_raw.planes(
+                 path, host_prefixes=xplane_raw.PROGRAM_SPANS)
+             if plane["name"].startswith("/host:")
+             for line, events in plane["lines"].items()
+             for s, d, m in events]
+    assert [name for _, _, name, _ in spans] == ["train_step"] * 3
+    assert all(d > 0 for _, d, _, _ in spans)
+    # the reader, on what a device trace would hold beside them
+    notes = []
+    run = type("Run", (), {"_raw": {"ops": [], "modules": [],
+                                    "spans": sorted(spans)},
+                           "note": notes.append})()
+    assert span_ms.read(run, span="train_step", q=50) \
+        == pytest.approx(sorted(d for _, d, _, _ in spans)[1] / 1e6)
+
+
+@pytest.fixture
+def for_tpu(monkeypatch):
+    """The kernels' backend gates see a TPU, and `lower` targets one: the
+    Mosaic kernels are serialised at lowering, with no chip and no TPU
+    compiler (nothing is compiled)."""
+    for pin in ("MXNET_FLASH_IMPL", "MXNET_FLASH_BSD_KERNEL", "MXNET_LN_IMPL",
+                "MXNET_FLASH_LAYOUT", "MXNET_FLASH_BWD", "MXNET_CE_SHARD",
+                "MXNET_CE_SINGLE_PASS"):
+        monkeypatch.delenv(pin, raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def lower(fn, *shapes):
+        return jax.jit(fn).trace(*shapes).lower(
+            lowering_platforms=("tpu",)).as_text(debug_info=True)
+
+    return lower
+
+
+def _kernels(text):
+    return set(re.findall(r'kernel_name = "(\w+)"', text))
+
+
+FLASH_KERNELS = {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
+
+
+@pytest.mark.parametrize("pin", [None, "pallas_ds"])
+def test_flash_kernels_are_named_in_their_lowered_calls(for_tpu,
+                                                        monkeypatch, pin):
+    from mxnet_tpu.ops.pallas_kernels import flash_attention
+
+    if pin:
+        monkeypatch.setenv("MXNET_FLASH_IMPL", pin)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True)
+                       .astype(jnp.float32))
+
+    sh = jax.ShapeDtypeStruct((2, 4, 512, 64), jnp.bfloat16)
+    text = for_tpu(jax.grad(loss, argnums=(0, 1, 2)), sh, sh, sh)
+    assert _kernels(text) == FLASH_KERNELS
+    # the name is a scope too: it reaches the trace's `tf_op`
+    assert 'jvp(flash_fwd)/pallas_call"' in text
+    assert 'transpose(jvp(flash_bwd_dq))/pallas_call"' in text
+
+
+@pytest.mark.parametrize("structure", ["loop", "stream"])
+def test_flash_bsd_kernels_share_the_names(for_tpu, monkeypatch, structure):
+    from mxnet_tpu.ops.pallas_kernels.flash_attention import \
+        flash_attention_bsd
+
+    monkeypatch.setenv("MXNET_FLASH_BSD_KERNEL", structure)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention_bsd(q, k, v, 2, causal=True)
+                       .astype(jnp.float32))
+
+    sh = jax.ShapeDtypeStruct((2, 512, 256), jnp.bfloat16)
+    text = for_tpu(jax.grad(loss, argnums=(0, 1, 2)), sh, sh, sh)
+    assert _kernels(text) == FLASH_KERNELS
+
+
+@pytest.mark.parametrize("single_pass,names", [
+    ("1", {"fused_ce_fwd", "fused_ce_bwd_dw"}),
+    ("0", {"fused_ce_fwd", "fused_ce_bwd_dx", "fused_ce_bwd_dw"}),
+])
+def test_fused_ce_kernels_are_named(for_tpu, monkeypatch, single_pass,
+                                    names):
+    from mxnet_tpu.ops.pallas_kernels import fused_softmax_ce
+
+    monkeypatch.setenv("MXNET_CE_SINGLE_PASS", single_pass)
+
+    def loss(x, w, label):
+        return jnp.sum(fused_softmax_ce(x, w, None, label))
+
+    text = for_tpu(jax.grad(loss, argnums=(0, 1)),
+                   jax.ShapeDtypeStruct((2048, 256), jnp.bfloat16),
+                   jax.ShapeDtypeStruct((2048, 256), jnp.bfloat16),
+                   jax.ShapeDtypeStruct((2048,), jnp.int32))
+    assert _kernels(text) == names
+
+
+def test_layer_norm_kernels_are_named(for_tpu):
+    from mxnet_tpu.ops.pallas_kernels.layer_norm import layer_norm
+
+    def loss(x, g, b):
+        return jnp.sum(layer_norm(x, g, b, 1e-5).astype(jnp.float32))
+
+    text = for_tpu(jax.grad(loss, argnums=(0, 1, 2)),
+                   jax.ShapeDtypeStruct((1024, 256), jnp.bfloat16),
+                   jax.ShapeDtypeStruct((256,), jnp.float32),
+                   jax.ShapeDtypeStruct((256,), jnp.float32))
+    assert _kernels(text) == {"layer_norm_fwd", "layer_norm_bwd"}

@@ -664,3 +664,139 @@ def test_span_phase_clean_including_ifexp(tmp_path):
         "tools/trace_report.py": _FIXTURE_REPORT,
     }, rules=_SPAN_RULES)
     assert res.findings == []
+
+
+# ---------------------------------------------------------------------------
+# 10. the span store holds a window, and records each iteration (ISSUE-25)
+# ---------------------------------------------------------------------------
+
+def test_window_bounds_are_half_open_on_the_close_time():
+    for i, (t0, t1) in enumerate([(0.0, 1.0), (0.5, 2.0), (1.5, 3.0)]):
+        tracing.add_span(0, "megastep", "r0", t0, t1, i=i)
+    tracing.note("r0", {"kind": "tick", "t1": 1.5})   # events never count
+    tracing.add_span(0, "megastep", "r1", 0.0, 2.0)
+    got = tracing.window("r0", 1.0, 3.0)
+    assert [s["attrs"]["i"] for s in got] == [0, 1]    # t1 in [1.0, 3.0)
+    assert all(s["type"] == "span" for s in got)
+    assert tracing.window("r0", 3.0, 9.0)[0]["attrs"]["i"] == 2
+    assert tracing.window("r0", 3.1, 9.0) == []
+    assert tracing.window("nobody", 0.0, 9.0) == []
+    assert "iteration" in tracing.PHASES
+
+
+def test_ring_defaults_to_a_window_and_dumps_its_newest_256():
+    assert tracing.tracer()._ring_cap == 16384
+    for i in range(16384 + 10):
+        tracing.note("r0", {"kind": "tick", "i": i})
+    ring = tracing.snapshot("r0")
+    assert len(ring) == 16384 and ring[0]["i"] == 10
+    rec = tracing.dump("r0", "quarantine")
+    assert rec["n"] == len(rec["tail"]) == 256
+    assert rec["tail"][-1]["i"] == 16384 + 9 and rec["ring_cap"] == 16384
+
+
+def test_iteration_record_counts_what_the_iteration_did(model_and_params):
+    """One `iteration` span per step() that launched, from the stamps
+    step() takes, with the counts taken where the work happens."""
+    model, params = model_and_params
+    eng = _engine(model, params, name="it0", block_size=4, n_blocks=32,
+                  decode_buckets=[2, 4], prefix=False)
+    eng.warmup()
+    t0 = time.perf_counter()
+    reqs = [eng.submit(list(range(1, 10)), max_new_tokens=4),
+            eng.submit(list(range(20, 26)), max_new_tokens=4)]
+    eng.run_until_idle(timeout=120)
+    t1 = time.perf_counter()
+    assert all(r.error is None for r in reqs)
+    its = [s for s in tracing.window("it0", t0, t1)
+           if s["phase"] == "iteration"]
+    assert len(its) == eng.stats["decode_steps"] >= 3
+    for s in its:
+        a = s["attrs"]
+        assert set(a) == {"rows", "bucket", "chunks", "queued",
+                          "blocks_live", "blocks_parked"}
+        assert 1 <= a["rows"] <= a["bucket"] and a["bucket"] in (2, 4)
+        assert a["blocks_parked"] == 0 and a["queued"] == 0
+        assert s["trace"] == 0 and s["t0"] < s["t1"]
+    # both prompts went through their one chunk in the first iteration
+    assert its[0]["attrs"]["chunks"] == 2
+    assert sum(s["attrs"]["chunks"] for s in its) == 2
+    # 9 and 6 prompt tokens in blocks of 4: 3 + 2 blocks, the first decode
+    # write of the 9-token row lands in its third block, the 6-token row's
+    # in its second
+    assert its[0]["attrs"]["blocks_live"] == 5
+    assert its[0]["attrs"]["rows"] == 2
+    assert sum(s["attrs"]["rows"] for s in its) == eng.stats["decode_rows"]
+    assert eng.leaked_blocks() == 0
+    # the counts are made anew each step(): one that launches nothing
+    # leaves no record and carries no launch over from the last
+    n = len(tracing.window("it0", t0, time.perf_counter() + 1))
+    eng.step()
+    assert "rows" not in eng._iter and "bucket" not in eng._iter
+    assert eng._iter["blocks_live"] == 0
+    assert len(tracing.window("it0", t0, time.perf_counter() + 1)) == n
+
+
+def test_iteration_blocks_live_excludes_parked_blocks(model_and_params):
+    """The prefix cache parks a finished request's full blocks: they are
+    held (the allocator's free count stays down) but no sequence lives in
+    them, and `blocks_live` says so."""
+    model, params = model_and_params
+    eng = _engine(model, params, name="it1", block_size=4, n_blocks=32,
+                  decode_buckets=[2], prefix=True)
+    eng.warmup()
+    first = eng.submit(list(range(1, 14)), max_new_tokens=3)
+    eng.run_until_idle(timeout=120)
+    assert first.error is None
+    parked = eng._prefix.parked_count
+    assert parked >= 3                       # 13 prompt tokens: 3 full blocks
+    t0 = time.perf_counter()
+    other = eng.submit(list(range(30, 36)), max_new_tokens=3)
+    eng.run_until_idle(timeout=120)
+    assert other.error is None
+    its = [s for s in tracing.window("it1", t0, time.perf_counter())
+           if s["phase"] == "iteration"]
+    assert its
+    for s in its:
+        a = s["attrs"]
+        assert a["blocks_parked"] >= parked
+        assert a["blocks_live"] == 2         # 6 tokens + the decode writes
+        assert eng._alloc.n_blocks - 1 - eng._alloc.free_blocks \
+            >= a["blocks_live"] + parked - 1
+
+
+def test_sched_phases_reach_the_profilers_trace(model_and_params, tmp_path):
+    """The scheduler's phases are `TraceAnnotation`s: a profiler session
+    around a few iterations finds `sched.iteration` and its eight leaf
+    phases on the host's plane (read with the benchmark's raw reader)."""
+    import jax
+
+    sys.path.insert(0, REPO)
+    from benchmark import xplane_raw
+
+    model, params = model_and_params
+    eng = _engine(model, params, name="it2", block_size=4, n_blocks=32,
+                  decode_buckets=[2])
+    eng.warmup()
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        req = eng.submit(list(range(1, 10)), max_new_tokens=4)
+        eng.run_until_idle(timeout=120)
+    finally:
+        jax.profiler.stop_trace()
+    assert req.error is None
+    path = xplane_raw.trace.find_xplane(str(tmp_path))
+    names = {}
+    for plane in xplane_raw.planes(path, host_prefixes=("sched.",)):
+        for events in plane["lines"].values():
+            for _, _, meta in events:
+                name = meta["name"].split("#")[0]
+                names[name] = names.get(name, 0) + 1
+    assert set(names) == {"sched." + p for p in (
+        "iteration", "sweep", "prefill", "admit", "grow", "pack", "launch",
+        "fetch", "publish")}
+    assert names["sched.launch"] == names["sched.fetch"] \
+        == names["sched.publish"] == eng.stats["decode_steps"]
+    assert names["sched.iteration"] >= names["sched.launch"]

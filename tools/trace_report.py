@@ -28,7 +28,7 @@ import sys
 RENDERED_PHASES = (
     "request", "queue_wait", "prefill", "replay", "restore_wait",
     "handoff_wait", "decode", "prefill_chunk", "handoff_pack",
-    "handoff_land", "megastep", "host_sweep", "spec_round",
+    "handoff_land", "megastep", "host_sweep", "spec_round", "iteration",
     "gateway_send")
 
 # interval phases: at most one open per trace at a time; their per-trace
@@ -39,7 +39,8 @@ INTERVAL_PHASES = ("queue_wait", "prefill", "replay", "restore_wait",
 TTFT_PHASES = ("queue_wait", "prefill", "replay", "restore_wait",
                "handoff_wait")
 LEAF_PHASES = ("prefill_chunk", "handoff_pack", "handoff_land",
-               "megastep", "host_sweep", "spec_round", "gateway_send")
+               "megastep", "host_sweep", "spec_round", "iteration",
+               "gateway_send")
 
 BAR_WIDTH = 36
 
@@ -74,7 +75,8 @@ def load(path):
 
 def by_trace(spans):
     """{trace id: [span, ...]} sorted by start time; the replica-scoped
-    spans (megastep / host_sweep / spec_round) live under key 0."""
+    spans (megastep / host_sweep / spec_round / iteration) live under
+    key 0."""
     traces = {}
     for s in spans:
         traces.setdefault(s.get("trace", 0), []).append(s)
