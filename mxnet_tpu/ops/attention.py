@@ -21,6 +21,8 @@ without inserting a collective until the output projection's
 row-sharded matmul reduces.  Keep it that way — no op in this file may
 mix embed positions across the head boundary (e.g. a transpose to
 `(hd, h)` order), or sub-mesh serving silently gains all-to-alls.
+(`paged_decode_attention` runs as a Pallas kernel only in a program built
+for one device; a sharded replica's decode keeps the gather + einsum form.)
 """
 from __future__ import annotations
 
@@ -29,7 +31,7 @@ import jax.numpy as jnp
 
 from ..base import MXNetError
 from .registry import OpDef, Param, register
-from .pallas_kernels import flash_attention
+from .pallas_kernels import flash_attention, paged_attention_mod
 from .pallas_kernels.flash_attention import flash_attention_bsd
 
 
@@ -151,12 +153,11 @@ def decode_attention(q, k_cache, v_cache, pos, num_heads, *, scale=None):
     Returns (batch, embed).
 
     Continuous batching gives every row its OWN position, so the validity
-    mask is per-row (`j <= pos[b]`), not a shared triangle.  jnp body only:
-    one (b, h, S) score row per token is a gather + two small matmuls —
-    XLA fuses it fine, and serving decode is HBM-bound on the cache read
-    (a dedicated Pallas kernel would buy little; the prefill side is where
-    the flash kernels earn their keep).  f32 softmax statistics regardless
-    of cache dtype, like the training kernels.
+    mask is per-row (`j <= pos[b]`), not a shared triangle.  `jax.numpy`
+    body: the whole cache is promoted to f32 and read by two einsums, f32
+    softmax statistics regardless of cache dtype, like the training
+    kernels.  Over a paged pool this is the reference, and the path of
+    the pools the kernel does not take (`paged_decode_attention`).
     """
     b, s, e = k_cache.shape
     if e % num_heads != 0:
@@ -236,22 +237,55 @@ def gather_paged_scales(scales, block_tables):
     return scales[block_tables.astype(jnp.int32)].reshape(b, m * bs)
 
 
-def paged_decode_attention(q, k_pool, v_pool, block_tables, pos, num_heads,
+def paged_decode_kernel_applies(pool, num_heads):
+    """Whether `paged_decode_attention` over ``pool`` (an unquantised
+    ``(layers, 2, n_blocks, block_size, embed)`` pool) runs as the Pallas
+    kernel, from what the code can see where the program is traced: the
+    kernel's own conditions (`pallas_kernels.paged_attention.applies`: a
+    TPU backend or the interpreter, f32 or bf16 blocks of whole tiles,
+    heads of 64 or 128) and a program built for one device.  A
+    tensor-sharded engine's program is partitioned by GSPMD, which refuses
+    Mosaic kernels (`pallas_kernels/_spmd.py`); it keeps the `jax.numpy`
+    body."""
+    from ..parallel.mesh import get_mesh
+
+    mesh = get_mesh()
+    return (mesh is None or mesh.size == 1) \
+        and paged_attention_mod.applies(pool, num_heads)
+
+
+def paged_decode_attention(q, pool, layer, block_tables, pos, num_heads,
                            *, scale=None):
-    """`decode_attention` over a paged K/V pool: gather each row's blocks
-    by table index, then run the same single-query position-masked
-    attention.  The gather is the only extra work — numerics are
-    identical to the slot cache (masked tail positions contribute exact
-    zeros either way).
+    """`decode_attention` of every row over layer ``layer`` of a paged K/V
+    pool ``(layers, 2, n_blocks, block_size, embed)``.
+
+    Where `paged_decode_kernel_applies` holds, the Pallas kernel
+    `paged_decode_attn` walks each row's live blocks in the pool's own
+    dtype with the pool left whole in HBM, under the scope
+    `decode_attention`.  Everywhere else (the CPU backend, a sharded
+    engine, widths the kernel does not take) the `jax.numpy` body runs:
+    the layer's K and V pools sliced out, each row's blocks gathered by
+    table index (`gather_paged_kv`), then `decode_attention` over the
+    table-wide context.  That body is the kernel's reference in the tests:
+    the two agree up to the order of the float32 sums (masked tail
+    positions contribute exact zeros either way).
 
     Dead-row contract (megastep decode): a retired/padding row is fed
     ``pos = n_table * block_size`` — the first position PAST its table
     coverage — so its K/V write redirects to the trash block (entry
     index ``pos // bs == n_table`` maps to block 0) and its validity
-    mask here goes all-valid over whatever the gathered blocks hold.
+    mask here goes all-valid over whatever the table's blocks hold.
     That output is garbage by construction and is discarded in-graph
     (the scan emits the ``-2`` dead sentinel instead); it cannot
     contaminate live rows because every row's softmax is independent."""
+    if paged_decode_kernel_applies(pool, num_heads):
+        with jax.named_scope("decode_attention"):
+            return paged_attention_mod.paged_decode_attn(
+                q, pool, layer, block_tables, pos, num_heads, scale=scale)
+    # the layer's slice of the pool fuses with the gather: taken inside
+    # the scope, or the fused copy has no name
+    with jax.named_scope("kv_gather"):
+        k_pool, v_pool = pool[layer, 0], pool[layer, 1]
     kc = gather_paged_kv(k_pool, block_tables)
     vc = gather_paged_kv(v_pool, block_tables)
     return decode_attention(q, kc, vc, pos, num_heads, scale=scale)

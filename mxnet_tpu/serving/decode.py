@@ -63,7 +63,8 @@ from jax.sharding import NamedSharding, PartitionSpec
 
 from ..base import MXNetError
 from ..ops.attention import (gather_paged_kv, gather_paged_scales,
-                             paged_decode_attention, decode_attention,
+                             paged_decode_attention,
+                             paged_decode_kernel_applies, decode_attention,
                              chunk_attention, verify_attention)
 from ..ops.pallas_kernels.flash_attention import flash_attention
 from ..ops.pallas_kernels.layer_norm import layer_norm
@@ -504,6 +505,15 @@ class TransformerKVModel:
     def _pack_pool(self, pool, scales):
         return pool if scales is None else (pool, scales)
 
+    def paged_decode_kernel(self, cache):
+        """Whether `decode_paged` over ``cache``, traced here, attends with
+        the Pallas kernel (`ops.attention.paged_decode_attention`).  The
+        int8 pool never does: its rows are dequantized by their scales as
+        they are gathered (`_gather_ctx`)."""
+        pool, scales = self._pool_parts(cache)
+        return scales is None and paged_decode_kernel_applies(
+            pool, self.num_heads)
+
     @jax.named_scope("kv_scatter")
     def _scatter_kv(self, pool, scales, layer, at, k, v):
         """Write one layer's new K and V rows into the pool at ``at`` (a
@@ -743,12 +753,10 @@ class TransformerKVModel:
             pool, scales = self._scatter_kv(pool, scales, i, (blk, off),
                                             k, v)
             if scales is None:
-                # the layer's slice of the pool fuses with the gather:
-                # taken inside the scope, or the fused copy has no name
-                with jax.named_scope("kv_gather"):
-                    k_pool, v_pool = pool[i, 0], pool[i, 1]
-                attn = paged_decode_attention(q, k_pool, v_pool, tables,
-                                              pos, self.num_heads)
+                # the whole pool: a kernel where one applies, which reads
+                # the row's live blocks in place
+                attn = paged_decode_attention(q, pool, i, tables, pos,
+                                              self.num_heads)
             else:
                 kc = self._gather_ctx(pool, scales, i, 0, tables)
                 vc = self._gather_ctx(pool, scales, i, 1, tables)

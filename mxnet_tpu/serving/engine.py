@@ -701,6 +701,13 @@ class ServingEngine:
             self._alloc = BlockAllocator(nb, bs)
             self._cache = model.init_block_pool(nb, bs,
                                                 device=self._kv_device())
+            # whether the decode programs attend with the paged Pallas
+            # kernel: decided here, once, as their traces will (the pool,
+            # the backend, this replica's mesh), for the `iteration`
+            # record's `attn_kernel`
+            self._attn_kernel = int(self._scoped(
+                lambda: model.paged_decode_kernel(self._cache),
+                "attn_kernel")())
             self._prefilling = {}  # row -> _Prefill (insertion-ordered)
             # cross-request prefix sharing (MXNET_SERVE_PREFIX=0 restores
             # single-owner paging bit-for-bit; MXNET_SERVE_PREFIX_POOL
@@ -3527,7 +3534,8 @@ class ServingEngine:
             self._watch("decode", args,
                         names + self._SAMPLE_NAMES[:len(samp)], b)
             compiled = self._compiled_decode(b)
-        self._iter.update(rows=n, bucket=b)
+        self._iter.update(rows=n, bucket=b,
+                          attn_kernel=self._attn_kernel if self._paged else 0)
         t_launch = time.perf_counter()
         try:
             if chaos.serve_launch_error():
@@ -3671,7 +3679,8 @@ class ServingEngine:
                         ("token", "pos", "left", "eos", "tables")
                         + self._SAMPLE_NAMES[:len(samp)], b)
             compiled = self._compiled_mega(b)
-        self._iter.update(rows=nrows, bucket=b)
+        self._iter.update(rows=nrows, bucket=b,
+                          attn_kernel=self._attn_kernel)
         t_launch = time.perf_counter()
         try:
             if chaos.serve_launch_error():
@@ -3911,7 +3920,8 @@ class ServingEngine:
                         ("tokens", "pos", "length", "tables")
                         + self._SAMPLE_NAMES[:len(samp)], b)
             compiled = self._compiled_verify(b)
-        self._iter.update(rows=n, bucket=b)
+        # a verify launch attends with `verify_attention`, never the kernel
+        self._iter.update(rows=n, bucket=b, attn_kernel=0)
         t_launch = time.perf_counter()
         try:
             if chaos.serve_launch_error():

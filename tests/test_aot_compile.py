@@ -18,6 +18,7 @@ The kernels gate on `jax.default_backend() == "tpu"`; from a CPU process the
 kernels and shape gates the chip would.
 """
 import os
+import re
 
 import numpy as np
 import pytest
@@ -234,39 +235,137 @@ def test_sharded_serving_programs_compile_for_four_devices(on_tpu, topo,
     assert comp.as_text().count("tpu_custom_call") == 2 * L + 1
 
 
-def test_decode_pool_copy_is_named_by_the_tpu_compiler(on_tpu, one_chip):
-    """The TPU compiler fuses a layer's slice of the pool with the gather
-    through the block tables (`slice_bitcast_fusion`, a quarter of a decode
-    launch on the chip) and names the fusion after the slice: the slice has
-    to be taken inside the `kv_gather` scope, or the largest operation of a
-    decode launch reads as time under no scope (PERF.md, PR 25).  GPT-2
-    large's widths, one layer."""
-    import re
-
+def _decode_program(one_chip, batch, n_blocks, layers=1, kv_quant=None):
+    """`serve_decode_b<batch>` at GPT-2 large's widths, compiled for the
+    described chip with the pool donated: (its text, its memory analysis,
+    the bytes of one layer's K pool)."""
     from mxnet_tpu.base import bfloat16
     from mxnet_tpu.serving import TransformerKVModel
 
-    V, S, E, bs, n_blocks, b = 512, 1024, 1280, 16, 64, 4
-    model = TransformerKVModel(V, S, num_layers=1, num_heads=20, num_embed=E,
-                               dtype=bfloat16)
+    V, S, E, bs = 512, 1024, 1280, 16
+    model = TransformerKVModel(V, S, num_layers=layers, num_heads=20,
+                               num_embed=E, dtype=bfloat16,
+                               kv_quant=kv_quant)
     params = {n: _bf16(s, one_chip) for n, s in model.param_shapes().items()}
-    pool = _bf16((1, 2, n_blocks, bs, E), one_chip)
+    shape = (layers, 2, n_blocks, bs, E)
+    pool = _bf16(shape, one_chip)
+    if kv_quant is not None:
+        pool = (jax.ShapeDtypeStruct(shape, jnp.int8, sharding=one_chip),
+                jax.ShapeDtypeStruct(shape[:-1], jnp.float32,
+                                     sharding=one_chip))
 
     def ints(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
 
-    def serve_decode_b4(params, pool, token, pos, tables):
+    def serve_decode(params, pool, token, pos, tables):
         logits, pool = model.decode_paged(params, pool, token, pos, tables)
         return jnp.argmax(logits, axis=-1), pool
 
-    text = jax.jit(serve_decode_b4, donate_argnums=(1,)).lower(
-        params, pool, ints(b), ints(b), ints(b, S // bs)).compile().as_text()
-    names = set(re.findall(r'op_name="jit\(serve_decode_b4\)/([^"]+)"', text))
-    assert {"kv_gather/squeeze", "kv_gather/gather",
-            "kv_scatter/scatter"} <= names
-    # none of the pool's own operations outside its scopes
-    assert not names & {"squeeze", "gather", "scatter", "dynamic_slice",
-                        "dynamic_update_slice"}
+    serve_decode.__name__ = "serve_decode_b%d" % batch
+    comp = jax.jit(serve_decode, donate_argnums=(1,)).lower(
+        params, pool, ints(batch), ints(batch),
+        ints(batch, S // bs)).compile()
+    return comp.as_text(), comp.memory_analysis(), n_blocks * bs * E * 2
+
+
+def _op_names(text, program):
+    return re.findall(r'op_name="jit\(%s\)/([^"]+)"' % program, text)
+
+
+@pytest.mark.parametrize("path", ["kernel", "jnp_int8_pool"])
+def test_decode_pool_is_read_as_the_tpu_compiler_names_it(on_tpu, one_chip,
+                                                          path):
+    """How a decode launch reads the pool, in the compiled program's own
+    names, at GPT-2 large's widths (PERF.md, PR 25 and PR 26).
+
+    kernel: the bf16 pool goes to one `paged_decode_attn` custom call a
+    layer, under `decode_attention`, whole: nothing gathers it, no
+    operation makes a value the size of a layer's K pool but the in-place
+    `kv_scatter` of the donated pool, and the program's temporaries are
+    smaller than that.  It compiles for every decode bucket of the cell.
+
+    jnp_int8_pool: the quantised pool keeps `gather_paged_kv` +
+    `decode_attention`.  The TPU compiler fuses a layer's slice of the pool
+    with the gather through the block tables (`slice_bitcast_fusion`, a
+    quarter of a decode launch on the chip before the kernel) and names the
+    fusion after the slice: the slice has to be taken inside the `kv_gather`
+    scope, or the largest operation of such a launch reads as time under no
+    scope."""
+    if path == "jnp_int8_pool":
+        text, _, _ = _decode_program(one_chip, 4, 64, kv_quant="int8")
+        names = set(_op_names(text, "serve_decode_b4"))
+        assert "tpu_custom_call" in text        # the fused LayerNorms
+        assert not any("paged_decode_attn" in n for n in names)
+        # (`_gather_ctx` dequantizes under the scope `gather_paged_kv`
+        # gathers under: the gather's name holds it twice)
+        assert {"kv_gather/squeeze", "kv_gather/kv_gather/gather",
+                "kv_scatter/scatter"} <= names
+        # none of the pool's own operations outside its scopes
+        assert not names & {"squeeze", "gather", "scatter", "dynamic_slice",
+                            "dynamic_update_slice"}
+        return
+    layers, n_blocks = 2, 3600
+    for batch in (1, 2, 4, 8, 16, 32, 64):
+        program = "serve_decode_b%d" % batch
+        text, memory, k_pool_bytes = _decode_program(one_chip, batch,
+                                                     n_blocks, layers)
+        names = _op_names(text, program)
+        calls = [n for n in names if n.endswith("/pallas_call")
+                 and "paged_decode_attn" in n]
+        assert calls == ["decode_attention/jit(_paged_decode)/"
+                         "paged_decode_attn/pallas_call"] * layers
+        # the layers call one lowered function: the kernel is traced and
+        # lowered once a program, whatever the model's depth
+        assert text.count("tpu_custom_call") == 3 * layers + 1
+        assert not any("kv_gather" in n for n in names)
+        # the pool is donated and updated in place ...
+        assert "input_output_alias" in text.splitlines()[0]
+        assert memory.alias_size_in_bytes == 2 * layers * k_pool_bytes
+        # ... and nothing the size of one layer's K pool is made beside it
+        assert memory.temp_size_in_bytes < k_pool_bytes
+        entry = text[text.index("\nENTRY "):]
+        for m in re.finditer(r"\n\s+(?:ROOT )?%\S+ = \w+\[([\d,]+)\]\S* "
+                             r"([\w-]+)\((.*)", entry):
+            dims, opcode, rest = m.groups()
+            if np.prod([int(d) for d in dims.split(",")]) * 2 \
+                    < k_pool_bytes:
+                continue
+            assert opcode == "parameter" or "kv_scatter/scatter" in rest, \
+                m.group(0)[:300]
+
+
+def test_megastep_scan_reads_the_pool_through_the_kernel(on_tpu, one_chip):
+    """`serve_mega_b*` scans `decode_paged`'s body with the pool as the
+    carry (so does the speculative drafter): the kernel lowers inside the
+    scan, once a layer, and the carried pool is still donated and never
+    copied."""
+    from mxnet_tpu.base import bfloat16
+    from mxnet_tpu.serving import TransformerKVModel
+
+    layers, n_blocks, batch = 2, 3600, 8
+    V, S, E, bs = 512, 1024, 1280, 16
+    model = TransformerKVModel(V, S, num_layers=layers, num_heads=20,
+                               num_embed=E, dtype=bfloat16)
+    params = {n: _bf16(s, one_chip) for n, s in model.param_shapes().items()}
+    pool = _bf16((layers, 2, n_blocks, bs, E), one_chip)
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    def serve_mega_b8(params, pool, token, pos, left, eos, tables):
+        return model.decode_megastep(
+            params, pool, token, pos, left, eos, tables, 4,
+            lambda logits, newpos: jnp.argmax(logits, -1).astype(jnp.int32))
+
+    comp = jax.jit(serve_mega_b8, donate_argnums=(1,)).lower(
+        params, pool, ints(batch), ints(batch), ints(batch), ints(batch),
+        ints(batch, S // bs)).compile()
+    text, memory = comp.as_text(), comp.memory_analysis()
+    k_pool_bytes = n_blocks * bs * E * 2
+    assert text.count("paged_decode_attn/pallas_call") == layers
+    assert "kv_gather" not in text
+    assert memory.alias_size_in_bytes == 2 * layers * k_pool_bytes
+    assert memory.temp_size_in_bytes < k_pool_bytes
 
 
 # -- whole train steps (toy widths: seconds each) ---------------------------

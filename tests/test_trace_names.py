@@ -231,3 +231,35 @@ def test_layer_norm_kernels_are_named(for_tpu):
                    jax.ShapeDtypeStruct((256,), jnp.float32),
                    jax.ShapeDtypeStruct((256,), jnp.float32))
     assert _kernels(text) == {"layer_norm_fwd", "layer_norm_bwd"}
+
+
+@pytest.mark.parametrize("pool_dtype,kernel", [
+    (jnp.bfloat16, True),      # the served pool: the kernel, and no gather
+    (jnp.int8, False),         # not the kernel's: the `jax.numpy` body
+])
+def test_paged_decode_attention_is_named_either_way(for_tpu, pool_dtype,
+                                                    kernel):
+    """`kv_gather_attn_ms_per_launch` reads the scopes `kv_gather` and
+    `decode_attention`: on a TPU the paged kernel runs under the second
+    with a name of its own, and a pool it does not take keeps both."""
+    from mxnet_tpu.ops.attention import paged_decode_attention
+
+    text = for_tpu(
+        lambda q, pool, tables, pos: paged_decode_attention(
+            q, pool, 1, tables, pos, 2),
+        jax.ShapeDtypeStruct((4, 128), jnp.bfloat16),
+        jax.ShapeDtypeStruct((2, 2, 16, 32, 128), pool_dtype),
+        jax.ShapeDtypeStruct((4, 8), jnp.int32),
+        jax.ShapeDtypeStruct((4,), jnp.int32))
+    if kernel:
+        assert _kernels(text) == {"paged_decode_attn"}
+        # the kernel's own name inside the function the layers share, and
+        # the scope on that function's call (a compiled program's `op_name`
+        # joins the two: `tests/test_aot_compile.py`)
+        assert '"paged_decode_attn/pallas_call"' in text
+        assert 'decode_attention/jit(_paged_decode)"' in text
+        assert "kv_gather" not in text
+    else:
+        assert _kernels(text) == set()
+        assert 'kv_gather/gather"' in text
+        assert 'decode_attention/' in text

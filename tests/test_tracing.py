@@ -714,8 +714,10 @@ def test_iteration_record_counts_what_the_iteration_did(model_and_params):
     for s in its:
         a = s["attrs"]
         assert set(a) == {"rows", "bucket", "chunks", "queued",
-                          "blocks_live", "blocks_parked"}
+                          "blocks_live", "blocks_parked", "attn_kernel"}
         assert 1 <= a["rows"] <= a["bucket"] and a["bucket"] in (2, 4)
+        # the CPU backend's decode programs hold no Pallas kernel
+        assert a["attn_kernel"] == 0
         assert a["blocks_parked"] == 0 and a["queued"] == 0
         assert s["trace"] == 0 and s["t0"] < s["t1"]
     # both prompts went through their one chunk in the first iteration
@@ -732,7 +734,7 @@ def test_iteration_record_counts_what_the_iteration_did(model_and_params):
     # leaves no record and carries no launch over from the last
     n = len(tracing.window("it0", t0, time.perf_counter() + 1))
     eng.step()
-    assert "rows" not in eng._iter and "bucket" not in eng._iter
+    assert not {"rows", "bucket", "attn_kernel"} & set(eng._iter)
     assert eng._iter["blocks_live"] == 0
     assert len(tracing.window("it0", t0, time.perf_counter() + 1)) == n
 
