@@ -14,7 +14,7 @@ import pytest
 from benchmark import harness, serve, spec, train
 
 TRAIN = "gpt2-medium.train-s1024"
-SERVE = "gpt2-large.chat-steady"
+SERVE = "gpt2-large.chat-near-knee"
 SEEDS = (1, 2, 3000000019)
 
 

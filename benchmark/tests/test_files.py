@@ -44,12 +44,34 @@ def test_metric_resolves(metric):
         meta = json.load(f)
     for key in ("unit", "layer", "source", "moves", "workloads"):
         assert meta[key] == entry[key], key
+    assert meta.get("better", entry["better"]) == entry["better"]
     read, args = spec.reader(metric)
     assert callable(read) and isinstance(args, dict)
     e2e = {m["name"]: m for m in BENCH["end_to_end"]}
     for cell in entry["workloads"]:
         moved = e2e[entry["moves"]]
         assert cell in moved.get("workloads", [cell])
+
+
+def _workloads_named(where):
+    """{where it is named: the workloads it names}."""
+    if where in ("end_to_end", "per_layer"):
+        return {"BENCHMARK.json %s %s" % (where, m["name"]):
+                m.get("workloads", []) for m in BENCH[where]}
+    out = {}
+    for name in _names("metrics"):
+        with open(os.path.join(HERE, "metrics", name + ".json")) as f:
+            out["metrics/%s.json" % name] = json.load(f).get("workloads", [])
+    return out
+
+
+@pytest.mark.parametrize("where", ["end_to_end", "per_layer", "metrics"])
+def test_every_workload_named_is_a_cell(where):
+    """A metric that names a cell `workloads` does not have (a retired
+    one, a misspelt one) would have nothing to read."""
+    cells = {w["name"] for w in BENCH["workloads"]}
+    for by, named in _workloads_named(where).items():
+        assert set(named) <= cells, (by, named)
 
 
 def test_config_files_are_each_configs_own():

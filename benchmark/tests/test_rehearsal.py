@@ -60,7 +60,7 @@ def test_sweep_prints_a_line_for_every_rate():
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     p = subprocess.run(
         [sys.executable, os.path.join(ROOT, "benchmark", "sweep.py"),
-         "--workload", "gpt2-large.chat-steady", "--rates", "4", "8",
+         "--workload", "gpt2-large.chat-near-knee", "--rates", "4", "8",
          "--seconds", "2", "--tiny"],
         cwd=ROOT, env=env, capture_output=True, text=True, timeout=600)
     assert p.returncode == 0, p.stderr[-2000:]
