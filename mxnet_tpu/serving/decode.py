@@ -106,6 +106,29 @@ class TransformerKVModel:
         self.quant = quant_resolve(quant)
         self.kv_quant = quant_resolve(kv_quant)
 
+    #: what a block of the paged pool holds: a K row and a V row of model
+    #: width a token (`serving/latent.py` has the other kind)
+    cache_kind = "kv_pair"
+
+    @property
+    def moe_pairs_per_row(self):
+        """(row, expert) pairs a row routes in one launch: one a layer."""
+        return self.num_layers if self.moe_experts else 0
+
+    def block_bytes(self, block_size, shards=1):
+        """Device bytes of one block of the paged pool, every layer, K and
+        V — the one place that prices the layout `init_block_pool` makes.
+        Under KV quantization the int8 rows plus one float32 scale a row;
+        ``shards > 1`` gives the PER-DEVICE bytes of a sub-mesh replica
+        (embed axis split where the mesh divides it, scales replicated,
+        as `kv_shardings` places them)."""
+        rows = self.num_layers * 2 * int(block_size)
+        embed = self.num_embed // shards \
+            if self.num_embed % int(shards) == 0 else self.num_embed
+        if self.kv_quant is not None:
+            return rows * (embed + 4)
+        return rows * embed * self.dtype.itemsize
+
     def with_quant(self, quant, kv_quant):
         """A shallow copy of this geometry with the given quantization
         specs (the engine's ``MXNET_SERVE_QUANT`` entry point: one model

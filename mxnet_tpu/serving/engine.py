@@ -144,7 +144,7 @@ from .journal import RequestJournal, journal_enabled
 from .paged import BlockAllocator, PrefixCache, TRASH_BLOCK
 from .sampling import sample_tokens
 from .spec import make_drafter
-from .tiers import HostBlockTier, pack_block_run
+from .tiers import HostBlockTier, check_cache_kind, pack_block_run
 from .errors import (ServeError, ServeTimeout, ServeOverload,
                      ServeDeadlineExceeded, ServeCancelled,
                      ServeQuarantined, ServeBlocksExhausted,
@@ -539,6 +539,7 @@ class ServingEngine:
             else:
                 ctx = np.asarray(ctx.devices).reshape(-1)[0]
         if self._mesh is not None:
+            self._refuse("mesh", "a Mesh ctx with MXNET_SERVE_SHARDED")
             # launch operands and token outputs are REPLICATED over the
             # mesh; _device doubles as that sharding so every existing
             # _put/device_put site stages mesh-consistently for free
@@ -626,6 +627,12 @@ class ServingEngine:
             raise MXNetError(
                 "ServingEngine: quantized KV blocks need the paged cache "
                 "(MXNET_SERVE_KV_QUANT set with MXNET_SERVE_PAGED=0)")
+        if not self._paged:
+            self._refuse("slot_cache", "paged=False / MXNET_SERVE_PAGED=0")
+        if self._quant is not None:
+            self._refuse("quant", "quant / MXNET_SERVE_QUANT")
+        if self._kv_quant is not None:
+            self._refuse("kv_quant", "kv_quant / MXNET_SERVE_KV_QUANT")
         self._quant_gate = (self._quant is not None
                             or self._kv_quant is not None)
         self._quant_logit_max = float(os.environ.get(
@@ -664,6 +671,12 @@ class ServingEngine:
         self._moe_pending = []
         self._moe_load = (np.zeros((self.model.moe_experts,), np.int64)
                           if self._moe else None)
+        # (rows, hits) an expert folded since the last `iteration` record
+        self._moe_since = (np.zeros((2, self.model.moe_experts), np.int64)
+                           if self._moe else None)
+        # `expert_load()` drains from the caller's thread while the
+        # scheduler drains after every launch: a count is folded once
+        self._moe_lock = threading.Lock()
         if self._paged:
             self._chunk_prefill = _env_flag("MXNET_SERVE_CHUNK_PREFILL") \
                 if chunk_prefill is None else bool(chunk_prefill)
@@ -730,6 +743,8 @@ class ServingEngine:
             self._restore_ahead = int(
                 os.environ.get("MXNET_SERVE_RESTORE_AHEAD", "2")
                 if restore_ahead is None else restore_ahead)
+            if tier_on:
+                self._refuse("tier", "tier / MXNET_SERVE_TIER")
             self._tier = HostBlockTier(self._host_blocks) \
                 if tier_on and self._host_blocks > 0 else None
             self._prefix = PrefixCache(
@@ -765,6 +780,7 @@ class ServingEngine:
         self._drafter_arg = spec_drafter
         self._drafter = None
         if self._spec:
+            self._refuse("spec", "spec / MXNET_SERVE_SPEC")
             if not self._paged:
                 raise MXNetError(
                     "ServingEngine: speculative decoding needs the paged "
@@ -786,6 +802,7 @@ class ServingEngine:
             is None else bool(megastep)
         self._mega_m = 0
         if mega_on:
+            self._refuse("megastep", "megastep / MXNET_SERVE_MEGASTEP")
             if not self._paged:
                 raise MXNetError(
                     "ServingEngine: megastep decode needs the paged cache "
@@ -890,6 +907,10 @@ class ServingEngine:
                       "session_turns": 0,
                       # quantization (0s when disabled)
                       "quant_trips": 0, "scale_corrupts": 0,
+                      # sparse experts (0s for a dense model): (row, expert)
+                      # pairs the launches routed over all experts, and
+                      # those of them that fell on experts held here
+                      "moe_pairs_routed": 0, "moe_pairs_held": 0,
                       # decode-loop accounting behind the host_frac
                       # gauge: hidden_s spans launch-dispatch -> fetch-
                       # complete (host work inside it rides under the
@@ -900,6 +921,15 @@ class ServingEngine:
                       "ingraph_retired": 0, "wall_s": 0.0,
                       "host_s": 0.0, "hidden_s": 0.0,
                       "fetch_wait_s": 0.0}
+
+    def _refuse(self, option, how):
+        """Raise if the model lists ``option`` among those it cannot serve
+        yet (`LatentMoEKVModel.unsupported`): by name, at construction,
+        instead of mis-reading its pool later."""
+        if option in getattr(self.model, "unsupported", ()):
+            raise MXNetError(
+                "ServingEngine: %s does not serve with %s yet (asked for by "
+                "%s)" % (type(self.model).__name__, option, how))
 
     # -- program building --------------------------------------------------
     _SAMPLE_NAMES = ("temp", "top_k", "top_p", "seed")
@@ -1272,13 +1302,17 @@ class ServingEngine:
         return fn
 
     def _moe_out(self, tape):
-        """The MoE programs' extra output: the launch's per-expert
-        routed-token counts, summed over layers into ONE (E,) row.
-        Dense models return () — their programs stay byte-identical
-        to PR 19."""
+        """The MoE programs' extra output, ONE (2, E) array a launch: the
+        per-expert routed-row counts summed over the tape's entries (one a
+        layer; a megastep's one entry is already summed over its steps),
+        and in how many of the entries each expert had a row at all (the
+        expert matrices a launch has to read).  Dense models return () —
+        their programs stay byte-identical to PR 19."""
         if not self._moe:
             return ()
-        return (jnp.sum(jnp.stack(tape), axis=0),)
+        tape = jnp.stack(tape)
+        return (jnp.stack([jnp.sum(tape, axis=0),
+                           jnp.sum(tape > 0, axis=0, dtype=tape.dtype)]),)
 
     def _unpack(self, out):
         """Split a compiled launch's outputs into (tokens, new_cache),
@@ -1292,22 +1326,41 @@ class ServingEngine:
         return out
 
     def _drain_moe(self, keep_last=True):
-        """Fold pending per-launch expert-count rows into the host
+        """Fold pending per-launch expert counts into the host
         accumulator and publish the `serve.<name>.expert_load.<i>`
-        gauges.  ``keep_last`` leaves the newest row pending — it may
+        gauges.  ``keep_last`` leaves the newest pending — it may
         belong to a launch still in flight."""
         if not self._moe:
             return
-        pend = self._moe_pending
-        n = len(pend) - 1 if keep_last else len(pend)
-        if n <= 0:
-            return
-        for a in pend[:n]:
-            self._moe_load += np.asarray(a)
-        del pend[:n]
-        for i, v in enumerate(self._moe_load):
-            telemetry.set_gauge(self._gauge + "expert_load.%s" % i,
-                                int(v))
+        with self._moe_lock:
+            pend = self._moe_pending
+            n = len(pend) - 1 if keep_last else len(pend)
+            if n <= 0:
+                return
+            folded = np.sum([np.asarray(a) for a in pend[:n]], axis=0)
+            del pend[:n]
+            self._moe_since += folded
+            self._moe_load += folded[0]
+            self.stats["moe_pairs_held"] += int(folded[0].sum())
+            for i, v in enumerate(self._moe_load):
+                telemetry.set_gauge(self._gauge + "expert_load.%s" % i,
+                                    int(v))
+
+    def _moe_record(self):
+        """The `iteration` record's attributes for the expert counts folded
+        since the last record (a chunk launched by an iteration that wrote
+        none counts in the next): ``expert_rows`` (row-expert pairs that
+        fell on held experts), ``expert_load_max`` (the fullest expert's
+        rows) and ``expert_hits`` ((layer, expert) pairs with a row at
+        all: the expert matrices read)."""
+        if not self._moe:
+            return {}
+        rows, hits = self._moe_since
+        out = {"expert_rows": int(rows.sum()),
+               "expert_load_max": int(rows.max()),
+               "expert_hits": int(hits.sum())}
+        self._moe_since[:] = 0
+        return out
 
     def expert_load(self):
         """Cumulative per-expert routed-token counts as a host array
@@ -1317,7 +1370,8 @@ class ServingEngine:
         if not self._moe:
             return None
         self._drain_moe(keep_last=False)
-        return self._moe_load.copy()
+        with self._moe_lock:
+            return self._moe_load.copy()
 
     def memory_footprint(self):
         """Device-memory accounting for params + K/V buffers:
@@ -3378,6 +3432,7 @@ class ServingEngine:
         t0 = time.perf_counter()
         h0 = self.stats["hidden_s"]
         c0 = self.stats["prefill_chunks"]
+        r0 = self.stats["decode_rows"] + self.stats["prefill_tokens"]
         # fold settled expert-load rows (all but the newest — it may
         # still be in flight) into the per-expert gauges
         self._drain_moe()
@@ -3387,8 +3442,16 @@ class ServingEngine:
                 n = self._step_mega()
             else:
                 n = self._step()
+        if self._moe:
+            self.stats["moe_pairs_routed"] += self.model.moe_pairs_per_row \
+                * (self.stats["decode_rows"] + self.stats["prefill_tokens"]
+                   - r0)
         dh = self.stats["hidden_s"] - h0
         if dh > 0:
+            # a single-step iteration has fetched its launch's tokens, so
+            # every count pending is on the host's side of it
+            self._drain_moe(keep_last=bool(self._mega_m))
+            self._iter.update(self._moe_record())
             t1 = time.perf_counter()
             wall = t1 - t0
             self.stats["wall_s"] += wall
@@ -3536,6 +3599,10 @@ class ServingEngine:
             compiled = self._compiled_decode(b)
         self._iter.update(rows=n, bucket=b,
                           attn_kernel=self._attn_kernel if self._paged else 0)
+        if self._paged:
+            # table entries the rows' attention walks: their live blocks
+            self._iter["ctx_blocks"] = int(
+                np.sum(pos[:n] // self.block_size) + n)
         t_launch = time.perf_counter()
         try:
             if chaos.serve_launch_error():
@@ -4346,6 +4413,8 @@ class ReplicaRouter:
         fallback.  MUST run before the engine's `warmup()` — a decode
         role decides which restore buckets join the frozen AOT set."""
         engine._on_death = self._handle_death
+        if role is not None:
+            check_cache_kind(engine.model, "prefill/decode handoff tickets")
         engine.role = role
         if role is not None:
             engine._handoff_sink = self._dispatch_handoff

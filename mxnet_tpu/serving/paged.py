@@ -71,7 +71,8 @@ here: when a `ServingEngine` spans a device mesh, the pool's embed
 axis E is split over the mesh while block ids, the block tables, this
 allocator, and the `PrefixCache` stay whole-pool host-side — every
 count and refcount below describes LOGICAL blocks, each physically
-striped across all shards.  `pool_bytes` reports both views.
+striped across all shards.  The model's `block_bytes(block_size, shards)`
+prices a block in both views.
 """
 from __future__ import annotations
 
@@ -81,26 +82,6 @@ from .. import chaos
 from ..base import MXNetError
 
 TRASH_BLOCK = 0
-
-
-def pool_bytes(num_layers, n_blocks, block_size, num_embed, itemsize=4,
-               quant=False, shards=1):
-    """Device bytes of the paged K/V pool
-    `(num_layers, 2, n_blocks, block_size, num_embed)` — the sizing
-    arithmetic the nightly HBM-accounting gate and `bench.py --serve
-    --sharded` use without materialising arrays.  `quant` prices the
-    int8 pool plus its f32 per-(block, position) scales; `shards > 1`
-    returns the PER-DEVICE bytes of a sub-mesh replica (embed axis
-    split; scales replicated, matching `kv_shardings`)."""
-    elems = int(num_layers) * 2 * int(n_blocks) * int(block_size)
-    num_embed, shards = int(num_embed), int(shards)
-    # non-divisible embed falls back to a replicated pool (kv_shardings)
-    per_dev_embed = num_embed // shards if num_embed % shards == 0 \
-        else num_embed
-    if quant:
-        # int8 payload + replicated f32 scale per (L, 2, block, pos)
-        return elems * per_dev_embed + elems * 4
-    return elems * per_dev_embed * int(itemsize)
 
 
 class BlockAllocator:

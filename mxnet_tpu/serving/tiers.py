@@ -64,7 +64,17 @@ import numpy as np
 
 from ..base import MXNetError
 
-__all__ = ["HostBlockTier", "pack_block_run"]
+__all__ = ["HostBlockTier", "pack_block_run", "check_cache_kind"]
+
+
+def check_cache_kind(model, what):
+    """Block runs are packed and landed as ``(layers, 2, k, block_size,
+    embed)`` K/V pairs; a model whose pool is of another kind (`cache_kind`)
+    is refused by name here rather than mis-copied."""
+    kind = getattr(model, "cache_kind", "kv_pair")
+    if kind != "kv_pair":
+        raise MXNetError("%s know the kv_pair pool layout only: %s's cache "
+                         "kind is %r" % (what, type(model).__name__, kind))
 
 
 def pack_block_run(model, block_size, arrs, kb):
@@ -79,6 +89,7 @@ def pack_block_run(model, block_size, arrs, kb):
     pack in lockstep.  Entries past ``len(arrs)`` stay zero; the
     caller's trash-padded destination ids scatter them into the trash
     block."""
+    check_cache_kind(model, "the host tier's block runs")
     data = model.block_run_placeholder(kb, block_size)
     for j, a in enumerate(arrs):
         if isinstance(data, tuple):

@@ -368,6 +368,103 @@ def test_megastep_scan_reads_the_pool_through_the_kernel(on_tpu, one_chip):
     assert memory.temp_size_in_bytes < k_pool_bytes
 
 
+# -- the latent pool's programs at the served widths -------------------------
+
+
+def _latent_model(layers):
+    """Kimi-K2.5's widths, this chip's share (12 of 384 experts, an eighth
+    of the vocabulary), ``layers`` deep: one dense layer, the rest expert
+    layers."""
+    from mxnet_tpu.base import bfloat16
+    from mxnet_tpu.serving import LatentMoEKVModel
+
+    return LatentMoEKVModel(
+        20480, 16384, layers, 7168, 64, 1536, 512, 128, 64, 128, 18432,
+        2048, 384, (0, 12), 8, first_dense=1, routed_scaling_factor=2.827,
+        rope_theta=50000.0, rope_scaling=dict(
+            beta_fast=32, beta_slow=1, factor=64, mscale=1, mscale_all_dim=1,
+            original_max_position_embeddings=4096), dtype=bfloat16)
+
+
+def _latent_program(one_chip, what, n, layers=2, n_blocks=4000, bs=64):
+    """`serve_decode_b<n>` or `serve_prefill_s<n>` of the latent model,
+    compiled for the described chip with the pool donated: (its text, its
+    memory analysis, the bytes of one layer of the pool)."""
+    model = _latent_model(layers)
+    params = {k: _bf16(v, one_chip) for k, v in model.param_shapes().items()}
+    pool = _bf16((layers, n_blocks, bs, model.pool_width), one_chip)
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    tables = ints(n if what == "decode" else 1, model.seq_len // bs)
+    if what == "decode":
+        def prog(params, pool, token, pos, tables):
+            logits, pool = model.decode_paged(params, pool, token, pos,
+                                              tables)
+            return jnp.argmax(logits, axis=-1), pool
+
+        args = (ints(n), ints(n), tables)
+    else:
+        def prog(params, pool, tokens, start, length, tables):
+            logits, pool = model.prefill_paged(params, pool, tokens, start,
+                                               length, tables)
+            return jnp.argmax(logits, axis=-1), pool
+
+        args = (ints(1, n), ints(1), ints(1), tables)
+    prog.__name__ = "serve_%s_%s%d" % (what, "b" if what == "decode" else "s",
+                                       n)
+    comp = jax.jit(prog, donate_argnums=(1,)).lower(params, pool,
+                                                    *args).compile()
+    return (comp.as_text(), comp.memory_analysis(),
+            n_blocks * bs * model.pool_width * 2)
+
+
+@pytest.mark.parametrize("batch", [1, 8, 64])
+def test_latent_decode_reads_the_pool_through_its_kernel(on_tpu, one_chip,
+                                                         batch):
+    """A decode launch over the latent pool at Kimi-K2.5's widths: one
+    `latent_decode_attn` custom call a layer under `decode_attention`, from
+    one lowered function; the pool donated and updated in place; and no
+    temporary that grows with the pool or with rows x table width x 576 (the
+    gathered context the `jax.numpy` body would make)."""
+    layers = 2
+    program = "serve_decode_b%d" % batch
+    text, memory, layer_bytes = _latent_program(one_chip, "decode", batch,
+                                                layers)
+    calls = [n for n in _op_names(text, program)
+             if n.endswith("/pallas_call")]
+    # (the compiler names the copies it puts around a call after it too)
+    assert set(calls) == {"decode_attention/jit(_latent_decode)/"
+                          "latent_decode_attn/pallas_call"}
+    assert text.count("tpu_custom_call") == layers
+    assert "input_output_alias" in text.splitlines()[0]
+    assert memory.alias_size_in_bytes == layers * layer_bytes
+    gathered = batch * 16384 * 576 * 2
+    assert memory.temp_size_in_bytes < min(layer_bytes, gathered)
+
+
+@pytest.mark.parametrize("chunk,layers", [(512, 2), (64, 4)])
+def test_latent_prefill_holds_no_table_wide_scores(on_tpu, one_chip, chunk,
+                                                   layers):
+    """A chunk over a table of 16,384 positions: the blockwise loop's
+    temporaries are of one context block, far from the 2.1 GB that float32
+    scores of 512 x table width x heads would take (or the 0.7 GB of the
+    whole context expanded to per-head keys and values), and under one
+    layer of the pool.  The one-block chunk at four layers is the case in
+    which the TPU compiler once laid the whole pool out anew to suit a
+    whole-block scatter (a dynamic-update-slice to it) and copied it: the
+    chunk's rows are scattered row by row since (my chip run, PR 30)."""
+    text, memory, layer_bytes = _latent_program(one_chip, "prefill", chunk,
+                                                layers)
+    assert "input_output_alias" in text.splitlines()[0]
+    assert memory.alias_size_in_bytes == layers * layer_bytes
+    assert memory.temp_size_in_bytes < layer_bytes
+    names = _op_names(text, "serve_prefill_s%d" % chunk)
+    assert any(n.startswith("mla_prefill_loop/while") for n in names)
+    assert any("moe_loop/while/body/moe_experts/" in n for n in names)
+
+
 # -- whole train steps (toy widths: seconds each) ---------------------------
 
 

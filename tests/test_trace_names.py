@@ -17,7 +17,8 @@ import jax.numpy as jnp
 import mxnet_tpu as mx
 from mxnet_tpu import models
 from mxnet_tpu.parallel import SPMDTrainer, make_mesh
-from mxnet_tpu.serving import ServingEngine, TransformerKVModel
+from mxnet_tpu.serving import (LatentMoEKVModel, ServingEngine,
+                               TransformerKVModel)
 
 SERVING_SCOPES = {"embed", "qkv_proj", "kv_scatter", "kv_gather", "attn_out",
                   "ffn", "lm_head", "sampler"}
@@ -58,6 +59,37 @@ def test_serving_programs_carry_their_name_and_scopes(engine, build, module,
     text = getattr(engine, build)(bucket).as_text()
     assert text.startswith("HloModule %s," % module)
     assert _scopes(text) >= SERVING_SCOPES | {attention}
+
+
+LATENT_SCOPES = {"embed", "mla_q_proj", "mla_kv_proj", "latent_scatter",
+                 "attn_out", "ffn", "moe_router", "moe_dispatch",
+                 "moe_experts", "moe_shared", "moe_combine", "moe_loop",
+                 "lm_head", "sampler"}
+
+
+@pytest.fixture(scope="module")
+def latent_engine():
+    model = LatentMoEKVModel(61, 32, 2, 32, 2, 12, 8, 8, 4, 8, 48, 16, 8,
+                             (2, 6), 2, routed_scaling_factor=2.0)
+    return ServingEngine(model, model.init_params(np.random.RandomState(3)),
+                         max_batch=4, block_size=4, n_blocks=32,
+                         prefill_buckets=[8], decode_buckets=[2],
+                         name="latent_names")
+
+
+@pytest.mark.parametrize("build,module,attention", [
+    ("_compiled_decode", "jit_serve_decode_b2", {"decode_attention"}),
+    ("_compiled_prefill", "jit_serve_prefill_s8",
+     {"mla_prefill_attention", "mla_prefill_loop"}),
+])
+def test_latent_programs_carry_their_name_and_scopes(latent_engine, build,
+                                                     module, attention):
+    """The second model class under the engine's own program names: what
+    the `.kimi` metrics' readers look for (`benchmark/metrics/*.kimi.json`)."""
+    bucket = 8 if build == "_compiled_prefill" else 2
+    text = getattr(latent_engine, build)(bucket).as_text()
+    assert text.startswith("HloModule %s," % module)
+    assert _scopes(text) >= LATENT_SCOPES | attention
 
 
 def test_pool_programs_carry_their_names(engine):
@@ -263,3 +295,21 @@ def test_paged_decode_attention_is_named_either_way(for_tpu, pool_dtype,
         assert _kernels(text) == set()
         assert 'kv_gather/gather"' in text
         assert 'decode_attention/' in text
+
+
+def test_latent_decode_kernel_is_named_in_its_lowered_call(for_tpu):
+    """`mla_decode_attn_ms_per_launch.kimi` and `mla_decode_roofline.kimi`
+    read the scope `decode_attention`: on a TPU the latent kernel runs under
+    it with a name of its own, inside the function the layers share."""
+    from mxnet_tpu.ops.latent_attention import latent_decode_attention
+
+    text = for_tpu(
+        lambda q, pool, tables, pos: latent_decode_attention(
+            q, pool, 1, tables, pos, 128, 0.1),
+        jax.ShapeDtypeStruct((4, 16, 256), jnp.bfloat16),
+        jax.ShapeDtypeStruct((2, 16, 32, 256), jnp.bfloat16),
+        jax.ShapeDtypeStruct((4, 8), jnp.int32),
+        jax.ShapeDtypeStruct((4,), jnp.int32))
+    assert _kernels(text) == {"latent_decode_attn"}
+    assert '"latent_decode_attn/pallas_call"' in text
+    assert 'decode_attention/jit(_latent_decode)"' in text

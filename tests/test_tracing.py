@@ -714,7 +714,10 @@ def test_iteration_record_counts_what_the_iteration_did(model_and_params):
     for s in its:
         a = s["attrs"]
         assert set(a) == {"rows", "bucket", "chunks", "queued",
-                          "blocks_live", "blocks_parked", "attn_kernel"}
+                          "blocks_live", "blocks_parked", "attn_kernel",
+                          "ctx_blocks"}
+        # the table entries the rows' attention walks: their live blocks
+        assert a["rows"] <= a["ctx_blocks"]
         assert 1 <= a["rows"] <= a["bucket"] and a["bucket"] in (2, 4)
         # the CPU backend's decode programs hold no Pallas kernel
         assert a["attn_kernel"] == 0
@@ -727,6 +730,7 @@ def test_iteration_record_counts_what_the_iteration_did(model_and_params):
     # write of the 9-token row lands in its third block, the 6-token row's
     # in its second
     assert its[0]["attrs"]["blocks_live"] == 5
+    assert its[0]["attrs"]["ctx_blocks"] == 5
     assert its[0]["attrs"]["rows"] == 2
     assert sum(s["attrs"]["rows"] for s in its) == eng.stats["decode_rows"]
     assert eng.leaked_blocks() == 0
