@@ -191,18 +191,33 @@ def decode_attention(q, k_cache, v_cache, pos, num_heads, *, scale=None):
 
 
 @jax.named_scope("kv_gather")
-def gather_paged_kv(pool, block_tables):
-    """Materialize per-row K or V context from a paged block pool.
+def gather_paged_kv(pool, layer, which, block_tables):
+    """Materialize per-row K or V context (or, for an int8 pool, its
+    dequantization scales) from a paged block pool.
 
-    pool:         (n_blocks, block_size, embed) — ONE layer's K (or V)
-                  block pool; block 0 is the engine's trash block.
+    pool:         (layers, 2, n_blocks, block_size, embed) — the WHOLE K/V
+                  pool, or the int8 pool's (layers, 2, n_blocks,
+                  block_size) f32 per-row scales, indexed exactly like it
+                  (scales travel WITH their block through sharing, CoW,
+                  spill and restore); block 0 is the engine's trash block.
+    layer, which: the layer, and 0 for K or 1 for V.
     block_tables: (b, m) int32 — row r's table entry t names the pool
                   block holding positions [t*block_size, (t+1)*block_size);
                   unallocated tail entries point at the trash block (their
                   positions are > pos[r], so the decode mask hides them).
-    Returns (b, m*block_size, embed): the same layout `decode_attention`
-    reads from a slot cache, reassembled by gather — paging changes WHERE
-    rows live, not what attention sees.
+    Returns (b, m*block_size, embed), or (b, m*block_size) of scales: the
+    same layout `decode_attention` reads from a slot cache, reassembled by
+    gather — paging changes WHERE rows live, not what attention sees.
+    Multiply gathered scales onto the gathered int8 rows
+    (``kc.astype(f32) * sc[..., None]``) to dequantize in-graph before the
+    attention math — position masking then hides the same tail entries it
+    always did, so trash-block scale garbage is never read.
+
+    ONE gather indexes the pool by (layer, which, table entry), so only the
+    table's blocks are read.  Taking the layer's K pool out first and
+    gathering from that names the same rows, but the TPU compiler
+    materialises the slice: a copy of a whole layer's K pool before every
+    gather (`slice_bitcast_fusion`; PERF.md, PR 31).
 
     Tables may ALIAS: with cross-request prefix sharing, several rows of
     one batch can name the same physical block (and the trash block is
@@ -213,28 +228,8 @@ def gather_paged_kv(pool, block_tables):
     tables share.
     """
     b, m = block_tables.shape
-    _, bs, e = pool.shape
-    return pool[block_tables.astype(jnp.int32)].reshape(b, m * bs, e)
-
-
-@jax.named_scope("kv_gather")
-def gather_paged_scales(scales, block_tables):
-    """Materialize per-row dequantization scales from a paged scale pool
-    (the int8-KV companion of `gather_paged_kv`).
-
-    scales:       (n_blocks, block_size) f32 — ONE layer's K (or V)
-                  per-row quantization scales, indexed exactly like the
-                  int8 block pool (scales travel WITH their block
-                  through sharing, CoW, spill and restore).
-    block_tables: (b, m) int32 — the same tables the K/V gather uses.
-    Returns (b, m*block_size): multiply onto the gathered int8 rows
-    (``kc.astype(f32) * sc[..., None]``) to dequantize in-graph before
-    the attention math — position masking then hides the same tail
-    entries it always did, so trash-block scale garbage is never read.
-    """
-    b, m = block_tables.shape
-    bs = scales.shape[1]
-    return scales[block_tables.astype(jnp.int32)].reshape(b, m * bs)
+    ctx = pool[layer, which, block_tables.astype(jnp.int32)]
+    return ctx.reshape((b, m * pool.shape[3]) + pool.shape[4:])
 
 
 def paged_decode_kernel_applies(pool, num_heads):
@@ -264,8 +259,8 @@ def paged_decode_attention(q, pool, layer, block_tables, pos, num_heads,
     dtype with the pool left whole in HBM, under the scope
     `decode_attention`.  Everywhere else (the CPU backend, a sharded
     engine, widths the kernel does not take) the `jax.numpy` body runs:
-    the layer's K and V pools sliced out, each row's blocks gathered by
-    table index (`gather_paged_kv`), then `decode_attention` over the
+    each row's blocks gathered from the whole pool by (layer, K or V,
+    table entry) (`gather_paged_kv`), then `decode_attention` over the
     table-wide context.  That body is the kernel's reference in the tests:
     the two agree up to the order of the float32 sums (masked tail
     positions contribute exact zeros either way).
@@ -282,12 +277,8 @@ def paged_decode_attention(q, pool, layer, block_tables, pos, num_heads,
         with jax.named_scope("decode_attention"):
             return paged_attention_mod.paged_decode_attn(
                 q, pool, layer, block_tables, pos, num_heads, scale=scale)
-    # the layer's slice of the pool fuses with the gather: taken inside
-    # the scope, or the fused copy has no name
-    with jax.named_scope("kv_gather"):
-        k_pool, v_pool = pool[layer, 0], pool[layer, 1]
-    kc = gather_paged_kv(k_pool, block_tables)
-    vc = gather_paged_kv(v_pool, block_tables)
+    kc = gather_paged_kv(pool, layer, 0, block_tables)
+    vc = gather_paged_kv(pool, layer, 1, block_tables)
     return decode_attention(q, kc, vc, pos, num_heads, scale=scale)
 
 

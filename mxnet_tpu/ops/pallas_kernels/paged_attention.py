@@ -4,8 +4,10 @@ One generated token per row attends to that row's context in the serving
 engine's paged K/V pool.  The `jax.numpy` form (`ops.attention`:
 `gather_paged_kv` + `decode_attention`) copies every entry of every row's
 block table out of the pool, live or trash, writes the copy again as float32
-split into heads, and reads that twice: 97 % of a decode launch on the chip
-(PERF.md, PR 25).  This kernel leaves the pool where it is.  For row ``r`` it
+split into heads, and reads that twice; as it stood, slicing the layer's K
+and V pools out before the gather, the TPU compiler copied those whole as
+well: together 97 % of a decode launch on the chip (PERF.md, PR 25 and
+PR 31).  This kernel leaves the pool where it is.  For row ``r`` it
 copies in only the blocks the row has reached, entries
 ``0 .. min(pos[r] // block_size, m - 1)`` of its table, in the pool's own
 dtype, double-buffered, ``chunk`` blocks to a step (the next chunk's copies,
@@ -227,8 +229,8 @@ def paged_decode_attn(q, pool, layer, block_tables, pos, num_heads, *,
     pos:          (b,) int32: the position the query occupies; its K/V row
                   is already in the pool
     Returns (b, embed) in q's dtype, equal to
-    `decode_attention(q, gather_paged_kv(pool[layer, 0], block_tables),
-    gather_paged_kv(pool[layer, 1], block_tables), pos, num_heads)` up to
+    `decode_attention(q, gather_paged_kv(pool, layer, 0, block_tables),
+    gather_paged_kv(pool, layer, 1, block_tables), pos, num_heads)` up to
     the order of the float32 sums.
     """
     hd = q.shape[1] // num_heads

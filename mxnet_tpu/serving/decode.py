@@ -62,8 +62,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec
 
 from ..base import MXNetError
-from ..ops.attention import (gather_paged_kv, gather_paged_scales,
-                             paged_decode_attention,
+from ..ops.attention import (gather_paged_kv, paged_decode_attention,
                              paged_decode_kernel_applies, decode_attention,
                              chunk_attention, verify_attention)
 from ..ops.pallas_kernels.flash_attention import flash_attention
@@ -562,10 +561,10 @@ class TransformerKVModel:
         gathered rows upcast to f32 and multiply by their gathered
         per-row scales before the attention math (which runs f32
         softmax statistics regardless)."""
-        ctx = gather_paged_kv(pool[layer, which], tables)
+        ctx = gather_paged_kv(pool, layer, which, tables)
         if scales is None:
             return ctx
-        sc = gather_paged_scales(scales[layer, which], tables)
+        sc = gather_paged_kv(scales, layer, which, tables)
         return ctx.astype(jnp.float32) * sc[..., None]
 
     def init_block_pool(self, n_blocks, block_size, device=None):

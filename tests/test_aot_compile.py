@@ -187,21 +187,20 @@ def test_ring_attention_compiles_for_four_devices(on_tpu, topo):
     assert "collective-permute" in comp.as_text()
 
 
-@pytest.mark.parametrize("program", ["prefill", "decode"])
-def test_sharded_serving_programs_compile_for_four_devices(on_tpu, topo,
-                                                           program):
-    """A sub-mesh serving replica's programs (`ServingEngine` with a Mesh):
-    params and the paged K/V pool sharded by the model's own rules, the
-    trace scoped to the mesh as `ServingEngine._scoped` does — the fused
-    LayerNorm then runs per device instead of being refused as not
-    partitionable."""
+def _sharded_serving_program(topo, program, n_blocks=256, heads=6):
+    """A sub-mesh serving replica's prefill or decode program (`ServingEngine`
+    with a Mesh) compiled for the four described devices, ``heads`` heads of
+    128: params and the paged K/V pool sharded by the model's own rules, the
+    trace scoped to the mesh as `ServingEngine._scoped` does.  (the compiled
+    program, the model's depth, the bytes of one layer's K pool on one
+    device)."""
     from mxnet_tpu.base import bfloat16
     from mxnet_tpu.parallel.mesh import MeshContext
     from mxnet_tpu.serving import TransformerKVModel
 
-    L, V, S, E, bs, n_blocks = 2, 32768, 1024, 768, 16, 256
-    model = TransformerKVModel(V, S, num_layers=L, num_heads=6, num_embed=E,
-                               use_bias=False, dtype=bfloat16)
+    L, V, S, E, bs = 2, 32768, 1024, 128 * heads, 16
+    model = TransformerKVModel(V, S, num_layers=L, num_heads=heads,
+                               num_embed=E, use_bias=False, dtype=bfloat16)
     mesh = Mesh(np.array(topo.devices), ("model",))
     repl = NamedSharding(mesh, P())
     shardings = model.param_shardings(mesh)
@@ -232,13 +231,24 @@ def test_sharded_serving_programs_compile_for_four_devices(on_tpu, topo,
     comp = jax.jit(prog, donate_argnums=(1,),
                    out_shardings=(repl, kv)).lower(params, pool,
                                                    *args).compile()
-    assert comp.as_text().count("tpu_custom_call") == 2 * L + 1
+    return comp, L, n_blocks * bs * (E // mesh.size) * 2
 
 
-def _decode_program(one_chip, batch, n_blocks, layers=1, kv_quant=None):
-    """`serve_decode_b<batch>` at GPT-2 large's widths, compiled for the
-    described chip with the pool donated: (its text, its memory analysis,
-    the bytes of one layer's K pool)."""
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_sharded_serving_programs_compile_for_four_devices(on_tpu, topo,
+                                                           program):
+    """A sub-mesh serving replica's programs: with the trace scoped to the
+    mesh the fused LayerNorm runs per device instead of being refused as not
+    partitionable."""
+    comp, layers, _ = _sharded_serving_program(topo, program)
+    assert comp.as_text().count("tpu_custom_call") == 2 * layers + 1
+
+
+def _serving_program(one_chip, what, n, n_blocks, layers=1, kv_quant=None):
+    """`serve_decode_b<n>`, `serve_prefill_s<n>` or `serve_verify_b<n>` (four
+    drafts a row) at GPT-2 large's widths, compiled for the described chip
+    with the pool donated: (its text, its memory analysis, the bytes of one
+    layer's K pool)."""
     from mxnet_tpu.base import bfloat16
     from mxnet_tpu.serving import TransformerKVModel
 
@@ -257,26 +267,75 @@ def _decode_program(one_chip, batch, n_blocks, layers=1, kv_quant=None):
     def ints(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
 
-    def serve_decode(params, pool, token, pos, tables):
-        logits, pool = model.decode_paged(params, pool, token, pos, tables)
-        return jnp.argmax(logits, axis=-1), pool
+    if what == "decode":
+        def prog(params, pool, token, pos, tables):
+            logits, pool = model.decode_paged(params, pool, token, pos,
+                                              tables)
+            return jnp.argmax(logits, axis=-1), pool
 
-    serve_decode.__name__ = "serve_decode_b%d" % batch
-    comp = jax.jit(serve_decode, donate_argnums=(1,)).lower(
-        params, pool, ints(batch), ints(batch),
-        ints(batch, S // bs)).compile()
-    return comp.as_text(), comp.memory_analysis(), n_blocks * bs * E * 2
+        args = (ints(n), ints(n), ints(n, S // bs))
+    else:
+        step = {"prefill": model.prefill_paged,
+                "verify": model.verify_paged}[what]
+
+        def prog(params, pool, tokens, start, length, tables):
+            logits, pool = step(params, pool, tokens, start, length, tables)
+            return jnp.argmax(logits, axis=-1), pool
+
+        rows, width = (1, n) if what == "prefill" else (n, 5)
+        args = (ints(rows, width), ints(rows), ints(rows),
+                ints(rows, S // bs))
+    prog.__name__ = "serve_%s_%s%d" % (what, "s" if what == "prefill" else "b",
+                                       n)
+    comp = jax.jit(prog, donate_argnums=(1,)).lower(params, pool,
+                                                    *args).compile()
+    itemsize = 2 if kv_quant is None else 1
+    return (comp.as_text(), comp.memory_analysis(),
+            n_blocks * bs * E * itemsize)
 
 
 def _op_names(text, program):
     return re.findall(r'op_name="jit\(%s\)/([^"]+)"' % program, text)
 
 
+_ITEMSIZE = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s32": 4,
+             "u32": 4, "f32": 4}
+
+
+def _assert_pool_is_only_updated_in_place(text, k_pool_bytes, n_blocks):
+    """No instruction of the entry computation makes a value the size of one
+    layer's K pool, or a layer's slice of anything laid out by block (an
+    int8 pool's scales are a 320th of its rows), but the pool's own
+    parameters and the in-place `kv_scatter` of the donated pool: a fusion
+    named after the scatter, or one whose root is the scatter (the compiler
+    fuses a one-block chunk's projection into the update and names the fusion
+    after the matmul).  Tuples and bitcasts make no value.  (The scales whole
+    are laid out anew on the way in and on the way out, before and after
+    this PR: the device keeps their 16-wide rows with the blocks minor.)"""
+    entry = text[text.index("\nENTRY "):]
+    for m in re.finditer(r"\n\s+(?:ROOT )?%\S+ = (.*?) ([\w-]+)\((.*)", entry):
+        types, opcode, rest = m.groups()
+        if opcode in ("parameter", "tuple", "get-tuple-element", "bitcast"):
+            continue
+        for dtype, dims in re.findall(r"(\w+)\[([\d,]+)\]", types):
+            dims = [int(d) for d in dims.split(",")]
+            if n_blocks in dims[:2] or np.prod(dims) * _ITEMSIZE[dtype] \
+                    >= k_pool_bytes:
+                break
+        else:
+            continue
+        called = re.search(r"calls=(%[\w.-]+)", rest)
+        root = re.search(r"\n%s .*?\n\s+ROOT ([^\n]*)" % re.escape(
+            called.group(1)), text, re.S).group(1) if called else ""
+        assert "kv_scatter/scatter" in rest or "kv_scatter/scatter" in root, \
+            m.group(0)[:300]
+
+
 @pytest.mark.parametrize("path", ["kernel", "jnp_int8_pool"])
 def test_decode_pool_is_read_as_the_tpu_compiler_names_it(on_tpu, one_chip,
                                                           path):
     """How a decode launch reads the pool, in the compiled program's own
-    names, at GPT-2 large's widths (PERF.md, PR 25 and PR 26).
+    names, at GPT-2 large's widths (PERF.md, PR 25, PR 26 and PR 31).
 
     kernel: the bf16 pool goes to one `paged_decode_attn` custom call a
     layer, under `decode_attention`, whole: nothing gathers it, no
@@ -285,30 +344,34 @@ def test_decode_pool_is_read_as_the_tpu_compiler_names_it(on_tpu, one_chip,
     smaller than that.  It compiles for every decode bucket of the cell.
 
     jnp_int8_pool: the quantised pool keeps `gather_paged_kv` +
-    `decode_attention`.  The TPU compiler fuses a layer's slice of the pool
-    with the gather through the block tables (`slice_bitcast_fusion`, a
-    quarter of a decode launch on the chip before the kernel) and names the
-    fusion after the slice: the slice has to be taken inside the `kv_gather`
-    scope, or the largest operation of such a launch reads as time under no
-    scope."""
+    `decode_attention`.  One gather a layer and K or V reads the table's
+    blocks out of the whole pool, and one their scales, all under
+    `kv_gather`.  (Until PR 31 the layer's K pool was sliced out first; the
+    TPU compiler materialised the slice, `slice_bitcast_fusion` under the
+    name `kv_gather/squeeze`: a copy of a layer's pool before every gather,
+    a quarter of a decode launch on the chip before the kernel and three
+    quarters of a prefill chunk.)"""
     if path == "jnp_int8_pool":
-        text, _, _ = _decode_program(one_chip, 4, 64, kv_quant="int8")
+        text, _, _ = _serving_program(one_chip, "decode", 4, 64,
+                                      kv_quant="int8")
         names = set(_op_names(text, "serve_decode_b4"))
         assert "tpu_custom_call" in text        # the fused LayerNorms
         assert not any("paged_decode_attn" in n for n in names)
         # (`_gather_ctx` dequantizes under the scope `gather_paged_kv`
         # gathers under: the gather's name holds it twice)
-        assert {"kv_gather/squeeze", "kv_gather/kv_gather/gather",
+        assert {"kv_gather/kv_gather/gather", "kv_gather/mul",
                 "kv_scatter/scatter"} <= names
-        # none of the pool's own operations outside its scopes
+        # no slice of the pool, and none of the pool's own operations
+        # outside its scopes
+        assert not any(n.endswith("/squeeze") for n in names)
         assert not names & {"squeeze", "gather", "scatter", "dynamic_slice",
                             "dynamic_update_slice"}
         return
     layers, n_blocks = 2, 3600
     for batch in (1, 2, 4, 8, 16, 32, 64):
         program = "serve_decode_b%d" % batch
-        text, memory, k_pool_bytes = _decode_program(one_chip, batch,
-                                                     n_blocks, layers)
+        text, memory, k_pool_bytes = _serving_program(
+            one_chip, "decode", batch, n_blocks, layers)
         names = _op_names(text, program)
         calls = [n for n in names if n.endswith("/pallas_call")
                  and "paged_decode_attn" in n]
@@ -323,15 +386,52 @@ def test_decode_pool_is_read_as_the_tpu_compiler_names_it(on_tpu, one_chip,
         assert memory.alias_size_in_bytes == 2 * layers * k_pool_bytes
         # ... and nothing the size of one layer's K pool is made beside it
         assert memory.temp_size_in_bytes < k_pool_bytes
-        entry = text[text.index("\nENTRY "):]
-        for m in re.finditer(r"\n\s+(?:ROOT )?%\S+ = \w+\[([\d,]+)\]\S* "
-                             r"([\w-]+)\((.*)", entry):
-            dims, opcode, rest = m.groups()
-            if np.prod([int(d) for d in dims.split(",")]) * 2 \
-                    < k_pool_bytes:
-                continue
-            assert opcode == "parameter" or "kv_scatter/scatter" in rest, \
-                m.group(0)[:300]
+        _assert_pool_is_only_updated_in_place(text, k_pool_bytes, n_blocks)
+
+
+@pytest.mark.parametrize("case", [
+    "prefill_s16", "prefill_s32", "prefill_s64", "prefill_s128",
+    "prefill_s256", "prefill_s512", "verify_b4", "int8_decode_b4",
+    "int8_prefill_s512", "sharded_decode_b8"])
+def test_jnp_paths_read_the_table_from_the_pool_in_place(on_tpu, topo,
+                                                         one_chip, case):
+    """Every program that reads a row's context with `gather_paged_kv` (the
+    serving cell's six prefill chunks, the speculative verify launch, the
+    int8 pool's decode and prefill, the tensor-sharded engine's decode), at
+    2 layers and 3,600 blocks: the pool (and the int8 pool's scales) is
+    donated and aliased in and out, the temporaries are under one layer's K
+    pool, and nothing of that size is made but by the in-place `kv_scatter`.
+
+    Until PR 31 each of them sliced the layer's K and V pools out before the
+    gather, and the TPU compiler made the slices: 2 x 147 MB of temporaries
+    at these sizes, 10.6 GB read and written a chunk at GPT-2 large's 36
+    layers, 32 of a chunk's 42 ms on the chip (PERF.md, PR 31)."""
+    layers, n_blocks = 2, 3600
+    if case == "sharded_decode_b8":
+        # (8 heads: a device's 2 are whole tiles of 128 lanes.  The 6 heads
+        # of the program above leave it 192 lanes, and the device then keeps
+        # the pool blocks-minor and the program lays all of it out anew on
+        # the way in and out, before and after PR 31: ROADMAP.md S3)
+        comp, _, k_pool_bytes = _sharded_serving_program(topo, "decode",
+                                                         n_blocks, heads=8)
+        text, memory = comp.as_text(), comp.memory_analysis()
+        aliased = 2 * layers * k_pool_bytes
+    else:
+        kv_quant = "int8" if case.startswith("int8_") else None
+        program = case.removeprefix("int8_")
+        what, n = program.split("_")
+        text, memory, k_pool_bytes = _serving_program(
+            one_chip, what, int(n[1:]), n_blocks, layers, kv_quant)
+        aliased = 2 * layers * k_pool_bytes
+        if kv_quant is not None:    # a float32 scale a row of 1,280 int8
+            aliased += aliased // 1280 * 4
+        assert any("kv_gather" in n
+                   for n in _op_names(text, "serve_" + program))
+    assert "input_output_alias" in text.splitlines()[0]
+    # (at least: the compiler pads the scales' and the shards' tiles)
+    assert memory.alias_size_in_bytes >= aliased
+    assert memory.temp_size_in_bytes < k_pool_bytes
+    _assert_pool_is_only_updated_in_place(text, k_pool_bytes, n_blocks)
 
 
 def test_megastep_scan_reads_the_pool_through_the_kernel(on_tpu, one_chip):

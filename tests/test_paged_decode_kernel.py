@@ -3,7 +3,7 @@
 The kernel body (`ops/pallas_kernels/paged_attention.py`) runs through the
 Pallas interpreter on the CPU; the reference is what every pool the kernel
 does not take still runs: `decode_attention` over `gather_paged_kv` of the
-layer's K and V pools.  One test per contract of the kernel, one case per
+layer's K and V blocks.  One test per contract of the kernel, one case per
 shape, so that each counts.  (That the kernel lowers for the chip at the
 cell's widths is `tests/test_aot_compile.py`'s.)
 """
@@ -44,8 +44,8 @@ def _tables(rs, b, m, n_blocks):
 
 
 def _reference(q, pool, tables, pos, heads):
-    return decode_attention(q, gather_paged_kv(pool[LAYER, 0], tables),
-                            gather_paged_kv(pool[LAYER, 1], tables), pos,
+    return decode_attention(q, gather_paged_kv(pool, LAYER, 0, tables),
+                            gather_paged_kv(pool, LAYER, 1, tables), pos,
                             heads)
 
 

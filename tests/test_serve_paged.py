@@ -167,6 +167,81 @@ def test_paged_vs_slot_token_parity_mid_batch(model_and_params):
     assert outs[True] == outs[False]
 
 
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32", "int8"])
+def test_gather_from_the_whole_pool_reads_the_slices_rows(dtype):
+    """`gather_paged_kv` indexes the whole pool by (layer, K or V, table
+    entry) in one gather (PERF.md, PR 31: the TPU compiler copies a layer's
+    slice of the pool when it is taken first).  The rows are bit for bit
+    those of `pool[layer, which][tables]`, eagerly and traced, with ragged
+    tables (tails on the trash block), entries two rows share, a row that
+    is all trash, and garbage in the trash block; the int8 pool's scales
+    likewise, and the dequantised context `_gather_ctx` hands attention."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops.attention import gather_paged_kv
+
+    rs = np.random.RandomState(31)
+    layers, n_blocks, bs, embed = 3, 9, 4, 8
+    shape = (layers, 2, n_blocks, bs, embed)
+    scales = None
+    if dtype == "int8":
+        pool = jnp.asarray(rs.randint(-127, 128, size=shape), jnp.int8)
+        scales = jnp.asarray(rs.rand(*shape[:-1]) + 0.01, jnp.float32)
+        scales = scales.at[:, :, TRASH_BLOCK].set(np.nan)   # never read
+    else:
+        pool = jnp.asarray(rs.randn(*shape) * 3, dtype)
+    tables = np.array([[3, 7, 1, TRASH_BLOCK],              # a ragged tail
+                       [3, 7, 5, 8],                        # a shared prefix
+                       [TRASH_BLOCK] * 4,                   # a padding row
+                       [2, TRASH_BLOCK, TRASH_BLOCK, TRASH_BLOCK]], np.int32)
+    traced = jax.jit(gather_paged_kv, static_argnums=(1, 2))
+    for layer in range(layers):
+        for which in (0, 1):
+            for src in (pool, scales) if scales is not None else (pool,):
+                want = np.asarray(src)[layer, which][tables].reshape(
+                    (4, 4 * bs) + src.shape[4:])
+                for got in (gather_paged_kv(src, layer, which, tables),
+                            traced(src, layer, which, jnp.asarray(tables))):
+                    assert got.dtype == src.dtype
+                    np.testing.assert_array_equal(np.asarray(got), want)
+    if scales is None:
+        return
+    model = TransformerKVModel(V, S, num_layers=layers, num_heads=2,
+                               num_embed=embed, kv_quant="int8")
+    got = np.asarray(model._gather_ctx(pool, scales, 1, 1, tables))
+    want = (np.asarray(pool, np.float32)[1, 1][tables]
+            * np.asarray(scales)[1, 1][tables][..., None])
+    np.testing.assert_array_equal(got, want.reshape(4, 4 * bs, embed))
+
+
+@pytest.mark.parametrize("path", ["chunked_prefill", "verify"])
+def test_paths_that_gather_the_context_match_the_slot_engine(
+        model_and_params, path):
+    """Every `jax.numpy` path that reads a row's context through
+    `gather_paged_kv` (a prefill chunk over a cached prefix; the speculative
+    verify launch; the CPU's decode launch in both) against the slot-cache
+    engine, which has no pool to gather from: the same greedy tokens."""
+    model, params = model_and_params
+    rng = np.random.RandomState(31)
+    prompts = [list(rng.randint(0, V, size=n)) for n in (14, 3, 11, 16)]
+    slot = _engine(model, params, paged=False, decode_buckets=[4])
+    want = _drain(slot, [slot.submit(p, max_new_tokens=6) for p in prompts])
+    if path == "chunked_prefill":
+        eng = _engine(model, params, prefill_buckets=[8],
+                      decode_buckets=[4])
+    else:
+        eng = _engine(model, params, spec=True, spec_k=3,
+                      spec_drafter="ngram", decode_buckets=[4])
+    got = _drain(eng, [eng.submit(p, max_new_tokens=6) for p in prompts])
+    assert got == want
+    reg = telemetry.registry()
+    if path == "chunked_prefill":
+        assert reg.counter("serve.prefill_chunks").value >= 7
+    else:
+        assert reg.counter("serve.verify_steps").value > 0
+
+
 def test_paged_zero_retrace_and_frozen_cache(model_and_params):
     """The paged bucket set compiles once at warmup; mixed traffic —
     including a chunked long prompt — compiles nothing after: no

@@ -193,9 +193,10 @@ def test_gather_paged_kv_aliased_tables():
     """Two rows naming the SAME physical block read identical shared
     rows — the read side of sharing needs no special casing."""
     rng = np.random.RandomState(3)
-    pool = jnp.asarray(rng.randn(5, 4, 8).astype(np.float32))
+    pools = jnp.asarray(rng.randn(2, 2, 5, 4, 8).astype(np.float32))
+    pool = pools[1, 0]
     tables = jnp.asarray(np.array([[1, 2], [1, 3]], np.int32))
-    out = np.asarray(gather_paged_kv(pool, tables))
+    out = np.asarray(gather_paged_kv(pools, 1, 0, tables))
     np.testing.assert_array_equal(out[0, :4], np.asarray(pool)[1])
     np.testing.assert_array_equal(out[1, :4], np.asarray(pool)[1])
     np.testing.assert_array_equal(out[0, 4:], np.asarray(pool)[2])
