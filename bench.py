@@ -609,8 +609,7 @@ def serve_bench(record=True, with_chaos=False):
     elif trace == "mixed":
         # log-normal prompt/output lengths (the realistic mixed-length
         # traffic paging exists for): most requests short, a heavy tail
-        # near the cap — the slot cache reserves for the tail on every
-        # request, the paged cache only pays for what each one uses
+        # near the cap — the paged cache only pays for what each one uses
         sigma = float(os.environ.get("SERVE_TRACE_SIGMA", "0.6"))
         def _lens(mean, cap, n):
             mu = np.log(max(mean, 1.5)) - sigma * sigma / 2.0
@@ -718,57 +717,52 @@ def serve_bench(record=True, with_chaos=False):
     rows = sum(e.stats["decode_rows"] for e in router.engines)
     padded = sum(e.stats["decode_padded"] for e in router.engines)
     max_concurrent = max(e.stats["max_concurrent"] for e in router.engines)
-    paged_engines = [e for e in router.engines if e._alloc is not None]
-    blocks = None
-    if paged_engines:
-        # leak check runs post-stop: every retired/failed/stranded
-        # sequence must have returned its blocks
-        def _sum(key):
-            return sum(e.stats[key] for e in paged_engines)
+    def _sum(key):
+        return sum(e.stats[key] for e in router.engines)
 
-        # leak check runs post-stop: blocks neither free, nor held, nor
-        # parked in the prefix pool (parked blocks are deliberate cache,
-        # not leaks)
-        looked = _sum("prefix_lookup_tokens")
-        blocks = {
-            "block_size": paged_engines[0].block_size,
-            "n_blocks": sum(e.n_blocks for e in paged_engines),
-            "free_min": min(e.stats["blocks_free_min"]
-                            for e in paged_engines),
-            "leaked": sum(e.leaked_blocks() for e in paged_engines),
-            "parked": sum(e._prefix.parked_count for e in paged_engines
-                          if e._prefix is not None),
-            "prefill_chunks": _sum("prefill_chunks"),
-            "preemptions": _sum("preemptions"),
-            "alloc_denied": _sum("alloc_denied"),
-            "prefix": None if all(e._prefix is None for e in paged_engines)
-            else {
-                "hits": _sum("prefix_hits"),
-                "bootstraps": _sum("prefix_bootstraps"),
-                "tokens_matched": _sum("prefix_tokens"),
-                "hit_rate": round(_sum("prefix_tokens") /
-                                  float(max(looked, 1)), 4),
-                "cow_copies": _sum("cow_copies"),
-                "evictions": _sum("prefix_evictions"),
-            },
-            # host-DRAM tier (docs/serving.md "Memory tiering &
-            # sessions"); None when MXNET_SERVE_TIER=0
-            "tier": None if all(e._tier is None for e in paged_engines)
-            else {
-                "host_blocks": sum(e._tier.capacity for e in paged_engines
-                                   if e._tier is not None),
-                "host_used": sum(e._tier.used for e in paged_engines
-                                 if e._tier is not None),
-                "host_leaked": sum(e.leaked_host_blocks()
-                                   for e in paged_engines),
-                "spilled": _sum("spilled"),
-                "restored": _sum("restored"),
-                "restored_tokens": _sum("restored_tokens"),
-                "spill_fails": _sum("spill_fails"),
-                "restore_fails": _sum("restore_fails"),
-                "session_hits": _sum("session_hits"),
-            },
-        }
+    # leak check runs post-stop: blocks neither free, nor held, nor
+    # parked in the prefix pool (parked blocks are deliberate cache,
+    # not leaks)
+    looked = _sum("prefix_lookup_tokens")
+    blocks = {
+        "block_size": router.engines[0].block_size,
+        "n_blocks": sum(e.n_blocks for e in router.engines),
+        "free_min": min(e.stats["blocks_free_min"]
+                        for e in router.engines),
+        "leaked": sum(e.leaked_blocks() for e in router.engines),
+        "parked": sum(e._prefix.parked_count for e in router.engines
+                      if e._prefix is not None),
+        "prefill_chunks": _sum("prefill_chunks"),
+        "preemptions": _sum("preemptions"),
+        "alloc_denied": _sum("alloc_denied"),
+        "prefix": None if all(e._prefix is None for e in router.engines)
+        else {
+            "hits": _sum("prefix_hits"),
+            "bootstraps": _sum("prefix_bootstraps"),
+            "tokens_matched": _sum("prefix_tokens"),
+            "hit_rate": round(_sum("prefix_tokens") /
+                              float(max(looked, 1)), 4),
+            "cow_copies": _sum("cow_copies"),
+            "evictions": _sum("prefix_evictions"),
+        },
+        # host-DRAM tier (docs/serving.md "Memory tiering &
+        # sessions"); None when MXNET_SERVE_TIER=0
+        "tier": None if all(e._tier is None for e in router.engines)
+        else {
+            "host_blocks": sum(e._tier.capacity for e in router.engines
+                               if e._tier is not None),
+            "host_used": sum(e._tier.used for e in router.engines
+                             if e._tier is not None),
+            "host_leaked": sum(e.leaked_host_blocks()
+                               for e in router.engines),
+            "spilled": _sum("spilled"),
+            "restored": _sum("restored"),
+            "restored_tokens": _sum("restored_tokens"),
+            "spill_fails": _sum("spill_fails"),
+            "restore_fails": _sum("restore_fails"),
+            "session_hits": _sum("session_hits"),
+        },
+    }
     # decode-loop accounting (docs/serving.md "Megastep decode &
     # streaming"): host_frac = exposed host time / decode-loop wall —
     # reported for EVERY leg (the single-step baseline included), so the
@@ -923,7 +917,7 @@ def serve_bench(record=True, with_chaos=False):
         "output_sig": sig,
         "batch_occupancy": round(rows / max(rows + padded, 1), 4),
         "max_concurrent": max_concurrent,
-        "cache": "paged" if paged_engines else "slot",
+        "cache": "paged",
         "blocks": blocks,
         "decode_loop": decode_loop,
         "spec": spec_stats,
@@ -942,76 +936,6 @@ def serve_bench(record=True, with_chaos=False):
         "telemetry_stream": os.path.relpath(tel_path, here),
     }
     if record:
-        out = os.path.join(here, "bench_results", "serve_bench.json")
-        os.makedirs(os.path.dirname(out), exist_ok=True)
-        with open(out, "w") as f:
-            json.dump(result, f, indent=1)
-    print(json.dumps(result))
-    return result
-
-
-def serve_mixed_bench(record=True):
-    """Slot-vs-paged cache A/B under a mixed-length log-normal trace at
-    EQUAL HBM budget (``python bench.py --serve --mixed``).
-
-    The slot run gets ``SERVE_SLOT_BATCH`` cache rows (each pinned at
-    the full S_max depth); the paged run gets exactly that memory re-cut
-    into blocks (`MXNET_SERVE_N_BLOCKS = (slot_batch+1) * ceil(S/bs)`)
-    and a ``SERVE_PAGED_BATCH`` (default 4x) row ceiling — under
-    mixed-length traffic the same HBM admits several times the
-    concurrent batch, which is the whole point of paging.  Records both
-    runs side by side (occupancy, free-block low-water mark, leak check,
-    tok/s/chip) plus the speedup into bench_results/serve_bench.json —
-    the nightly paged gate reads exactly these fields.
-    """
-    from mxnet_tpu import telemetry
-
-    slot_b = int(os.environ.get("SERVE_SLOT_BATCH", "2"))
-    paged_b = int(os.environ.get("SERVE_PAGED_BATCH", str(4 * slot_b)))
-    seq = int(os.environ.get("SERVE_SEQ", "128"))
-    bs = int(os.environ.get("MXNET_SERVE_BLOCK_SIZE", "16"))
-    n_blocks = (slot_b + 1) * -(-seq // bs)
-    runs = {}
-    # the A/B premise is the mixed-length trace at offered load >>
-    # capacity — pinned for BOTH legs (and restored after: an in-process
-    # caller's later serve_bench must not inherit them)
-    shared = {"SERVE_TRACE": "mixed", "SERVE_RATE": "0"}
-    for mode, env in (
-            ("slot", {"MXNET_SERVE_PAGED": "0",
-                      "MXNET_SERVE_MAX_BATCH": str(slot_b)}),
-            ("paged", {"MXNET_SERVE_PAGED": "1",
-                       "MXNET_SERVE_MAX_BATCH": str(paged_b),
-                       "MXNET_SERVE_N_BLOCKS": str(n_blocks),
-                       "MXNET_SERVE_BLOCK_SIZE": str(bs)})):
-        env = dict(shared, **env)
-        old = {k: os.environ.get(k) for k in env}
-        os.environ.update(env)
-        telemetry.reset()  # fresh counters/sinks per leg
-        try:
-            runs[mode] = serve_bench(record=False)
-        finally:
-            for k, v in old.items():
-                if v is None:
-                    os.environ.pop(k, None)
-                else:
-                    os.environ[k] = v
-    slot, paged = runs["slot"], runs["paged"]
-    result = {
-        "metric": "serve_paged_vs_slot",
-        # the acceptance ratio: tok/s/chip at equal HBM budget
-        "value": round(paged["value"] / max(slot["value"], 1e-9), 3),
-        "unit": "paged/slot tok/s/chip ratio (equal HBM: %d slot rows "
-                "== %d blocks x %d)" % (slot_b + 1, n_blocks, bs),
-        "slot": slot,
-        "paged": paged,
-        "equal_hbm_token_rows": (slot_b + 1) * seq,
-        "concurrency_gain": round(
-            paged["max_concurrent"] / max(slot["max_concurrent"], 1), 3),
-        "occupancy": {"slot": slot["batch_occupancy"],
-                      "paged": paged["batch_occupancy"]},
-    }
-    if record:
-        here = os.path.dirname(os.path.abspath(__file__))
         out = os.path.join(here, "bench_results", "serve_bench.json")
         os.makedirs(os.path.dirname(out), exist_ok=True)
         with open(out, "w") as f:
@@ -1709,8 +1633,7 @@ def serve_disagg_bench(record=True):
     # the A/B premise: identical trace, identical chips — only the
     # fleet topology differs (and is restored after: an in-process
     # caller's later serve_bench must not inherit the split)
-    shared = {"SERVE_TRACE": "burst", "MXNET_SERVE_PAGED": "1",
-              "SERVE_REPLICAS": replicas}
+    shared = {"SERVE_TRACE": "burst", "SERVE_REPLICAS": replicas}
     for mode, env in (
             ("colocated", {"MXNET_SERVE_DISAGG": "0"}),
             ("disagg", {"MXNET_SERVE_DISAGG": "1",
@@ -1795,13 +1718,11 @@ def serve_sharded_bench(record=True):
     n_dev = len(jax.devices())
     k = max(2, min(int(os.environ.get("SERVE_SHARD_DEVICES", "2")), n_dev))
     runs = {}
-    shared = {"MXNET_SERVE_PAGED": "1"}
     for mode, env in (
             ("replicated", {"SERVE_REPLICAS": str(k),
                             "MXNET_SERVE_SHARDED_DEVICES": "1"}),
             ("sharded", {"SERVE_REPLICAS": "1",
                          "MXNET_SERVE_SHARDED_DEVICES": str(k)})):
-        env = dict(shared, **env)
         old = {kk: os.environ.get(kk) for kk in env}
         os.environ.update(env)
         telemetry.reset()  # fresh counters/sinks per leg
@@ -1876,8 +1797,7 @@ def serve_tracing_bench(record=True):
 
     here = os.path.dirname(os.path.abspath(__file__))
     replicas = os.environ.get("SERVE_REPLICAS", "2")
-    shared = {"SERVE_TRACE": "burst", "MXNET_SERVE_PAGED": "1",
-              "SERVE_REPLICAS": replicas,
+    shared = {"SERVE_TRACE": "burst", "SERVE_REPLICAS": replicas,
               "MXNET_SERVE_DISAGG": "1",
               "MXNET_SERVE_PREFILL_REPLICAS": os.environ.get(
                   "MXNET_SERVE_PREFILL_REPLICAS", "1")}
@@ -2399,9 +2319,7 @@ if __name__ == "__main__":
         overlap_bench()
     elif "--serve" in sys.argv:
         _serve_lint_preflight()
-        if "--mixed" in sys.argv:
-            serve_mixed_bench()
-        elif "--prefix" in sys.argv:
+        if "--prefix" in sys.argv:
             serve_prefix_bench()
         elif "--spec" in sys.argv:
             serve_spec_bench()

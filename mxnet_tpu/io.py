@@ -1003,8 +1003,14 @@ class ImageRecordIter(DataIter):
                             "failed to decode and were zero-filled (%s); "
                             "cumulative count in .decode_failures",
                             failed, _native.last_error())
-            out = (self._finish_hwc_u8(self._data_buf) if self._native_u8
-                   else self._finish(self._data_buf))
+            # the loader writes every batch into the one `_data_buf`, and
+            # JAX may read a host array it was handed after the call
+            # returns (it may even alias it): hand it a copy, or the next
+            # `next()` (a prefetch thread calls it back to back) rewrites
+            # this batch's images under this batch's labels
+            data = self._data_buf.copy()
+            out = (self._finish_hwc_u8(data) if self._native_u8
+                   else self._finish(data))
             return DataBatch(
                 data=[out],
                 label=[array(self._label_buf.copy())],
@@ -1081,7 +1087,7 @@ class ImageRecordIter(DataIter):
         The augmented batch stays a device array inside the NDArray — no
         host round-trip; it overlaps the train step under async dispatch."""
         if self._augmenter is None:
-            return array(data.copy() if data is not None else data)
+            return array(data)
         return NDArray(self._augmenter(data))
 
     def _finish_hwc_u8(self, data_u8):

@@ -206,7 +206,7 @@ def gather_paged_kv(pool, layer, which, block_tables):
                   unallocated tail entries point at the trash block (their
                   positions are > pos[r], so the decode mask hides them).
     Returns (b, m*block_size, embed), or (b, m*block_size) of scales: the
-    same layout `decode_attention` reads from a slot cache, reassembled by
+    contiguous (b, S, embed) layout `decode_attention` reads, reassembled by
     gather — paging changes WHERE rows live, not what attention sees.
     Multiply gathered scales onto the gathered int8 rows
     (``kc.astype(f32) * sc[..., None]``) to dequantize in-graph before the

@@ -6,8 +6,8 @@ package is the production path on top of it (ROADMAP item 1):
 
 * `decode.TransformerKVModel` — prefill + single-token KV-cache decode
   functions for `models/transformer.py` graphs (same parameter names, so
-  training checkpoints serve directly), over either a slot cache or the
-  paged block pool (`prefill_paged`/`decode_paged`).
+  training checkpoints serve directly), over the paged block pool
+  (`prefill_paged`/`decode_paged`).
 * `paged.BlockAllocator` — refcounted host-side free list over the
   fixed device block pool (the vLLM PagedAttention idea): sequences
   hold blocks for their actual length, so HBM admits by footprint, not

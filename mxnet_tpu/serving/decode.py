@@ -4,15 +4,16 @@ The training/Predictor path runs the full-sequence graph: every forward
 recomputes attention over all S positions.  Autoregressive serving wants
 two different programs:
 
-* **prefill** — one pass over the (padded) prompt that produces the
-  per-layer K/V projections *as outputs* so they can be written into a
-  persistent cache, plus the logits of the LAST real token (the first
-  sampling decision).  Attention itself runs through the same
-  `flash_attention` kernels as training, so prefill numerics match the
-  full-sequence forward exactly.
-* **decode** — one token per sequence per step: reads the K/V cache via
-  `ops.attention.decode_attention` (O(S) per token instead of the full
-  graph's O(S^2)) and scatter-writes the new K/V row in place.
+* **prefill** (`prefill_paged`) — one pass over a bucket-sized chunk of
+  the (padded) prompt that writes the chunk's per-layer K/V projections
+  into a persistent cache and returns the logits of the LAST real token
+  (the first sampling decision).  Attention runs `chunk_attention` over
+  the cached prefix and the chunk itself: the training causal mask once
+  the chunk starts at 0.
+* **decode** (`decode_paged`) — one token per sequence per step: reads
+  the K/V cache via `ops.attention.paged_decode_attention` (O(S) per
+  token instead of the full graph's O(S^2)) and scatter-writes the new
+  K/V row in place.
 
 Both are pure functions over a `{name: array}` parameter dict using the
 SAME names `get_transformer_lm` mints (embed_weight, pos_embed_weight,
@@ -21,13 +22,14 @@ _beta, final_ln_gamma/_beta, pred_weight/_bias), so a FeedForward
 checkpoint serves without conversion and the parity test
 (tests/test_serving.py) can bind one set of weights to both programs.
 
-Cache layout: ONE array of shape (num_layers, 2, n_slots, S_max, embed)
-(2 = K then V).  Keeping every layer in a single buffer lets the engine
-donate it through each prefill/decode call (in-place update, no per-step
-reallocation) and makes admit/retire a pure slot-index bookkeeping
-operation — no data moves when a sequence enters or leaves the batch.
-Sequences occupy a slot; per-row positions make the batch ragged-free:
-row b attends to cache[..., b, 0:pos[b]+1, :].
+Cache layout: ONE block pool of shape (num_layers, 2, n_blocks,
+block_size, embed) (2 = K then V; block 0 is the trash block,
+serving/paged.py).  Keeping every layer in a single buffer lets the
+engine donate it through each prefill/decode call (in-place update, no
+per-step reallocation), and a sequence reaches its rows through its
+(b, m) int32 block table, so admit/retire is host-side bookkeeping: no
+data moves when a sequence enters or leaves the batch.  Row b attends
+to positions 0..pos[b] of its own table.
 
 QUANTIZATION (docs/serving.md "Quantization", mxnet_tpu/quant):
 
@@ -65,7 +67,6 @@ from ..base import MXNetError
 from ..ops.attention import (gather_paged_kv, paged_decode_attention,
                              paged_decode_kernel_applies, decode_attention,
                              chunk_attention, verify_attention)
-from ..ops.pallas_kernels.flash_attention import flash_attention
 from ..ops.pallas_kernels.layer_norm import layer_norm
 from ..quant.codec import quantize, quantize_rows, resolve as quant_resolve
 
@@ -296,9 +297,8 @@ class TransformerKVModel:
 
     def kv_shardings(self, mesh, axis="model"):
         """(pool, scales) shardings for the sub-mesh replica's KV
-        buffers: the paged pool (L, 2, n_blocks, bs, E) and the slot
-        cache (L, 2, n_slots, S_max, E) split on the trailing embed
-        (head) axis — every shard holds ITS heads' K/V for ALL blocks,
+        buffers: the paged pool (L, 2, n_blocks, bs, E) splits on the
+        trailing embed (head) axis — every shard holds ITS heads' K/V for ALL blocks,
         so block tables, the allocator, the prefix cache and all
         host-side scheduling stay replica-global exactly as on one
         device — while the KV-quant scales (one f32 per token row, no
@@ -310,24 +310,6 @@ class TransformerKVModel:
         return (NamedSharding(mesh,
                               PartitionSpec(None, None, None, None, axis)),
                 repl)
-
-    def init_cache(self, n_slots, device=None):
-        """Zeroed K/V cache: (num_layers, 2, n_slots, S_max, embed).
-
-        ``device`` places the buffer on a specific device (the engine's
-        ctor AND its cache-rebuild recovery path: when a failed donating
-        launch consumes the buffer, a fresh one is allocated here without
-        touching the compiled executables — rebuild compiles nothing)."""
-        shape = (self.num_layers, 2, int(n_slots), self.seq_len,
-                 self.num_embed)
-        if isinstance(device, tuple):
-            # a sub-mesh engine passes its (pool, scales) sharding pair
-            # uniformly; the slot cache is one full-precision array and
-            # takes the pool half (same rank, embed axis last)
-            device = device[0]
-        if device is None:
-            return jnp.zeros(shape, self.dtype)
-        return jax.device_put(np.zeros(shape, self.dtype), device)
 
     # -- shared pieces -----------------------------------------------------
     def _proj(self, params, x, name):
@@ -413,96 +395,6 @@ class TransformerKVModel:
             x, params["final_ln_gamma"], params["final_ln_beta"], self.eps),
             "pred")
 
-    # -- prefill -----------------------------------------------------------
-    def prefill(self, params, tokens, length, moe_tape=None):
-        """Forward the (right-padded) prompt, returning the cache fill.
-
-        tokens: (b, s) int32, rows padded past ``length`` with any id.
-        length: (b,) int32 — number of real tokens per row (>= 1).
-        Returns (logits, kv):
-          logits (b, vocab) — logits of each row's LAST real token
-          kv (num_layers, 2, b, s, embed) — per-layer K/V projections for
-          cache rows 0..s (entries past ``length`` are don't-cares: decode
-          overwrites position ``length`` first and only ever attends
-          <= its own position).
-
-        The head matmul runs on ONE row per sequence, not all s positions
-        — at serving shapes the (vocab, embed) head is the largest matmul
-        in the graph and the prompt's other s-1 logit rows are never
-        sampled from.
-        """
-        b, s = tokens.shape
-        h, e = self.num_heads, self.num_embed
-        x = self._embed(params, tokens)
-        x = x + params["pos_embed_weight"][0, :s]
-        kv = []
-        for i in range(self.num_layers):
-            p = "layer%d_" % i
-            hn = layer_norm(x, params[p + "ln1_gamma"],
-                            params[p + "ln1_beta"], self.eps)
-            hf = hn.reshape(-1, e)
-            with jax.named_scope("qkv_proj"):
-                q = self._proj(params, hf, p + "q").reshape(b, s, e)
-                k = self._proj(params, hf, p + "k").reshape(b, s, e)
-                v = self._proj(params, hf, p + "v").reshape(b, s, e)
-            kv.append(jnp.stack([k, v]))
-            # (b, s, e) -> (b, h, s, hd): the training kernels' layout
-            def heads(t):
-                return t.reshape(b, s, h, e // h).transpose(0, 2, 1, 3)
-            attn = flash_attention(heads(q), heads(k), heads(v), causal=True)
-            attn = attn.transpose(0, 2, 1, 3).reshape(-1, e)
-            x = x + self._attn_out(params, attn, p).reshape(b, s, e)
-            hn = layer_norm(x, params[p + "ln2_gamma"],
-                            params[p + "ln2_beta"], self.eps)
-            x = x + self._ffn(params, hn.reshape(-1, e), p,
-                              tape=moe_tape).reshape(b, s, e)
-        last = jnp.take_along_axis(
-            x, (length.astype(jnp.int32) - 1)[:, None, None], axis=1
-        )[:, 0, :]  # (b, e)
-        return self._head(params, last), jnp.stack(kv)
-
-    # -- decode ------------------------------------------------------------
-    def decode(self, params, cache, token, pos, slots, moe_tape=None):
-        """One generation step for a bucket of sequences.
-
-        cache: (num_layers, 2, n_slots, S_max, embed) — donated by the
-               engine's compiled program; updated in place.
-        token: (b,) int32 — each row's current token (the one sampled last
-               step, or the prompt's last token right after prefill).
-        pos:   (b,) int32 — the position ``token`` occupies.
-        slots: (b,) int32 — which cache slot each row owns.  Padding rows
-               point at the engine's trash slot.
-        Returns (logits (b, vocab), new_cache).
-        """
-        e = self.num_embed
-        pos = pos.astype(jnp.int32)
-        slots = slots.astype(jnp.int32)
-        x = self._embed(params, token)
-        x = x + jnp.take(params["pos_embed_weight"][0], pos, axis=0)
-        for i in range(self.num_layers):
-            p = "layer%d_" % i
-            hn = layer_norm(x, params[p + "ln1_gamma"],
-                            params[p + "ln1_beta"], self.eps)
-            with jax.named_scope("qkv_proj"):
-                q = self._proj(params, hn, p + "q")
-                k = self._proj(params, hn, p + "k")
-                v = self._proj(params, hn, p + "v")
-            # scatter this step's K/V rows, then gather the bucket's slots.
-            # Duplicate indices only occur among padding rows (shared trash
-            # slot), whose values are never attended.
-            with jax.named_scope("kv_scatter"):
-                cache = cache.at[i, 0, slots, pos].set(k.astype(cache.dtype))
-                cache = cache.at[i, 1, slots, pos].set(v.astype(cache.dtype))
-            with jax.named_scope("kv_gather"):
-                kc = cache[i, 0, slots]  # (b, S_max, e)
-                vc = cache[i, 1, slots]
-            attn = decode_attention(q, kc, vc, pos, self.num_heads)
-            x = x + self._attn_out(params, attn, p)
-            hn = layer_norm(x, params[p + "ln2_gamma"],
-                            params[p + "ln2_beta"], self.eps)
-            x = x + self._ffn(params, hn, p, tape=moe_tape)
-        return self._head(params, x), cache
-
     # -- paged cache -------------------------------------------------------
     @staticmethod
     def cache_lost(cache):
@@ -572,8 +464,12 @@ class TransformerKVModel:
         embed) — under KV quantization the (pool, scales) PAIR, with the
         pool in the quantized dtype and per-row f32 scales
         (num_layers, 2, n_blocks, block_size).  Block 0 is the trash
-        block (serving/paged.py); like `init_cache` this is also the
-        pool-rebuild recovery allocation."""
+        block (serving/paged.py).
+
+        ``device`` places the buffers (the engine's ctor AND its
+        cache-rebuild recovery path: when a failed donating launch
+        consumes the pool, a fresh one is allocated here without
+        touching the compiled executables — rebuild compiles nothing)."""
         shape = (self.num_layers, 2, int(n_blocks), int(block_size),
                  self.num_embed)
         # a sub-mesh engine passes ``device`` as the (pool, scales)
@@ -735,8 +631,7 @@ class TransformerKVModel:
 
     def decode_paged(self, params, pool, token, pos, tables,
                      moe_tape=None):
-        """One generation step over the paged pool (the block-table
-        counterpart of `decode`).
+        """One generation step over the paged pool.
 
         pool:   (num_layers, 2, n_blocks, block_size, embed), donated.
         token:  (b,) int32 — each row's current token.
@@ -937,13 +832,3 @@ class TransformerKVModel:
         logits = self._head(params, x.reshape(-1, e)).reshape(
             b, c, self.vocab_size)
         return logits, self._pack_pool(pool, scales)
-
-    @jax.named_scope("kv_scatter")
-    def write_prefill(self, cache, kv, length, slots):
-        """Scatter a prefill's (num_layers, 2, b, s, embed) K/V block into
-        the cache at ``slots`` (rows 0..s-1; s <= S_max).  ``length`` is
-        unused for masking (decode never attends past its own position)
-        but kept in the signature so a future packed layout can trim."""
-        s = kv.shape[3]
-        return cache.at[:, :, slots.astype(jnp.int32), :s].set(
-            kv.astype(cache.dtype))

@@ -12,7 +12,7 @@ AOT-compiled at `warmup()` for a small FIXED set of shapes —
 * prefill buckets: (1, s) for s in ``MXNET_SERVE_PREFILL_BUCKETS``
   (prompts right-pad up to the smallest bucket that fits), and
 * decode buckets: (b, 1) for b in ``MXNET_SERVE_BUCKETS`` (the active
-  set pads up to the smallest bucket with rows pointed at a trash slot).
+  set pads up to the smallest bucket with rows pointed at the trash block).
 
 Executables live in an `executor.AotCache` (`serve.aot.hits/compiles`
 counters) and every launch feeds the PR-2 retrace watchdog
@@ -20,19 +20,16 @@ counters) and every launch feeds the PR-2 retrace watchdog
 "no recompiles after warmup" is an asserted property
 (tests/test_serving.py), not a hope.
 
-The K/V cache is PAGED by default (``MXNET_SERVE_PAGED=0`` restores the
-slot cache bit-for-bit): a fixed block pool
+The K/V cache is PAGED: a fixed block pool
 (L, 2, n_blocks, block_size, E) DONATED through each compiled call, with
 per-row int32 block tables and a host-side free-list allocator
 (serving/paged.py).  Admission is free-block accounting — a sequence
-holds blocks for its ACTUAL length, so at equal HBM mixed-length traffic
-admits a strictly larger concurrent batch than the slot cache's
-worst-case rows.  Growth is one block at a time; a denied growth
-preempts (blocks freed, request requeued with its generated tokens —
-deterministic replay makes preemption invisible in the output).  Prompts
-longer than the largest prefill bucket stream through the pool in
-bucket-sized CHUNKS (one per iteration once decoding — the Sarathi
-ttft-interference bound), so the out-of-range rejection path is gone.
+holds blocks for its ACTUAL length, not for the cache depth.  Growth is
+one block at a time; a denied growth preempts (blocks freed, request
+requeued with its generated tokens — deterministic replay makes
+preemption invisible in the output).  Prompts longer than the largest
+prefill bucket stream through the pool in bucket-sized CHUNKS (one per
+iteration once decoding — the Sarathi ttft-interference bound).
 
 Paged blocks are SHAREABLE across requests (``MXNET_SERVE_PREFIX=0``
 restores single-owner paging bit-for-bit): the allocator refcounts every
@@ -386,9 +383,9 @@ class ServeRequest:
 class _Seq:
     """Scheduler state of one active sequence: `last` is the token that
     will be fed (and cached) at position `pos` on the next decode step.
-    ``blocks`` is the paged path's host-side block list (None on the
-    slot path): entry t holds cache positions [t*bs, (t+1)*bs).
-    ``ctx`` (paged only) is the incrementally maintained list of the
+    ``blocks`` is the host-side block list: entry t holds cache
+    positions [t*bs, (t+1)*bs).
+    ``ctx`` is the incrementally maintained list of the
     tokens cached at rows [0, pos) — prefix registration and preemption
     resume read it directly instead of re-concatenating prompt +
     generated every time (which would be quadratic over a long
@@ -396,7 +393,7 @@ class _Seq:
 
     __slots__ = ("req", "last", "pos", "n_new", "blocks", "ctx")
 
-    def __init__(self, req, last, pos, blocks=None, ctx=None):
+    def __init__(self, req, last, pos, blocks, ctx):
         self.req = req
         self.last = last
         self.pos = pos
@@ -406,7 +403,7 @@ class _Seq:
 
 
 class _Prefill:
-    """A paged-path admission mid-stream: ``tokens`` is everything the
+    """An admission mid-stream: ``tokens`` is everything the
     cache must hold before decode starts (the prompt — or, after a
     preemption, prompt + already-generated tokens), ``done`` how many of
     them are cached so far.  One bucket-sized chunk advances per
@@ -510,8 +507,7 @@ class ServingEngine:
                  decode_buckets=None, prefill_buckets=None,
                  max_new_tokens=None, eos_id=None, name="replica0",
                  queue_max=None, overload=None, deadline_ms=None, aot=None,
-                 paged=None, block_size=None, n_blocks=None,
-                 chunk_prefill=None, sampling=None, prefix=None,
+                 block_size=None, n_blocks=None, sampling=None, prefix=None,
                  prefix_pool=None, spec=None, spec_k=None,
                  spec_drafter=None, min_progress=None, thrash_trip=None,
                  tier=None, host_blocks=None, restore_ahead=None,
@@ -597,11 +593,8 @@ class ServingEngine:
         self._launch_retries = max(1, int(os.environ.get(
             "MXNET_SERVE_LAUNCH_RETRIES", "3")))
 
-        # paged K/V cache (MXNET_SERVE_PAGED=0 kill-switch restores the
-        # slot cache bit-for-bit); sampling programs (MXNET_SERVE_SAMPLING
-        # =0 restores the PR-7 greedy-only program signatures)
-        self._paged = _env_flag("MXNET_SERVE_PAGED") if paged is None \
-            else bool(paged)
+        # sampling programs (MXNET_SERVE_SAMPLING=0 restores the PR-7
+        # greedy-only program signatures)
         self._sampling = _env_flag("MXNET_SERVE_SAMPLING") \
             if sampling is None else bool(sampling)
         # post-training quantization (docs/serving.md "Quantization"):
@@ -616,19 +609,9 @@ class ServingEngine:
         kvq = os.environ.get("MXNET_SERVE_KV_QUANT", "") \
             if kv_quant is None else kv_quant
         if kvq in ("", None):
-            # implicit default: int8 KV rides along with weight quant —
-            # but only where it can (paged); a slot-cache engine keeps
-            # weight-only quantization instead of failing over a
-            # variable the user never set
-            kvq = "int8" if (self._quant is not None
-                             and self._paged) else "0"
+            # implicit default: int8 KV rides along with weight quant
+            kvq = "int8" if self._quant is not None else "0"
         self._kv_quant = quant_resolve(kvq)
-        if self._kv_quant is not None and not self._paged:
-            raise MXNetError(
-                "ServingEngine: quantized KV blocks need the paged cache "
-                "(MXNET_SERVE_KV_QUANT set with MXNET_SERVE_PAGED=0)")
-        if not self._paged:
-            self._refuse("slot_cache", "paged=False / MXNET_SERVE_PAGED=0")
         if self._quant is not None:
             self._refuse("quant", "quant / MXNET_SERVE_QUANT")
         if self._kv_quant is not None:
@@ -677,100 +660,80 @@ class ServingEngine:
         # `expert_load()` drains from the caller's thread while the
         # scheduler drains after every launch: a count is folded once
         self._moe_lock = threading.Lock()
-        if self._paged:
-            self._chunk_prefill = _env_flag("MXNET_SERVE_CHUNK_PREFILL") \
-                if chunk_prefill is None else bool(chunk_prefill)
-            bs = int(os.environ.get("MXNET_SERVE_BLOCK_SIZE", "0")
-                     if block_size is None else block_size)
-            if bs < 0:
-                raise MXNetError("ServingEngine: block_size must be >= 1")
-            if bs == 0:
-                # auto: the largest divisor of EVERY prefill bucket, capped
-                # at 16 (the vLLM-ish default) — default buckets end at
-                # seq_len itself, so e.g. seq_len=100 resolves to 4, not a
-                # constructor error
-                import math
-                g = 0
-                for s in self.prefill_buckets:
-                    g = math.gcd(g, s)
-                bs = max(d for d in range(1, min(16, g) + 1) if g % d == 0)
-            bad = [s for s in self.prefill_buckets if s % bs]
-            if bad:
-                raise MXNetError(
-                    "ServingEngine: block_size %d must divide every "
-                    "prefill bucket (violated by %s) — chunk starts and "
-                    "prefill scatters are block-aligned" % (bs, bad))
-            self.block_size = bs
-            # table width: enough entries to cover the full cache depth
-            self._n_table = -(-model.seq_len // bs)
-            nb = int(os.environ.get("MXNET_SERVE_N_BLOCKS", "0")
-                     if n_blocks is None else n_blocks)
-            if nb == 0:
-                # default = the slot cache's exact HBM budget: the
-                # (max_batch + 1 trash) rows it would have pinned,
-                # re-cut into blocks (+ the trash block)
-                nb = (self.max_batch + 1) * self._n_table
-            self.n_blocks = nb
-            self._alloc = BlockAllocator(nb, bs)
-            self._cache = model.init_block_pool(nb, bs,
-                                                device=self._kv_device())
-            # whether the decode programs attend with the paged Pallas
-            # kernel: decided here, once, as their traces will (the pool,
-            # the backend, this replica's mesh), for the `iteration`
-            # record's `attn_kernel`
-            self._attn_kernel = int(self._scoped(
-                lambda: model.paged_decode_kernel(self._cache),
-                "attn_kernel")())
-            self._prefilling = {}  # row -> _Prefill (insertion-ordered)
-            # cross-request prefix sharing (MXNET_SERVE_PREFIX=0 restores
-            # single-owner paging bit-for-bit; MXNET_SERVE_PREFIX_POOL
-            # caps the parked refcount-0 LRU pool, < 0 = bounded only by
-            # allocation pressure)
-            self._prefix_pool = int(
-                os.environ.get("MXNET_SERVE_PREFIX_POOL", "-1")
-                if prefix_pool is None else prefix_pool)
-            prefix_on = _env_flag("MXNET_SERVE_PREFIX") if prefix is None \
-                else bool(prefix)
-            # host-DRAM block tier (MXNET_SERVE_TIER, default OFF: =0 is
-            # the PR-12 evict-and-recompute behavior bit-for-bit).  The
-            # tier rides the prefix index — without it there is nothing
-            # to spill — so prefix off forces tier off.
-            tier_on = (_env_flag("MXNET_SERVE_TIER", "0") if tier is None
-                       else bool(tier)) and prefix_on
-            self._host_blocks = int(
-                os.environ.get("MXNET_SERVE_HOST_BLOCKS", "256")
-                if host_blocks is None else host_blocks)
-            self._restore_ahead = int(
-                os.environ.get("MXNET_SERVE_RESTORE_AHEAD", "2")
-                if restore_ahead is None else restore_ahead)
-            if tier_on:
-                self._refuse("tier", "tier / MXNET_SERVE_TIER")
-            self._tier = HostBlockTier(self._host_blocks) \
-                if tier_on and self._host_blocks > 0 else None
-            self._prefix = PrefixCache(
-                bs, self._prefix_pool,
-                spill_hook=self._spill_block if self._tier is not None
-                else None,
-                host_drop_hook=self._host_dropped if self._tier is not None
-                else None) if prefix_on else None
-            self._restoring = {}   # row -> _Restore (insertion-ordered)
-            self._landing = {}     # row -> HandoffLanding (disagg)
-        else:
-            self._chunk_prefill = False
-            self.block_size = None
-            self.n_blocks = None
-            self._alloc = None
-            self._prefix = None
-            self._prefix_pool = -1
-            self._tier = None
-            self._host_blocks = 0
-            self._restore_ahead = 0
-            self._restoring = {}
-            self._landing = {}
-            # slot max_batch is the trash slot padding rows write into
-            self._cache = model.init_cache(self.max_batch + 1,
-                                           device=self._kv_device())
-            self._prefilling = {}
+        bs = int(os.environ.get("MXNET_SERVE_BLOCK_SIZE", "0")
+                 if block_size is None else block_size)
+        if bs < 0:
+            raise MXNetError("ServingEngine: block_size must be >= 1")
+        if bs == 0:
+            # auto: the largest divisor of EVERY prefill bucket, capped
+            # at 16 (the vLLM-ish default) — default buckets end at
+            # seq_len itself, so e.g. seq_len=100 resolves to 4, not a
+            # constructor error
+            import math
+            g = 0
+            for s in self.prefill_buckets:
+                g = math.gcd(g, s)
+            bs = max(d for d in range(1, min(16, g) + 1) if g % d == 0)
+        bad = [s for s in self.prefill_buckets if s % bs]
+        if bad:
+            raise MXNetError(
+                "ServingEngine: block_size %d must divide every "
+                "prefill bucket (violated by %s) — chunk starts and "
+                "prefill scatters are block-aligned" % (bs, bad))
+        self.block_size = bs
+        # table width: enough entries to cover the full cache depth
+        self._n_table = -(-model.seq_len // bs)
+        nb = int(os.environ.get("MXNET_SERVE_N_BLOCKS", "0")
+                 if n_blocks is None else n_blocks)
+        if nb == 0:
+            # default: max_batch rows at the full cache depth, and one
+            # row's worth more that holds the trash block
+            nb = (self.max_batch + 1) * self._n_table
+        self.n_blocks = nb
+        self._alloc = BlockAllocator(nb, bs)
+        self._cache = model.init_block_pool(nb, bs,
+                                            device=self._kv_device())
+        # whether the decode programs attend with the paged Pallas
+        # kernel: decided here, once, as their traces will (the pool,
+        # the backend, this replica's mesh), for the `iteration`
+        # record's `attn_kernel`
+        self._attn_kernel = int(self._scoped(
+            lambda: model.paged_decode_kernel(self._cache),
+            "attn_kernel")())
+        self._prefilling = {}  # row -> _Prefill (insertion-ordered)
+        # cross-request prefix sharing (MXNET_SERVE_PREFIX=0 restores
+        # single-owner paging bit-for-bit; MXNET_SERVE_PREFIX_POOL
+        # caps the parked refcount-0 LRU pool, < 0 = bounded only by
+        # allocation pressure)
+        self._prefix_pool = int(
+            os.environ.get("MXNET_SERVE_PREFIX_POOL", "-1")
+            if prefix_pool is None else prefix_pool)
+        prefix_on = _env_flag("MXNET_SERVE_PREFIX") if prefix is None \
+            else bool(prefix)
+        # host-DRAM block tier (MXNET_SERVE_TIER, default OFF: =0 is
+        # the PR-12 evict-and-recompute behavior bit-for-bit).  The
+        # tier rides the prefix index — without it there is nothing
+        # to spill — so prefix off forces tier off.
+        tier_on = (_env_flag("MXNET_SERVE_TIER", "0") if tier is None
+                   else bool(tier)) and prefix_on
+        self._host_blocks = int(
+            os.environ.get("MXNET_SERVE_HOST_BLOCKS", "256")
+            if host_blocks is None else host_blocks)
+        self._restore_ahead = int(
+            os.environ.get("MXNET_SERVE_RESTORE_AHEAD", "2")
+            if restore_ahead is None else restore_ahead)
+        if tier_on:
+            self._refuse("tier", "tier / MXNET_SERVE_TIER")
+        self._tier = HostBlockTier(self._host_blocks) \
+            if tier_on and self._host_blocks > 0 else None
+        self._prefix = PrefixCache(
+            bs, self._prefix_pool,
+            spill_hook=self._spill_block if self._tier is not None
+            else None,
+            host_drop_hook=self._host_dropped if self._tier is not None
+            else None) if prefix_on else None
+        self._restoring = {}   # row -> _Restore (insertion-ordered)
+        self._landing = {}     # row -> HandoffLanding (disagg)
         # speculative decoding (MXNET_SERVE_SPEC, default off: the
         # PR-10 single-token decode path is bit-for-bit untouched at 0)
         self._spec = _env_flag("MXNET_SERVE_SPEC", "0") if spec is None \
@@ -781,10 +744,6 @@ class ServingEngine:
         self._drafter = None
         if self._spec:
             self._refuse("spec", "spec / MXNET_SERVE_SPEC")
-            if not self._paged:
-                raise MXNetError(
-                    "ServingEngine: speculative decoding needs the paged "
-                    "cache (MXNET_SERVE_SPEC=1 with MXNET_SERVE_PAGED=0)")
             if self._spec_k < 1:
                 raise MXNetError("ServingEngine: MXNET_SERVE_SPEC_K must "
                                  "be >= 1, got %d" % self._spec_k)
@@ -803,12 +762,6 @@ class ServingEngine:
         self._mega_m = 0
         if mega_on:
             self._refuse("megastep", "megastep / MXNET_SERVE_MEGASTEP")
-            if not self._paged:
-                raise MXNetError(
-                    "ServingEngine: megastep decode needs the paged cache "
-                    "(MXNET_SERVE_MEGASTEP=1 with MXNET_SERVE_PAGED=0) — "
-                    "in-graph retirement parks dead rows on the trash "
-                    "block, which only the paged path has")
             self._mega_m = int(
                 os.environ.get("MXNET_SERVE_MEGASTEP_STEPS", "4")
                 if megastep_steps is None else megastep_steps)
@@ -831,7 +784,7 @@ class ServingEngine:
         self._qcond = threading.Condition(self._qlock)
         self._admitting = 0       # popped off _queue, prefill in flight
         self._iter = {}           # the iteration's counts, anew each step()
-        self._active = {}         # slot -> _Seq (insertion-ordered)
+        self._active = {}         # row -> _Seq (insertion-ordered)
         self._free = list(range(self.max_batch))
         self._stopped = threading.Event()
         self._draining = False    # drain(): admission closed, queue serves out
@@ -886,8 +839,7 @@ class ServingEngine:
                       "decode_padded": 0, "prefills": 0, "completed": 0,
                       "tokens": 0, "prefill_chunks": 0, "preemptions": 0,
                       "alloc_denied": 0, "max_concurrent": 0,
-                      "blocks_free_min": (self._alloc.free_blocks
-                                          if self._paged else None),
+                      "blocks_free_min": self._alloc.free_blocks,
                       # prefix caching (0s when disabled)
                       "prefix_hits": 0, "prefix_tokens": 0,
                       "prefix_lookup_tokens": 0, "prefix_bootstraps": 0,
@@ -974,93 +926,49 @@ class ServingEngine:
                                   newpos))
 
     def _compiled_prefill(self, s_bucket):
-        if self._paged:
-            def build():
-                def prog(params, pool, tokens, start, length, tables,
-                         *samp):
-                    tape = []
-                    logits, pool = self.model.prefill_paged(
-                        params, pool, tokens, start, length, tables,
-                        moe_tape=tape)
-                    return (self._pick(logits, samp, start + length),
-                            pool) + self._moe_out(tape)
-
-                fn = self._jit(prog, (1,), ("repl", "cache")
-                               + ("repl",) * self._moe,
-                               "serve_prefill_s%d" % s_bucket)
-                toks = self._put(np.zeros((1, s_bucket), np.int32))
-                zero = self._put(np.zeros((1,), np.int32))
-                one = self._put(np.ones((1,), np.int32))
-                tables = self._put(np.zeros((1, self._n_table), np.int32))
-                samp = tuple(self._put(a)
-                             for a in self._sample_placeholders(1))
-                return fn.lower(self._params, self._cache, toks, zero,
-                                one, tables, *samp).compile()
-
-            return self._aot.get(("prefill_paged", 1, s_bucket), build)
-
         def build():
-            def prog(params, cache, tokens, length, slot, *samp):
+            def prog(params, pool, tokens, start, length, tables, *samp):
                 tape = []
-                logits, kv = self.model.prefill(params, tokens, length,
-                                                moe_tape=tape)
-                cache = self.model.write_prefill(cache, kv, length, slot)
-                return (self._pick(logits, samp, length),
-                        cache) + self._moe_out(tape)
+                logits, pool = self.model.prefill_paged(
+                    params, pool, tokens, start, length, tables,
+                    moe_tape=tape)
+                return (self._pick(logits, samp, start + length),
+                        pool) + self._moe_out(tape)
 
             fn = self._jit(prog, (1,), ("repl", "cache")
                            + ("repl",) * self._moe,
                            "serve_prefill_s%d" % s_bucket)
             toks = self._put(np.zeros((1, s_bucket), np.int32))
+            zero = self._put(np.zeros((1,), np.int32))
             one = self._put(np.ones((1,), np.int32))
+            tables = self._put(np.zeros((1, self._n_table), np.int32))
             samp = tuple(self._put(a) for a in self._sample_placeholders(1))
-            return fn.lower(self._params, self._cache, toks, one,
-                            one, *samp).compile()
+            return fn.lower(self._params, self._cache, toks, zero,
+                            one, tables, *samp).compile()
 
-        return self._aot.get(("prefill", 1, s_bucket), build)
+        return self._aot.get(("prefill_paged", 1, s_bucket), build)
 
     def _compiled_decode(self, b_bucket):
-        if self._paged:
-            def build():
-                def prog(params, pool, token, pos, tables, *samp):
-                    tape = []
-                    logits, pool = self.model.decode_paged(
-                        params, pool, token, pos, tables, moe_tape=tape)
-                    return (self._pick(logits, samp, pos + 1),
-                            pool) + self._moe_out(tape)
-
-                fn = self._jit(prog, (1,), ("repl", "cache")
-                               + ("repl",) * self._moe,
-                               "serve_decode_b%d" % b_bucket)
-                z = self._put(np.zeros((b_bucket,), np.int32))
-                tables = self._put(np.zeros((b_bucket, self._n_table),
-                                            np.int32))
-                samp = tuple(self._put(a)
-                             for a in self._sample_placeholders(b_bucket))
-                return fn.lower(self._params, self._cache, z, z, tables,
-                                *samp).compile()
-
-            return self._aot.get(("decode_paged", b_bucket, 1), build)
-
         def build():
-            def prog(params, cache, token, pos, slots, *samp):
+            def prog(params, pool, token, pos, tables, *samp):
                 tape = []
-                logits, cache = self.model.decode(params, cache, token,
-                                                  pos, slots,
-                                                  moe_tape=tape)
+                logits, pool = self.model.decode_paged(
+                    params, pool, token, pos, tables, moe_tape=tape)
                 return (self._pick(logits, samp, pos + 1),
-                        cache) + self._moe_out(tape)
+                        pool) + self._moe_out(tape)
 
             fn = self._jit(prog, (1,), ("repl", "cache")
                            + ("repl",) * self._moe,
                            "serve_decode_b%d" % b_bucket)
             z = self._put(np.zeros((b_bucket,), np.int32))
+            tables = self._put(np.zeros((b_bucket, self._n_table),
+                                        np.int32))
             samp = tuple(self._put(a)
                          for a in self._sample_placeholders(b_bucket))
-            return fn.lower(self._params, self._cache, z, z, z,
+            return fn.lower(self._params, self._cache, z, z, tables,
                             *samp).compile()
 
-        return self._aot.get(("decode", b_bucket, 1), build)
+        return self._aot.get(("decode_paged", b_bucket, 1), build)
 
     def _compiled_mega(self, b_bucket):
         """The m-step fused decode megastep (docs/serving.md "Megastep
@@ -1255,16 +1163,16 @@ class ServingEngine:
 
     def _kv_device(self):
         """Placement for the K/V buffers: the (pool, scales) sharding
-        pair on a sub-mesh replica — `init_block_pool`/`init_cache`
-        split it — the plain device otherwise."""
+        pair on a sub-mesh replica — `init_block_pool` splits it — the
+        plain device otherwise."""
         return self._device if self._mesh is None else self._kv_shard
 
     def _cache_sharding(self):
         """The sharding pytree of `self._cache` as the compiled
         programs see it (mesh mode only): the (pool, scales) pair under
-        KV quant, the single pool/slot-cache sharding otherwise."""
+        KV quant, the single pool sharding otherwise."""
         psh, ssh = self._kv_shard
-        if self._paged and self.model.kv_quant is not None:
+        if self.model.kv_quant is not None:
             return (psh, ssh)
         return psh
 
@@ -1402,24 +1310,17 @@ class ServingEngine:
         toks = np.zeros((1, s), np.int32)
         one = np.ones((1,), np.int32)
         samp = self._sample_placeholders(1)
-        if self._paged:
-            tables = np.zeros((1, self._n_table), np.int32)
-            return ((toks, one, one, tables) + samp,
-                    ("tokens", "start", "length", "tables")
-                    + self._SAMPLE_NAMES[:len(samp)])
-        return ((toks, one, one) + samp,
-                ("tokens", "length", "slot") + self._SAMPLE_NAMES[:len(samp)])
+        tables = np.zeros((1, self._n_table), np.int32)
+        return ((toks, one, one, tables) + samp,
+                ("tokens", "start", "length", "tables")
+                + self._SAMPLE_NAMES[:len(samp)])
 
     def _decode_watch_arrays(self, b):
         z = np.zeros((b,), np.int32)
         samp = self._sample_placeholders(b)
-        if self._paged:
-            tables = np.zeros((b, self._n_table), np.int32)
-            return ((z, z, tables) + samp,
-                    ("token", "pos", "tables")
-                    + self._SAMPLE_NAMES[:len(samp)])
-        return ((z, z, z) + samp,
-                ("token", "pos", "slots") + self._SAMPLE_NAMES[:len(samp)])
+        tables = np.zeros((b, self._n_table), np.int32)
+        return ((z, z, tables) + samp,
+                ("token", "pos", "tables") + self._SAMPLE_NAMES[:len(samp)])
 
     def _mega_watch_arrays(self, b):
         z = np.zeros((b,), np.int32)
@@ -1476,8 +1377,7 @@ class ServingEngine:
             self._compiled_cow()
             arrays, names = self._cow_watch_arrays()
             self._watch("cow", arrays, names, 1, seed=True)
-        if self._tier is not None or (self._paged
-                                      and self.role == "decode"):
+        if self._tier is not None or self.role == "decode":
             # the restore writes join the frozen set too: a host hit in
             # steady state compiles nothing, it only transfers.  A
             # decode-role replica needs the same bucketed scatters for
@@ -1490,7 +1390,7 @@ class ServingEngine:
         self._aot.freeze()
         return {"prefill": list(self.prefill_buckets),
                 "decode": list(self.decode_buckets),
-                "cache": "paged" if self._paged else "slot",
+                "cache": "paged",
                 "block_size": self.block_size, "n_blocks": self.n_blocks,
                 "prefix": self._prefix is not None,
                 "tier": None if self._tier is None else
@@ -1511,7 +1411,7 @@ class ServingEngine:
         geometry, name, and admission config; params SHARED (already on
         the device, no host round-trip); the compiled AOT set SHARED, so
         the replacement's `warmup()` re-seeds the watchdog but compiles
-        nothing new; fresh K/V cache and slot state.  ``name`` overrides
+        nothing new; fresh K/V cache and row state.  ``name`` overrides
         the replica name — the autoscaler's scale-up templates a NEW
         replica off a live one, which must not collide with it in the
         per-replica gauges or the chaos step counters."""
@@ -1526,8 +1426,7 @@ class ServingEngine:
             queue_max=self._queue_max,
             overload=self._overload,
             deadline_ms=self._deadline_ms_default, aot=self._aot,
-            paged=self._paged, block_size=self.block_size,
-            n_blocks=self.n_blocks, chunk_prefill=self._chunk_prefill,
+            block_size=self.block_size, n_blocks=self.n_blocks,
             sampling=self._sampling, prefix=self._prefix is not None,
             prefix_pool=self._prefix_pool, spec=self._spec,
             spec_k=self._spec_k,
@@ -1672,35 +1571,26 @@ class ServingEngine:
                            temperature=temperature, top_k=top_k,
                            top_p=top_p, seed=seed, session=session)
         req._on_token = on_token
-        if not (self._paged and self._chunk_prefill) and \
-                len(req.prompt) > self.prefill_buckets[-1]:
-            # chunked prefill streams any prompt through bucket-sized
-            # chunks; without it the largest bucket is the hard ceiling
-            raise MXNetError(
-                "ServingEngine: prompt length %d exceeds the largest "
-                "prefill bucket %d" % (len(req.prompt),
-                                       self.prefill_buckets[-1]))
         if len(req.prompt) >= self.model.seq_len:
             raise MXNetError(
                 "ServingEngine: prompt length %d leaves no room to "
                 "generate (seq_len %d)" % (len(req.prompt),
                                            self.model.seq_len))
-        if self._paged:
-            # a request whose WORST-CASE footprint exceeds the whole pool
-            # can only ever end in a preemption livelock — reject typed
-            # at the door (transient pressure is not this: it queues,
-            # retries, or preempts+requeues instead)
-            worst = min(len(req.prompt) + req.max_new_tokens,
-                        self.model.seq_len)
-            need = self._alloc.blocks_for(worst)
-            if need > self._alloc.capacity:
-                telemetry.inc("serve.blocks_rejected")
-                raise ServeBlocksExhausted(
-                    "ServingEngine %s: request needs up to %d cache "
-                    "blocks but the pool only has %d usable "
-                    "(n_blocks=%d, block_size=%d)"
-                    % (self.name, need, self._alloc.capacity,
-                       self.n_blocks, self.block_size))
+        # a request whose WORST-CASE footprint exceeds the whole pool
+        # can only ever end in a preemption livelock — reject typed
+        # at the door (transient pressure is not this: it queues,
+        # retries, or preempts+requeues instead)
+        worst = min(len(req.prompt) + req.max_new_tokens,
+                    self.model.seq_len)
+        need = self._alloc.blocks_for(worst)
+        if need > self._alloc.capacity:
+            telemetry.inc("serve.blocks_rejected")
+            raise ServeBlocksExhausted(
+                "ServingEngine %s: request needs up to %d cache "
+                "blocks but the pool only has %d usable "
+                "(n_blocks=%d, block_size=%d)"
+                % (self.name, need, self._alloc.capacity,
+                   self.n_blocks, self.block_size))
         telemetry.inc("serve.sampled_requests" if req.temperature > 0
                       else "serve.greedy_requests")
         if self._queue_max > 0 and self._overload == "block":
@@ -1831,7 +1721,7 @@ class ServingEngine:
         request and its prefill landing in `_active` (or finishing) —
         without it a thread-driven `run_until_idle` could read depth 0
         and declare idle while a prefill is in flight.  `_prefilling`
-        (paged chunked prefills mid-stream) and `_restoring` (host-tier
+        (chunked prefills mid-stream) and `_restoring` (host-tier
         restores staged but not landed) count the same way."""
         with self._qlock:
             return len(self._queue) + self._admitting + \
@@ -1914,18 +1804,17 @@ class ServingEngine:
             self._alloc.reclaim(freed)
             self._count_evictions(len(freed))
 
-    def _quant_trip_req(self, req, where, requeue=True):
+    def _quant_trip_req(self, req, where):
         """A quantization logit gate tripped for ``req`` (the compiled
         program emitted the -1 sentinel): count, then requeue ONCE for
-        a clean retry — the second trip (or a path with no exact-replay
-        road, e.g. a mid-generation slot-cache row) quarantines typed
+        a clean retry — the second trip quarantines typed
         `ServeQuantError`.  The one outcome this path can never have is
         a silently emitted wrong token."""
         self.stats["quant_trips"] += 1
         self._count("quant.trips")
         telemetry.record_event("serve_quant_trip", replica=self.name,
                                request=req.id, where=where)
-        if requeue and req._requeues < 1:
+        if req._requeues < 1:
             req._requeues += 1
             with self._qlock:
                 self._queue.appendleft(req)
@@ -1961,15 +1850,11 @@ class ServingEngine:
         row's blocks from the prefix index, release them exactly once,
         and requeue with the exact-replay resume (tokens already
         emitted passed the gate — the replay continues after them with
-        freshly quantized context).  Slot-cache rows have no replay
-        road, so they quarantine directly."""
-        replayable = self._paged and seq.blocks is not None
-        if replayable:
-            self._scrub_quant(seq.blocks)
+        freshly quantized context)."""
+        self._scrub_quant(seq.blocks)
         req = self._vacate_row(row, seq,
-                               capture_resume=replayable
-                               and seq.req._requeues < 1)
-        self._quant_trip_req(req, where, requeue=replayable)
+                               capture_resume=seq.req._requeues < 1)
+        self._quant_trip_req(req, where)
 
     def _release_blocks(self, holder):
         """Drop a seq/prefill's block refs exactly once (every path a
@@ -1978,7 +1863,7 @@ class ServingEngine:
         of freeing — hot prefixes survive the request — everything else
         returns to the free list.  The leak check is `leaked_blocks()`
         returning 0 after a drain."""
-        if self._paged and holder.blocks is not None:
+        if holder.blocks is not None:
             self._drop_refs(holder.blocks)
             holder.blocks = None
             self._block_gauges()
@@ -2020,8 +1905,6 @@ class ServingEngine:
     def leaked_blocks(self):
         """Blocks neither free, nor held by a live sequence, nor parked
         in the prefix pool — must be 0 after any drain."""
-        if not self._paged:
-            return 0
         parked = 0 if self._prefix is None else self._prefix.parked_count
         return self._alloc.capacity - self._alloc.free_blocks - \
             self._alloc.used_blocks - parked
@@ -2123,11 +2006,8 @@ class ServingEngine:
         scheduler iteration — it walks every held block, which is not
         free at large batch x depth), which then returns (blocks held by
         a sequence, blocks the prefix cache parks alone)."""
-        if not self._paged:
-            return
         free = self._alloc.free_blocks
-        if self.stats["blocks_free_min"] is None \
-                or free < self.stats["blocks_free_min"]:
+        if free < self.stats["blocks_free_min"]:
             self.stats["blocks_free_min"] = free
         telemetry.set_gauge(self._gauge + "blocks_free", free)
         telemetry.set_gauge(self._gauge + "blocks_shared",
@@ -2174,72 +2054,68 @@ class ServingEngine:
         """The donated K/V buffer was consumed by a failed launch: every
         ADMITTED sequence lost its context (typed failure), the cache is
         reallocated, and the engine keeps serving its queue — scoped
-        failure, not an engine death.  On the paged path the whole pool
-        + every block table is rebuilt: the allocator resets, active
+        failure, not an engine death.  The whole pool + every block
+        table is rebuilt: the allocator resets, active
         sequences fail typed, and mid-prefill requests requeue for one
         retry against the fresh pool (their cached chunks died with it)."""
         err = ServeCacheInvalidated(
             "ServingEngine %s: K/V cache invalidated (%s)"
             % (self.name, reason[:300]))
-        for slot, seq in list(self._active.items()):
+        for row, seq in list(self._active.items()):
             seq.blocks = None  # the pool they pointed into is gone
-            self._retire_error(slot, seq, err)
-        if self._paged:
-            for row, pf in list(self._prefilling.items()):
-                del self._prefilling[row]
-                self._free.append(row)
-                pf.blocks = None
-                if pf.req._requeues < 1:
-                    pf.req._requeues += 1
-                    with self._qlock:
-                        self._queue.appendleft(pf.req)
-                    tracing.phase(pf.req.id, "queue_wait", self.name,
-                                  requeue="cache_rebuild")
-                else:
-                    self._quarantine(pf.req, "prefill lost to a cache "
-                                     "rebuild twice: %s" % reason[:200])
-            for row, rs in list(self._restoring.items()):
-                # a staged restore's target blocks died with the pool;
-                # same one-retry contract as a mid-stream prefill
-                del self._restoring[row]
-                self._free.append(row)
-                rs.blocks = None
-                if rs.req._requeues < 1:
-                    rs.req._requeues += 1
-                    with self._qlock:
-                        self._queue.appendleft(rs.req)
-                    tracing.phase(rs.req.id, "queue_wait", self.name,
-                                  requeue="cache_rebuild")
-                else:
-                    self._quarantine(rs.req, "restore lost to a cache "
-                                     "rebuild twice: %s" % reason[:200])
-            for row, ld in list(self._landing.items()):
-                # a staged handoff landing's target blocks died with the
-                # pool; the packed host bytes are useless without them —
-                # fall back to the journal exact-replay road
-                del self._landing[row]
-                self._free.append(row)
-                ld.blocks = None
-                self._handoff_lost(ld.ticket.req,
-                                   "handoff landing lost to a cache "
-                                   "rebuild: %s" % reason[:200])
-            if self._prefix is not None:
-                self._prefix.clear()  # the pool its nodes point at is gone
-            if self._tier is not None:
-                # the index died with the pool and the host copies are
-                # unreachable without it: clear the bottom tier too (one
-                # sweep, not a hook per handle)
-                self._tier.clear()
-                telemetry.set_gauge(self._gauge + "host_blocks_used", 0)
-            self._alloc.reset()
-            self._cache = self.model.init_block_pool(
-                self.n_blocks, self.block_size, device=self._kv_device())
-            if self._drafter is not None:
-                self._drafter.on_cache_rebuild()
-            self._block_gauges()
-        else:
-            self._cache = self.model.init_cache(self.max_batch + 1,
-                                                device=self._kv_device())
+            self._retire_error(row, seq, err)
+        for row, pf in list(self._prefilling.items()):
+            del self._prefilling[row]
+            self._free.append(row)
+            pf.blocks = None
+            if pf.req._requeues < 1:
+                pf.req._requeues += 1
+                with self._qlock:
+                    self._queue.appendleft(pf.req)
+                tracing.phase(pf.req.id, "queue_wait", self.name,
+                              requeue="cache_rebuild")
+            else:
+                self._quarantine(pf.req, "prefill lost to a cache "
+                                 "rebuild twice: %s" % reason[:200])
+        for row, rs in list(self._restoring.items()):
+            # a staged restore's target blocks died with the pool;
+            # same one-retry contract as a mid-stream prefill
+            del self._restoring[row]
+            self._free.append(row)
+            rs.blocks = None
+            if rs.req._requeues < 1:
+                rs.req._requeues += 1
+                with self._qlock:
+                    self._queue.appendleft(rs.req)
+                tracing.phase(rs.req.id, "queue_wait", self.name,
+                              requeue="cache_rebuild")
+            else:
+                self._quarantine(rs.req, "restore lost to a cache "
+                                 "rebuild twice: %s" % reason[:200])
+        for row, ld in list(self._landing.items()):
+            # a staged handoff landing's target blocks died with the
+            # pool; the packed host bytes are useless without them —
+            # fall back to the journal exact-replay road
+            del self._landing[row]
+            self._free.append(row)
+            ld.blocks = None
+            self._handoff_lost(ld.ticket.req,
+                               "handoff landing lost to a cache "
+                               "rebuild: %s" % reason[:200])
+        if self._prefix is not None:
+            self._prefix.clear()  # the pool its nodes point at is gone
+        if self._tier is not None:
+            # the index died with the pool and the host copies are
+            # unreachable without it: clear the bottom tier too (one
+            # sweep, not a hook per handle)
+            self._tier.clear()
+            telemetry.set_gauge(self._gauge + "host_blocks_used", 0)
+        self._alloc.reset()
+        self._cache = self.model.init_block_pool(
+            self.n_blocks, self.block_size, device=self._kv_device())
+        if self._drafter is not None:
+            self._drafter.on_cache_rebuild()
+        self._block_gauges()
         self._count("cache_rebuilds")
         telemetry.record_event("serve_cache_rebuild", replica=self.name,
                                reason=reason[:200])
@@ -2262,91 +2138,9 @@ class ServingEngine:
             seed[i] = r.seed
         return tuple(self._put(a) for a in (temp, tk, tp, seed))
 
+    # -- admission / chunked prefill ---------------------------------------
     def _admit_one(self, req):
-        """Admit one queued request.  Returns False ONLY when a paged
-        block allocation was denied (the request went back to the queue
-        front — stop admitting this iteration)."""
-        if self._paged:
-            return self._admit_one_paged(req)
-        slot = self._free.pop()
-        tracing.phase(req.id, "prefill", self.name,
-                      prompt_len=len(req.prompt))
-        try:
-            plen = len(req.prompt)
-            s = self._bucket_for(plen, self.prefill_buckets)
-            toks = np.zeros((1, s), np.int32)
-            toks[0, :plen] = req.prompt
-            toks_d = self._put(toks)
-            length = self._put(np.array([plen], np.int32))
-            slot_d = self._put(np.array([slot], np.int32))
-            samp = self._samp_device([req], 1)
-            self._watch("prefill", (toks_d, length, slot_d) + samp,
-                        ("tokens", "length", "slot")
-                        + self._SAMPLE_NAMES[:len(samp)], s)
-            compiled = self._compiled_prefill(s)
-            if chaos.serve_launch_error():
-                raise chaos.ChaosError("chaos: injected prefill launch "
-                                       "error")
-        except Exception as e:
-            # nothing launched: the fault is this request's alone
-            self._free.append(slot)
-            self._quarantine(req, "prefill setup failed: %s" % e)
-            return True
-        try:
-            first, self._cache = self._unpack(compiled(
-                self._params, self._cache, toks_d, length, slot_d, *samp))
-            first = int(np.asarray(first)[0])
-        except Exception as e:
-            self._free.append(slot)
-            kind = self._classify_failure(e)
-            if kind == "device":
-                req._finish(error=ServeEngineDead(
-                    "prefill launch failed: %s" % str(e)[:400]))
-                raise _EngineFatal("prefill launch failed: %s" % e) from e
-            if kind == "cache":
-                self._rebuild_cache("prefill launch failed: %s" % e)
-                # this request's prefill was eaten with the cache; one
-                # retry against the fresh buffer, then quarantine
-                if req._requeues < 1:
-                    req._requeues += 1
-                    with self._qlock:
-                        self._queue.appendleft(req)
-                    tracing.phase(req.id, "queue_wait", self.name,
-                                  requeue="cache_rebuild")
-                else:
-                    self._quarantine(req, "prefill launch failed twice "
-                                     "across a cache rebuild: %s" % e)
-                return True
-            self._quarantine(req, "prefill launch failed: %s" % e)
-            return True
-        if first < 0:
-            # quantization logit gate (no token emitted yet: the retry
-            # replays the whole prompt — the slot path has no blocks or
-            # prefix index to scrub)
-            self._free.append(slot)
-            self._quant_trip_req(req, "prefill")
-            return True
-        telemetry.observe("serve.queue_age_ms",
-                          1e3 * (time.perf_counter() - req.t_submit))
-        req.t_first = time.perf_counter()
-        req.tokens.append(first)
-        self.stats["prefills"] += 1
-        self.stats["prefill_tokens"] += plen
-        self.stats["tokens"] += 1
-        telemetry.inc("serve.prefills")
-        telemetry.inc("serve.tokens")
-        seq = _Seq(req, first, plen)
-        if self._seq_finished(seq, first):
-            self._retire(slot, seq, enter=False)
-        else:
-            tracing.phase(req.id, "decode", self.name, pos=plen)
-            self._active[slot] = seq
-        req._publish()
-        return True
-
-    # -- paged admission / chunked prefill ---------------------------------
-    def _admit_one_paged(self, req):
-        """Paged admission: look up the longest cached block-aligned
+        """Admit one queued request: look up the longest cached block-aligned
         prefix, acquire those shared blocks, allocate fresh blocks for
         the uncached suffix (+ the first decode write), then stream only
         the SUFFIX through the pool in bucket-sized chunks.  A prompt the
@@ -2358,7 +2152,7 @@ class ServingEngine:
         pool can free, or a `block_exhaust` chaos clause — is a typed
         requeue: the request goes BACK to the queue front and admission
         stops this iteration (free blocks can only appear when something
-        retires)."""
+        retires); that is the ONLY case this returns False."""
         row = self._free.pop()
         tokens = req.prompt if req._resume is None else req._resume[0]
         if self._prefix is None:
@@ -2653,7 +2447,7 @@ class ServingEngine:
         return False without touching anything: the `MXNET_SERVE_DISAGG=0`
         bit-for-bit contract lives on this first line."""
         if self._handoff_sink is None or self.role != "prefill" \
-                or not self._paged or req._no_handoff or pos <= 0:
+                or req._no_handoff or pos <= 0:
             return False
         t_pack = time.perf_counter()  # handoff stage START (pack + ship)
         ticket = None
@@ -2746,8 +2540,6 @@ class ServingEngine:
         this replica is dead, draining, or stopped — the drain fence
         the router's redirect logic relies on: a handoff must never
         race admission-close on a draining target."""
-        if not self._paged:
-            raise MXNetError("receive_handoff: paged serving only")
         # adopt the carried trace context BEFORE queueing: spans this
         # replica records parent under the root the prefill side opened
         tracing.adopt(ticket.trace, ticket.parent, replica=self.name)
@@ -2894,7 +2686,7 @@ class ServingEngine:
 
     def _advance_chunk(self, pf):
         """Launch one prefill chunk; the final chunk moves the sequence
-        to the active set.  Failure scoping mirrors the slot path:
+        to the active set.  Failure scoping:
         setup/scoped faults quarantine the request, cache loss rebuilds
         the pool (requeueing every mid-prefill request, this one
         included), device death is scheduler-fatal."""
@@ -3274,10 +3066,10 @@ class ServingEngine:
             return True
         return False
 
-    def _retire(self, slot, seq, enter=True):
+    def _retire(self, row, seq, enter=True):
         if enter:
-            del self._active[slot]
-        self._free.append(slot)
+            del self._active[row]
+        self._free.append(row)
         if self._drafter is not None and seq.ctx is not None:
             # learning drafters index completed generations (the REST-
             # style store): deterministic decoding makes a finished
@@ -3300,9 +3092,9 @@ class ServingEngine:
         if seq.req.ttft_ms is not None:
             telemetry.observe("serve.ttft_ms", seq.req.ttft_ms)
 
-    def _retire_error(self, slot, seq, err):
-        del self._active[slot]
-        self._free.append(slot)
+    def _retire_error(self, row, seq, err):
+        del self._active[row]
+        self._free.append(row)
         self._release_blocks(seq)
         seq.req._finish(error=err)
 
@@ -3337,12 +3129,12 @@ class ServingEngine:
                         keep.append(r)
                 self._queue = keep
                 self._qcond.notify_all()
-        for slot, seq in list(self._active.items()):
+        for row, seq in list(self._active.items()):
             r = seq.req
             if r._cancelled or r.expired(now):
                 dropped.append(r)
-                del self._active[slot]
-                self._free.append(slot)
+                del self._active[row]
+                self._free.append(row)
                 self._release_blocks(seq)
         for pf in list(self._prefilling.values()):
             r = pf.req
@@ -3532,11 +3324,9 @@ class ServingEngine:
                     self._count_evictions(len(evicted))
         with _phase("sweep"):
             self._sweep()
-        if self._paged:
-            self._advance_staged()
+        self._advance_staged()
         self._admit()
-        if self._paged:
-            self._grow()
+        self._grow()
         n = len(self._active)
         if n > self.stats["max_concurrent"]:
             self.stats["max_concurrent"] = n
@@ -3563,8 +3353,8 @@ class ServingEngine:
         no row has a usable draft — a verify launch that can only
         accept zero drafts would pay the k+1-wide program for the same
         one token per row this computes)."""
-        slots = [s for s in self._active if s not in self._stalled]
-        n = len(slots)
+        rows = [s for s in self._active if s not in self._stalled]
+        n = len(rows)
         if n == 0:
             # every active row is stalled on a denied allocation: nothing
             # to launch — back off briefly so the retry loop doesn't spin
@@ -3572,37 +3362,26 @@ class ServingEngine:
             time.sleep(0.001)
             return len(self._active) + self._pending_work()
         b = self._bucket_for(n, self.decode_buckets)
-        seqs = [self._active[s] for s in slots]
+        seqs = [self._active[s] for s in rows]
         with _phase("pack"):
             token = np.zeros((b,), np.int32)
             pos = np.zeros((b,), np.int32)
-            if self._paged:
-                tables = np.full((b, self._n_table), TRASH_BLOCK, np.int32)
-                for i, seq in enumerate(seqs):
-                    token[i] = seq.last
-                    pos[i] = seq.pos
-                    tables[i, :len(seq.blocks)] = seq.blocks
-                extra, names = (self._put(tables),), \
-                    ("token", "pos", "tables")
-            else:
-                slot_ids = np.full((b,), self.max_batch, np.int32)  # trash
-                for i, (slot, seq) in enumerate(zip(slots, seqs)):
-                    token[i] = seq.last
-                    pos[i] = seq.pos
-                    slot_ids[i] = slot
-                extra, names = (self._put(slot_ids),), \
-                    ("token", "pos", "slots")
+            tables = np.full((b, self._n_table), TRASH_BLOCK, np.int32)
+            for i, seq in enumerate(seqs):
+                token[i] = seq.last
+                pos[i] = seq.pos
+                tables[i, :len(seq.blocks)] = seq.blocks
             samp = self._samp_device([s.req for s in seqs], b)
-            args = (self._put(token), self._put(pos)) + extra + samp
+            args = (self._put(token), self._put(pos),
+                    self._put(tables)) + samp
             self._watch("decode", args,
-                        names + self._SAMPLE_NAMES[:len(samp)], b)
+                        ("token", "pos", "tables")
+                        + self._SAMPLE_NAMES[:len(samp)], b)
             compiled = self._compiled_decode(b)
-        self._iter.update(rows=n, bucket=b,
-                          attn_kernel=self._attn_kernel if self._paged else 0)
-        if self._paged:
+        self._iter.update(
+            rows=n, bucket=b, attn_kernel=self._attn_kernel,
             # table entries the rows' attention walks: their live blocks
-            self._iter["ctx_blocks"] = int(
-                np.sum(pos[:n] // self.block_size) + n)
+            ctx_blocks=int(np.sum(pos[:n] // self.block_size) + n))
         t_launch = time.perf_counter()
         try:
             if chaos.serve_launch_error():
@@ -3631,11 +3410,11 @@ class ServingEngine:
         telemetry.inc("serve.decode_padded", b - n)
         telemetry.set_gauge(self._gauge + "batch_occupancy", n / float(b))
         with _phase("publish"):
-            for i, (slot, seq) in enumerate(zip(slots, seqs)):
+            for i, (row, seq) in enumerate(zip(rows, seqs)):
                 t = int(nxt[i])
                 if t < 0:
                     # quantization logit gate: never emit the flagged token
-                    self._quant_trip_seq(slot, seq)
+                    self._quant_trip_seq(row, seq)
                     continue
                 finished = self._advance_one(seq, t)
                 if not finished and self._drafter is not None \
@@ -3644,7 +3423,7 @@ class ServingEngine:
                     # store: a staggered twin drafts off this row's stream
                     self._drafter.observe(seq.ctx + [seq.last], 1)
                 if finished:
-                    self._retire(slot, seq)
+                    self._retire(row, seq)
                 seq.req._publish()
         return len(self._active) + self._pending_work()
 
@@ -3720,12 +3499,12 @@ class ServingEngine:
         under the launch.  Returns None when nothing launched (all
         rows stalled, or the launch failed and took the retry
         ladder)."""
-        slots = [s for s in self._active if s not in self._stalled]
-        nrows = len(slots)
+        rows = [s for s in self._active if s not in self._stalled]
+        nrows = len(rows)
         if nrows == 0:
             return None
         b = self._bucket_for(nrows, self.decode_buckets)
-        seqs = [self._active[s] for s in slots]
+        seqs = [self._active[s] for s in rows]
         with _phase("pack"):
             token = np.zeros((b,), np.int32)
             pos = np.zeros((b,), np.int32)
@@ -3760,7 +3539,7 @@ class ServingEngine:
             self._handle_launch_failure(e, "megastep")
             return None
         self._launch_fails = 0
-        return (slots, seqs, out, nrows, b, t_launch)
+        return (rows, seqs, out, nrows, b, t_launch)
 
     def _finish_mega(self, inflight):
         """Fetch a megastep's (b, m) token grid and walk it row-major
@@ -3771,7 +3550,7 @@ class ServingEngine:
         trip scrubs/requeues exactly as a single-step trip would),
         -2 dead (the row retired at an earlier step — or was launched
         already-finished)."""
-        slots, seqs, out, nrows, b, t_launch = inflight
+        rows, seqs, out, nrows, b, t_launch = inflight
         t_fetch = time.perf_counter()
         with _phase("fetch"):
             out = np.asarray(out)  # the one per-megastep host fetch
@@ -3792,8 +3571,8 @@ class ServingEngine:
                             nrows / float(b))
         emitted = retired = 0
         with _phase("publish"):
-            for i, (slot, seq) in enumerate(zip(slots, seqs)):
-                if self._active.get(slot) is not seq:
+            for i, (row, seq) in enumerate(zip(rows, seqs)):
+                if self._active.get(row) is not seq:
                     # swept, preempted or vacated while in flight: its
                     # in-flight tokens drop on the floor; the journal still
                     # holds the pre-megastep position, so replay neither
@@ -3815,10 +3594,10 @@ class ServingEngine:
                 emitted += adv
                 if tripped:
                     # quantization logit gate: never emit the flagged token
-                    self._quant_trip_seq(slot, seq, "megastep")
+                    self._quant_trip_seq(row, seq, "megastep")
                 elif finished:
                     retired += 1  # retirement decided in-graph, mid-scan
-                    self._retire(slot, seq)
+                    self._retire(row, seq)
                 elif adv and self._drafter is not None \
                         and seq.ctx is not None:
                     self._drafter.observe(seq.ctx + [seq.last], adv)
@@ -4148,9 +3927,9 @@ class ServingEngine:
         migrates the stragglers), so the release accounting cannot
         diverge between the two exits."""
         inflight = []
-        for slot, seq in list(self._active.items()):
-            del self._active[slot]
-            self._free.append(slot)
+        for row, seq in list(self._active.items()):
+            del self._active[row]
+            self._free.append(row)
             self._release_blocks(seq)
             inflight.append(seq.req)
         for pf in list(self._prefilling.values()):
@@ -4190,7 +3969,7 @@ class ServingEngine:
             if t.is_alive():
                 # a wedged device launch: keep the ref so a later start()
                 # cannot spawn a second scheduler over the same cache and
-                # slot state, and fail loudly
+                # row state, and fail loudly
                 raise MXNetError(
                     "ServingEngine %s: scheduler thread did not stop "
                     "within 30s (wedged launch?)" % self.name)
@@ -4385,11 +4164,6 @@ class ReplicaRouter:
         self._disagg = bool(disagg) and len(self.engines) >= 2
         n = len(self.engines)
         if self._disagg:
-            if not all(e._paged for e in self.engines):
-                raise MXNetError(
-                    "ReplicaRouter: MXNET_SERVE_DISAGG needs paged=True "
-                    "on every replica (the handoff is a paged block-run "
-                    "transfer)")
             p = int(os.environ.get("MXNET_SERVE_PREFILL_REPLICAS", "0")
                     if prefill_replicas is None else prefill_replicas)
             if p <= 0:
@@ -4502,7 +4276,7 @@ class ReplicaRouter:
         and re-enters decode at the same position with the same
         request-keyed RNG.  Returns the engine that took it (truthy; a
         request already resolved in the window returns True), or False
-        when nothing can take it (no journal, no paged survivor, or
+        when nothing can take it (no journal, no survivor, or
         every survivor shed) — callers that only branch keep working,
         and `drain` uses the target to move session entries WITH their
         live turn."""
@@ -4512,9 +4286,6 @@ class ReplicaRouter:
             return True   # resolved in the window: nothing to move
         state = self.journal.replay_state(req)
         survivors = self._live_engines(exclude=exclude)
-        if state is not None:
-            # exact replay rides the paged resume path
-            survivors = [e for e in survivors if e._paged]
         if not survivors:
             return False
         if state is not None:
@@ -4791,7 +4562,7 @@ class ReplicaRouter:
                     if isinstance(target, ServingEngine):
                         moved[id(req)] = target
                     continue
-                # no journal (or no paged survivor): a straggler with no
+                # no journal (or no survivor): a straggler with no
                 # generated tokens needs no replay — the PR-8 redispatch
                 # keeps it alive losslessly; only in-flight progress that
                 # cannot be replayed has to fail typed

@@ -13,8 +13,8 @@ block, for a row ``x`` (RMSNorm, no biases anywhere):
 FFN is a SwiGLU in the first ``first_dense`` layers and `ops.moe`'s expert
 layer after them; `ops.latent_attention` has the attention's two forms and
 the rotary positions.  The equations are written out in
-`models/kimi_k2_reference.py`, the plain float32 reference the tests hold
-these programs to.
+`benchmark/reference/kimi_k2.py`, the plain float32 reference the tests and
+the benchmark hold these programs to.
 
 **The second cache kind** (`cache_kind` "latent").  A block holds one row a
 token, ``c_kv`` after its norm beside ``k_pe`` after RoPE, shared by all
@@ -23,8 +23,8 @@ block 0 the trash block; ``width`` is ``kv_lora_rank + qk_rope_head_dim``
 rounded up to whole 128-lane tiles (576 -> 640: the TPU lays the last axis
 out in tiles of 128 either way, and the decode kernel's copies must be whole
 tiles), the spare lanes zero.  The engine reaches it only through this
-class's methods; `block_bytes` answers for its size.  What does not know the layout yet refuses it by name: int8 KV, the
-slot cache, megastep, speculation (`verify_paged`), the host tier, handoff,
+class's methods; `block_bytes` answers for its size.  What does not know the layout yet refuses it by name: int8 KV,
+megastep, speculation (`verify_paged`), the host tier, handoff,
 a sharded mesh (`unsupported`; the engine raises at construction).
 
 **The share** (`experts_held`).  The router scores all ``n_routed_experts``
@@ -73,8 +73,8 @@ class LatentMoEKVModel:
     #: engine options that do not know the latent pool yet (the engine
     #: refuses each at construction; block runs, and so the handoff, refuse
     #: the cache kind: `tiers.check_cache_kind`)
-    unsupported = frozenset({"quant", "kv_quant", "slot_cache", "megastep",
-                             "spec", "tier", "mesh"})
+    unsupported = frozenset({"quant", "kv_quant", "megastep", "spec", "tier",
+                             "mesh"})
 
     def __init__(self, vocab_size, seq_len, num_layers, hidden_size,
                  num_heads, q_lora_rank, kv_lora_rank, qk_nope_head_dim,

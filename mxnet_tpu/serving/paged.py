@@ -5,10 +5,9 @@ in this repo's primitives) splits the per-replica K/V buffer into a pool
 of fixed-size blocks: `(num_layers, 2, n_blocks, block_size, embed)` on
 the device, an int32 block table per active row, and THIS allocator on
 the host.  A sequence holds `ceil(tokens / block_size)` blocks instead
-of a full `(S_max, embed)` slot row, so HBM admits as many concurrent
-sequences as their actual lengths fit — the slot cache's worst-case
-reservation is exactly what capped batch occupancy under mixed-length
-traffic.
+of a full `(S_max, embed)` row, so HBM admits as many concurrent
+sequences as their actual lengths fit — a worst-case reservation a
+sequence is what would cap batch occupancy under mixed-length traffic.
 
 Blocks are interchangeable fixed-size units, so a free list plus a
 per-block REFCOUNT is the whole allocator: external fragmentation cannot

@@ -143,6 +143,53 @@ def check_numeric_gradient(sym, location, grad_nodes=None, rtol=1e-2,
     return exe
 
 
+class FullForward:
+    """The serving tests' reference: the Symbol's full forward of
+    `get_transformer_lm` bound to a `TransformerKVModel`'s parameters, no
+    cache and no scheduler, every position recomputed at every call."""
+
+    def __init__(self, model, params):
+        from . import cpu, nd
+        from .models.transformer import get_transformer_lm
+
+        self.vocab_size, self.seq_len = model.vocab_size, model.seq_len
+        net = get_transformer_lm(
+            model.vocab_size, model.seq_len, num_layers=model.num_layers,
+            num_heads=model.num_heads, num_embed=model.num_embed,
+            num_ffn_hidden=model.num_ffn_hidden, use_bias=model.use_bias)
+        sym = net.get_internals()["pred_output"]
+        args = {n: nd.array(params[n]) for n in model.param_shapes()}
+        args["data"] = nd.zeros((1, model.seq_len))
+        self._exe = sym.bind(cpu(), args, grad_req="null")
+
+    def logits(self, tokens):
+        """(len(tokens), vocab) logits of one sequence; the mask is causal,
+        so the padding behind it is inert."""
+        n = len(tokens)
+        data = np.zeros((1, self.seq_len), np.float32)
+        data[0, :n] = tokens
+        out = self._exe.forward(is_train=False, data=data)[0].asnumpy()
+        return out.reshape(self.seq_len, self.vocab_size)[:n]
+
+    def greedy(self, prompt, max_new, min_gap=1e-3):
+        """Greedy tokens of ``prompt``, the whole sequence recomputed for
+        every token, as far as the context reaches.  A step whose top two
+        logits lie within ``min_gap`` raises: there an engine's rounding
+        could pick the other token, so the test's seed is at fault."""
+        seq, out = [int(t) for t in prompt], []
+        while len(out) < max_new and len(seq) <= self.seq_len:
+            last = self.logits(seq)[-1]
+            second, first = np.sort(last)[-2:]
+            if first - second <= min_gap:
+                raise AssertionError(
+                    "FullForward.greedy: near-tie (%.3g) at position %d of "
+                    "prompt %s: pick another seed"
+                    % (first - second, len(seq), seq[:len(prompt)]))
+            out.append(int(np.argmax(last)))
+            seq.append(out[-1])
+        return out
+
+
 def aot_v5e_mesh():
     """One-device Mesh over a DESCRIBED (not attached) v5e topology: the
     target for compiling a program for the chip with no chip present

@@ -31,7 +31,7 @@ elif [ "$1" = "--serve-smoke" ]; then
     set -- tests/test_serving.py -q -m 'not slow' \
         -p no:cacheprovider "$@"
 elif [ "$1" = "--serve-paged-smoke" ]; then
-    # fast paged-cache smoke: block allocator, paged-vs-slot parity,
+    # fast paged-cache smoke: block allocator, parity with the full forward,
     # chunked prefill, seeded sampling, block-leak and preemption
     # coverage, and the paged zero-retrace gate (docs/serving.md
     # "Paged KV cache")

@@ -119,47 +119,6 @@ print("serve gate passed: %s tok/s/chip, p99 %s ms, occupancy %s" % (
     rec["value"], rec["latency_ms"]["p99"], rec["batch_occupancy"]))
 PY
 
-# -- paged-cache serve gate (docs/serving.md "Paged KV cache") ------------
-# slot-vs-paged A/B at EQUAL HBM budget under a mixed-length log-normal
-# trace: the paged cache must admit a strictly higher concurrent batch
-# AND beat the slot cache's tok/s/chip, leak no blocks, and compile
-# nothing in steady state on either leg; artifact lands in
-# bench_results/serve_bench.json
-env PYTHONPATH= JAX_PLATFORMS=cpu \
-    SERVE_REQUESTS=32 SERVE_SEQ=64 SERVE_NEW=12 SERVE_PROMPT_MAX=20 \
-    SERVE_SLOT_BATCH=2 MXNET_SERVE_BLOCK_SIZE=16 \
-    python bench.py --serve --mixed | tee /tmp/nightly_serve_paged.log
-python - <<'PY'
-import json
-rec = json.loads(
-    open("/tmp/nightly_serve_paged.log").read().strip().splitlines()[-1])
-slot, paged = rec["slot"], rec["paged"]
-for leg, r in (("slot", slot), ("paged", paged)):
-    assert r["completed"] == r["requests"], \
-        "paged gate (%s): %s/%s completed (errors: %s)" % (
-            leg, r["completed"], r["requests"], r.get("errors"))
-    assert r["steady_state_recompiles"] == 0, \
-        "paged gate (%s): %d steady-state recompiles" % (
-            leg, r["steady_state_recompiles"])
-    assert r["steady_state_retrace_events"] == 0, \
-        "paged gate (%s): watchdog fired %d times" % (
-            leg, r["steady_state_retrace_events"])
-assert paged["max_concurrent"] > slot["max_concurrent"], \
-    "paged gate: concurrency %s not above slot %s at equal HBM" % (
-        paged["max_concurrent"], slot["max_concurrent"])
-assert paged["value"] > slot["value"], \
-    "paged gate: %s tok/s/chip not above slot %s" % (
-        paged["value"], slot["value"])
-assert paged["blocks"]["leaked"] == 0, \
-    "paged gate: %d blocks leaked" % paged["blocks"]["leaked"]
-print("paged gate passed: %sx tok/s (%s vs %s), concurrency %s->%s, "
-      "occupancy %s->%s" % (rec["value"], slot["value"], paged["value"],
-                            slot["max_concurrent"],
-                            paged["max_concurrent"],
-                            rec["occupancy"]["slot"],
-                            rec["occupancy"]["paged"]))
-PY
-
 # -- prefix-caching serve gate (docs/serving.md "Prefix caching") ---------
 # single-owner vs prefix-sharing A/B at EQUAL HBM under the shared-
 # system-prompt trace: the prefix cache must answer strictly faster

@@ -320,6 +320,52 @@ def test_native_loader_decode_failure_count(tmp_path):
     assert float(np.abs(d[0]).sum()) > 0.0
 
 
+def test_native_loader_batch_survives_the_next_write(tmp_path):
+    """The native loader writes every batch into one buffer, and where that
+    buffer happens to lie on a 64-byte boundary JAX's CPU backend aliases a
+    host array it is handed instead of copying it: a batch whose device work
+    is still queued must not change when the loader's buffer is written
+    again (a prefetch thread calls `next()` back to back).  The buffer is
+    put on such a boundary here; numpy's own allocation lands on one in
+    some processes and not in others."""
+    import ctypes
+
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu import _native, recordio
+
+    if not _native.available():
+        pytest.skip("native lib not built")
+    path = str(tmp_path / "gray.rec")
+    rec = recordio.MXRecordIO(path, "w")
+    rng = np.random.RandomState(9)
+    for i in range(32):
+        rec.write(recordio.pack_img(
+            recordio.IRHeader(0, float(i), i, 0),
+            (rng.rand(24, 24) * 255).astype(np.uint8), img_fmt=".jpg"))
+    rec.close()
+    it = mx.io.ImageRecordIter(path_imgrec=path, data_shape=(1, 24, 24),
+                               batch_size=32, scale=1.0 / 255,
+                               use_native=True)
+    assert it._native_u8
+    raw = np.zeros(it._data_buf.nbytes + 64, np.uint8)
+    off = (-raw.ctypes.data) % 64
+    it._data_buf = raw[off:off + it._data_buf.nbytes].reshape(
+        it._data_buf.shape)
+    it._data_ptr = it._data_buf.ctypes.data_as(
+        ctypes.POINTER(ctypes.c_uint8))
+    want = next(it).data[0].asnumpy().copy()
+    busy = jax.jit(lambda a: jnp.linalg.matrix_power(a, 64))
+    big = jnp.full((512, 512), 1e-3, jnp.float32)
+    for _ in range(8):
+        it.reset()
+        busy(big)                    # the device is busy: what follows queues
+        batch = next(it)
+        it._data_buf[:] = 255        # the loader's next write
+        np.testing.assert_array_equal(batch.data[0].asnumpy(), want)
+
+
 def test_recordio_remote_fetch_hooks(tmp_path):
     """Remote-read hooks (the dmlc::InputSplit role,
     `iter_image_recordio.cc:105-126`): file:// built in, custom schemes

@@ -196,7 +196,6 @@ def test_block_exhaust_total_denial_expires_typed_not_hangs(
     and nothing hangs."""
     model, params = model_and_params
     eng = _engine(model, params)
-    assert eng._paged
     eng.warmup()
     _chaos(monkeypatch, "block_exhaust:1.0")
     reqs = [eng.submit([1 + i, 2], deadline_ms=300) for i in range(3)]

@@ -174,10 +174,8 @@ def test_megastep_kill_switch_builds_nothing(model_and_params):
     assert off._mega_m == 0
 
 
-def test_megastep_requires_paged_and_sane_steps(model_and_params):
+def test_megastep_rejects_zero_steps(model_and_params):
     model, params = model_and_params
-    with pytest.raises(MXNetError):
-        _engine(model, params, megastep=True, paged=False)
     with pytest.raises(MXNetError):
         _mega_engine(model, params, m=0)
 

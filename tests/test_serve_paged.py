@@ -5,14 +5,14 @@ Contracts under test:
 1. `BlockAllocator`: LIFO free list over the fixed pool — exhaustion is
    a None (not an exception), double/trash frees are loud, reset voids
    everything.
-2. Paged-vs-slot parity: with `MXNET_SERVE_PAGED=0` as the oracle, the
-   paged engine produces token-identical greedy output under mid-batch
-   admit/retire — paging changes WHERE cache rows live, not what
-   attention sees.
+2. Parity with the full forward (`test_utils.FullForward`, no cache and
+   no scheduler): the engine produces its greedy tokens under mid-batch
+   admit/retire, through chunked prefill over a cached prefix, and
+   through the speculative verify launch — paging changes WHERE cache
+   rows live, not what attention sees.
 3. Chunked prefill: a prompt longer than the largest prefill bucket
    streams through bucket-sized chunks and matches a single-shot
-   prefill token-for-token; the slot path (and chunk_prefill=False)
-   still rejects it typed.
+   prefill token-for-token.
 4. Sampling: temperature/top-k/top-p with a request-keyed seeded RNG —
    deterministic across runs, invariant to batch composition, and
    greedy neighbours are unperturbed.
@@ -35,6 +35,7 @@ from mxnet_tpu.base import MXNetError
 from mxnet_tpu.serving import (BlockAllocator, ServingEngine,
                                TransformerKVModel, ServeBlocksExhausted,
                                ServeCacheInvalidated, TRASH_BLOCK)
+from mxnet_tpu.test_utils import FullForward
 
 V, S, L, H, E = 61, 32, 2, 2, 32
 
@@ -58,7 +59,7 @@ def _engine(model, params, **kw):
     kw.setdefault("max_new_tokens", 6)
     # greedy-only programs unless a test opts in: the in-graph sampler
     # roughly doubles each program's AOT time and only the sampling
-    # tests (and the slot-vs-paged parity A/B) need it compiled
+    # tests (and the mid-batch parity test) need it compiled
     kw.setdefault("sampling", False)
     return ServingEngine(model, params, **kw)
 
@@ -131,12 +132,12 @@ def test_block_size_must_divide_prefill_buckets(model_and_params):
         _engine(model, params, block_size=16)  # buckets [8, 16]
     eng = _engine(model, params)               # auto clips 16 -> 8
     assert eng.block_size == 8
-    # default pool = the slot cache's exact HBM budget, re-cut
+    # default pool: max_batch rows at full depth, one more for the trash
     assert eng.n_blocks == (eng.max_batch + 1) * (-(-S // 8))
 
 
 # ---------------------------------------------------------------------------
-# 2. paged vs slot parity
+# 2. parity with the full forward
 # ---------------------------------------------------------------------------
 
 def _drain(eng, reqs, timeout=300):
@@ -144,27 +145,26 @@ def _drain(eng, reqs, timeout=300):
     return [r.result(1) for r in reqs]
 
 
-def test_paged_vs_slot_token_parity_mid_batch(model_and_params):
-    """Mixed lengths, staggered admits/retires: the paged engine's greedy
-    output is token-identical to the slot engine's (MXNET_SERVE_PAGED=0
-    oracle) — the kill-switch contract read in both directions."""
+def test_paged_token_parity_mid_batch_vs_full_forward(model_and_params):
+    """Mixed lengths, staggered admits/retires, the sampling programs at
+    temperature 0: the engine's output is token-identical to the full
+    forward's greedy tokens, one sequence at a time."""
     model, params = model_and_params
-    rng = np.random.RandomState(11)
+    full_forward = FullForward(model, params)
+    rng = np.random.RandomState(13)
     prompts = [list(rng.randint(0, V, size=n)) for n in (3, 9, 5, 14, 2, 7)]
     max_news = [2, 6, 3, 5, 6, 4]
-    outs = {}
-    for paged in (False, True):
-        eng = _engine(model, params, max_batch=3, paged=paged,
-                      sampling=True)
-        first = [eng.submit(p, max_new_tokens=m)
-                 for p, m in zip(prompts[:4], max_news[:4])]
-        for _ in range(3):
-            eng.step()
-        late = [eng.submit(p, max_new_tokens=m)
-                for p, m in zip(prompts[4:], max_news[4:])]
-        outs[paged] = _drain(eng, first + late)
-        assert not eng._active and len(eng._free) == eng.max_batch
-    assert outs[True] == outs[False]
+    eng = _engine(model, params, max_batch=3, sampling=True)
+    first = [eng.submit(p, max_new_tokens=m)
+             for p, m in zip(prompts[:4], max_news[:4])]
+    for _ in range(3):
+        eng.step()
+    late = [eng.submit(p, max_new_tokens=m)
+            for p, m in zip(prompts[4:], max_news[4:])]
+    got = _drain(eng, first + late)
+    assert not eng._active and len(eng._free) == eng.max_batch
+    assert got == [full_forward.greedy(p, m)
+                   for p, m in zip(prompts, max_news)]
 
 
 @pytest.mark.parametrize("dtype", ["bfloat16", "float32", "int8"])
@@ -216,29 +216,31 @@ def test_gather_from_the_whole_pool_reads_the_slices_rows(dtype):
 
 
 @pytest.mark.parametrize("path", ["chunked_prefill", "verify"])
-def test_paths_that_gather_the_context_match_the_slot_engine(
+def test_paths_that_gather_the_context_match_the_full_forward(
         model_and_params, path):
     """Every `jax.numpy` path that reads a row's context through
     `gather_paged_kv` (a prefill chunk over a cached prefix; the speculative
-    verify launch; the CPU's decode launch in both) against the slot-cache
-    engine, which has no pool to gather from: the same greedy tokens."""
+    verify launch; the CPU's decode launch in both) against the full
+    forward, which has no pool to gather from: the same greedy tokens."""
     model, params = model_and_params
-    rng = np.random.RandomState(31)
+    full_forward = FullForward(model, params)
+    rng = np.random.RandomState(56)
     prompts = [list(rng.randint(0, V, size=n)) for n in (14, 3, 11, 16)]
-    slot = _engine(model, params, paged=False, decode_buckets=[4])
-    want = _drain(slot, [slot.submit(p, max_new_tokens=6) for p in prompts])
+    want = [full_forward.greedy(p, 6) for p in prompts]
     if path == "chunked_prefill":
         eng = _engine(model, params, prefill_buckets=[8],
                       decode_buckets=[4])
     else:
+        # with prompts longer than the one prefill bucket, so that the
+        # verify launches read a context that chunks wrote
         eng = _engine(model, params, spec=True, spec_k=3,
-                      spec_drafter="ngram", decode_buckets=[4])
+                      spec_drafter="ngram", prefill_buckets=[8],
+                      decode_buckets=[4])
     got = _drain(eng, [eng.submit(p, max_new_tokens=6) for p in prompts])
     assert got == want
     reg = telemetry.registry()
-    if path == "chunked_prefill":
-        assert reg.counter("serve.prefill_chunks").value >= 7
-    else:
+    assert reg.counter("serve.prefill_chunks").value >= 7
+    if path == "verify":
         assert reg.counter("serve.verify_steps").value > 0
 
 
@@ -249,8 +251,7 @@ def test_paged_zero_retrace_and_frozen_cache(model_and_params):
     frozen-cache witness (`serve.aot.frozen_compiles`) still zero."""
     model, params = model_and_params
     eng = _engine(model, params, sampling=True)  # the full acceptance
-    assert eng._paged                            # config: paged + chunked
-    eng.warmup()                                 # + sampling programs
+    eng.warmup()                                 # config: sampling programs
     reg = telemetry.registry()
     compiles = reg.counter("serve.aot.compiles").value
     # prefix sharing (default-on) adds exactly ONE program: the CoW copy
@@ -309,13 +310,6 @@ def test_chunked_prefill_piggybacks_on_decode(model_and_params):
     outs = _drain(eng, [short, long_req])
     assert outs == [_oracle(model, params, short_p, 6),
                     _oracle(model, params, long_p, 3)]
-
-
-def test_chunk_prefill_disabled_rejects_long_prompt(model_and_params):
-    model, params = model_and_params
-    eng = _engine(model, params, chunk_prefill=False)
-    with pytest.raises(MXNetError, match="prefill bucket"):
-        eng.submit(list(range(17)))
 
 
 # ---------------------------------------------------------------------------

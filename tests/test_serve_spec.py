@@ -346,12 +346,6 @@ def test_spec_kill_switch_restores_plain_decode(model_and_params):
     assert _run(eng_on, reqs_kw) == outs
 
 
-def test_spec_requires_paged_cache(model_and_params):
-    model, params = model_and_params
-    with pytest.raises(MXNetError, match="paged"):
-        _engine(model, params, paged=False, spec=True)
-
-
 def test_spec_respawn_carries_config_and_compiles_nothing(model_and_params):
     model, params = model_and_params
     eng = _spec_engine(model, params, "model")

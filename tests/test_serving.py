@@ -2,9 +2,10 @@
 
 The contracts under test, in dependency order:
 
-1. KV-cache numerics: prefill + single-token decode reproduce the
-   full-sequence `models/transformer.py` forward (the Symbol graph bound
-   through Executor) within fp32 tolerance, token by token.
+1. KV-cache numerics: chunked prefill + single-token decode over the
+   paged pool reproduce the full-sequence `models/transformer.py` forward
+   (the Symbol graph bound through Executor) within fp32 tolerance, token
+   by token.
 2. Scheduling: sequences admit and retire MID-batch (iteration-level,
    Orca-style) without perturbing their neighbours — batched greedy
    outputs are bit-identical to one-request-at-a-time runs.
@@ -27,11 +28,11 @@ import pytest
 
 import jax.numpy as jnp
 
-import mxnet_tpu as mx
 from mxnet_tpu import telemetry
 from mxnet_tpu.base import MXNetError
 from mxnet_tpu.models.transformer import get_transformer_lm
 from mxnet_tpu.ops.attention import decode_attention
+from mxnet_tpu.test_utils import FullForward
 from mxnet_tpu.serving import (ReplicaRouter, ServingEngine,
                                TransformerKVModel, ServeTimeout,
                                ServeOverload, ServeDeadlineExceeded,
@@ -108,57 +109,77 @@ def test_param_names_match_transformer_symbol(model_and_params):
         assert tuple(by_name[name]) == tuple(shape), name
 
 
-def test_prefill_decode_parity_vs_full_forward(model_and_params):
-    """Acceptance gate: KV-cache decode logits == full-sequence forward
-    logits at every generated position, within fp32 tolerance."""
+@pytest.mark.parametrize("block_size", [4, 8, 16])
+def test_prefill_decode_parity_vs_full_forward(model_and_params, block_size):
+    """Acceptance gate: the programs the engine launches, `prefill_paged`
+    in chunks (one row a launch, as the engine admits), `verify_paged`
+    over a fed span and `decode_paged` over a ragged batch, through block
+    tables that are a shuffle of the pool, give the full-sequence
+    forward's logits at every position, within fp32 tolerance."""
     model, params = model_and_params
-    net = get_transformer_lm(V, S, num_layers=L, num_heads=H, num_embed=E)
-    logits_sym = net.get_internals()["pred_output"]
-
-    B, P = 3, 5
+    full_forward = FullForward(model, params)
     rng = np.random.RandomState(0)
+    lens = [5, 13, 22]
+    B, m = len(lens), S // block_size
     toks = rng.randint(0, V, size=(B, S))
-    args = {n: mx.nd.array(params[n]) for n in model.param_shapes()}
-    args["data"] = mx.nd.array(toks.astype(np.float32))
-    exe = logits_sym.bind(mx.cpu(), args, grad_req="null")
-    full = exe.forward(is_train=False)[0].asnumpy().reshape(B, S, V)
+    full = np.stack([full_forward.logits(t) for t in toks])
 
+    tables = 1 + rng.permutation(B * m).reshape(B, m)   # block 0 is trash
+    assert (tables != 1 + np.arange(B * m).reshape(B, m)).any()
+    tables = jnp.asarray(tables, jnp.int32)
+    pool = model.init_block_pool(1 + B * m, block_size)
     pj = {k: jnp.asarray(v) for k, v in params.items()}
-    length = jnp.full((B,), P, jnp.int32)
-    slots = jnp.arange(B, dtype=jnp.int32)
-    logits_p, kv = model.prefill(pj, jnp.asarray(toks[:, :P], jnp.int32),
-                                 length)
-    np.testing.assert_allclose(np.asarray(logits_p), full[:, P - 1],
-                               atol=2e-5)
-    cache = model.write_prefill(model.init_cache(B), kv, length, slots)
-    for t in range(P, S):
-        lg, cache = model.decode(pj, cache,
-                                 jnp.asarray(toks[:, t], jnp.int32),
-                                 jnp.full((B,), t, jnp.int32), slots)
-        np.testing.assert_allclose(np.asarray(lg), full[:, t], atol=2e-5,
-                                   err_msg="decode diverged at pos %d" % t)
+    chunk = max(8, block_size)
+    for i, n in enumerate(lens):
+        for start in range(0, n, chunk):
+            piece = np.zeros((1, chunk), np.int32)
+            length = min(chunk, n - start)
+            piece[0, :length] = toks[i, start:start + length]
+            lg, pool = model.prefill_paged(
+                pj, pool, jnp.asarray(piece), jnp.asarray([start], jnp.int32),
+                jnp.asarray([length], jnp.int32), tables[i:i + 1])
+        np.testing.assert_allclose(np.asarray(lg[0]), full[i, n - 1],
+                                   atol=2e-5)
+    # the speculative verify launch scores a fed span in one program: the
+    # same logits at every fed position (its pool is dropped: the decode
+    # steps below write those positions themselves)
+    span = np.stack([toks[i, n:n + 4] for i, n in enumerate(lens)])
+    lg, _ = model.verify_paged(
+        pj, pool, jnp.asarray(span, jnp.int32), jnp.asarray(lens, jnp.int32),
+        jnp.full((B,), 4, jnp.int32), tables)
+    for i, n in enumerate(lens):
+        np.testing.assert_allclose(np.asarray(lg[i]), full[i, n:n + 4],
+                                   atol=2e-5)
+    for step in range(S - max(lens)):
+        pos = np.asarray(lens) + step
+        lg, pool = model.decode_paged(
+            pj, pool, jnp.asarray(toks[np.arange(B), pos], jnp.int32),
+            jnp.asarray(pos, jnp.int32), tables)
+        np.testing.assert_allclose(
+            np.asarray(lg), full[np.arange(B), pos], atol=2e-5,
+            err_msg="decode diverged at positions %s" % pos)
 
 
 def test_ragged_prefill_lengths_isolated(model_and_params):
-    """Rows with different prompt lengths in one padded prefill must match
-    their own unpadded single-row prefill (right-padding is inert)."""
+    """Rows with different prompt lengths in one padded `prefill_paged`
+    launch must each give their own sequence's logits (right-padding is
+    inert, whatever it holds, and rows do not see each other)."""
     model, params = model_and_params
+    full_forward = FullForward(model, params)
     pj = {k: jnp.asarray(v) for k, v in params.items()}
     rng = np.random.RandomState(3)
     lens = [3, 8, 5]
-    s_bucket = 8
-    toks = np.zeros((len(lens), s_bucket), np.int32)
-    rows = [rng.randint(0, V, size=n) for n in lens]
-    for i, r in enumerate(rows):
-        toks[i, :len(r)] = r
-    logits, _ = model.prefill(pj, jnp.asarray(toks),
-                              jnp.asarray(lens, jnp.int32))
-    for i, r in enumerate(rows):
-        solo, _ = model.prefill(
-            pj, jnp.asarray(r[None, :], jnp.int32),
-            jnp.asarray([len(r)], jnp.int32))
+    s_bucket, bs = 8, 4
+    toks = rng.randint(0, V, size=(len(lens), s_bucket)).astype(np.int32)
+    tables = 1 + np.arange(len(lens) * (S // bs)).reshape(len(lens), -1)
+    logits, _ = model.prefill_paged(
+        pj, model.init_block_pool(1 + tables.size, bs), jnp.asarray(toks),
+        jnp.zeros((len(lens),), jnp.int32), jnp.asarray(lens, jnp.int32),
+        jnp.asarray(tables, jnp.int32))
+    for i, n in enumerate(lens):
         np.testing.assert_allclose(np.asarray(logits[i]),
-                                   np.asarray(solo[0]), atol=2e-5)
+                                   full_forward.logits(toks[i, :n])[-1],
+                                   atol=2e-5)
 
 
 # ---------------------------------------------------------------------------
@@ -250,14 +271,12 @@ def test_capacity_bound_request_uses_full_cache(model_and_params):
 
 def test_prompt_too_long_rejected(model_and_params):
     model, params = model_and_params
-    # the largest-bucket ceiling applies to the slot path and to the
-    # paged path with chunked prefill disabled; chunked prefill (the
-    # default) streams long prompts instead (tests/test_serve_paged.py)
-    for kw in ({"paged": False}, {"chunk_prefill": False}):
-        eng = _engine(model, params, **kw)
-        with pytest.raises(MXNetError, match="prefill bucket"):
-            eng.submit(list(range(17)))
     eng = _engine(model, params)
+    # the largest prefill bucket (16) is no ceiling: up to seq_len - 1
+    # tokens a prompt streams through it in chunks
+    req = eng.submit(list(range(S - 1)), max_new_tokens=1)
+    eng.run_until_idle(timeout=300)
+    assert len(req.result(1)) == 1
     with pytest.raises(MXNetError, match="empty prompt"):
         eng.submit([])
     with pytest.raises(MXNetError, match="leaves no room"):
