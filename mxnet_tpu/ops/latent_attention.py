@@ -17,14 +17,22 @@ block.
 * **prefill** (`latent_prefill_attention`): the expanded form, blockwise.  A
   chunk of ``c`` queries attends to the cached prefix and to itself (its own
   rows are already in the pool) in context blocks of bounded size: each
-  block's latent rows are gathered through the table, expanded through
+  block's latent rows are taken through the table, expanded through
   ``W_kvb`` to per-head keys and values, and folded into a running float32
-  softmax.  The loop's trip count follows the context the chunk can see, not
-  the table's width, and no temporary is chunk x table width x heads.
+  softmax.  The trip count follows the context the chunk can see, not the
+  table's width, and no temporary is chunk x table width x heads.
   Re-expanding a cached token costs 2 x rank x heads x (nope + v) operations
   a chunk; absorbing instead would cost (rank + rope + rank) / (nope + rope
   + v) = 3.4 times the attention's own operations for every query, which is
-  more from a chunk of ~170 tokens up.
+  more from a chunk of ~170 tokens up.  On a TPU the Pallas flash kernel
+  `latent_prefill_attn` walks the live blocks in place and keeps a group of
+  heads' scores and running statistics in VMEM; elsewhere (a CPU, a sharded
+  mesh) a `lax` loop gathers each block and leaves its scores to XLA, and
+  is the kernel's reference in the tests.
+
+Which path a program takes is decided where it is traced, by what the code
+can observe (`latent_decode_kernel_applies`, `latent_prefill_kernel_applies`:
+the backend, the mesh, the pool's dtype and tiles), and by no option.
 """
 from __future__ import annotations
 
@@ -113,14 +121,25 @@ def rope(x, positions, inv_freq, factor=1.0):
                            axis=-1).astype(x.dtype)
 
 
-def latent_decode_kernel_applies(pool, rank):
-    """Whether `latent_decode_attention` over ``pool``, traced here, is the
-    Pallas kernel: a one-device program on a TPU (or the interpreter)."""
+def _one_device_program():
     from ..parallel.mesh import get_mesh
 
     mesh = get_mesh()
-    return (mesh is None or mesh.size == 1) \
-        and latent_attention_mod.applies(pool, rank)
+    return mesh is None or mesh.size == 1
+
+
+def latent_decode_kernel_applies(pool, rank):
+    """Whether `latent_decode_attention` over ``pool``, traced here, is the
+    Pallas kernel: a one-device program on a TPU (or the interpreter)."""
+    return _one_device_program() and latent_attention_mod.applies(pool, rank)
+
+
+def latent_prefill_kernel_applies(pool, rank, c):
+    """Whether `latent_prefill_attention` of a chunk of ``c`` queries over
+    ``pool``, traced here, is the Pallas kernel: a one-device program on a
+    TPU (or the interpreter), a pool and a chunk of whole tiles."""
+    return _one_device_program() \
+        and latent_attention_mod.prefill_applies(pool, rank, c)
 
 
 def latent_decode_attention(q, pool, layer, block_tables, pos, rank, scale):
@@ -159,12 +178,20 @@ def latent_prefill_attention(q_nope, q_pe, pool, layer, block_tables, start,
     w_kvb:  (heads * (nope + v_dim), rank): a head's key part then its
             value part
     Returns (b, c, heads * v_dim) in q's dtype.  Every operation is under
-    the scope `mla_prefill_attention`; the loop operation around the steps
-    has `mla_prefill_loop` to itself (a device trace holds it as one event
+    the scope `mla_prefill_attention`.  Where
+    `latent_prefill_kernel_applies`, that is the Pallas kernel
+    `latent_prefill_attn`; elsewhere the `lax` loop below, the kernel's
+    reference in the tests, whose loop operation around the steps has
+    `mla_prefill_loop` to itself (a device trace holds it as one event
     around its steps' operations, which must not count twice).
     """
     scope = jax.named_scope("mla_prefill_attention")
     b, c, h, nope = q_nope.shape
+    if latent_prefill_kernel_applies(pool, rank, c):
+        with scope:
+            return latent_attention_mod.latent_prefill_attn(
+                q_nope, q_pe, pool, layer, block_tables, start, w_kvb,
+                rank=rank, v_dim=v_dim, scale=scale)
     bs = pool.shape[2]
     m = block_tables.shape[1]
     nb = max(1, min(PREFILL_BLOCK_TOKENS // bs, m))  # table entries a step

@@ -547,21 +547,27 @@ def test_latent_decode_reads_the_pool_through_its_kernel(on_tpu, one_chip,
 @pytest.mark.parametrize("chunk,layers", [(512, 2), (64, 4)])
 def test_latent_prefill_holds_no_table_wide_scores(on_tpu, one_chip, chunk,
                                                    layers):
-    """A chunk over a table of 16,384 positions: the blockwise loop's
-    temporaries are of one context block, far from the 2.1 GB that float32
-    scores of 512 x table width x heads would take (or the 0.7 GB of the
-    whole context expanded to per-head keys and values), and under one
-    layer of the pool.  The one-block chunk at four layers is the case in
-    which the TPU compiler once laid the whole pool out anew to suit a
-    whole-block scatter (a dynamic-update-slice to it) and copied it: the
-    chunk's rows are scattered row by row since (my chip run, PR 30)."""
+    """A chunk over a table of 16,384 positions at Kimi-K2.5's widths: one
+    `latent_prefill_attn` custom call a layer under `mla_prefill_attention`,
+    from one lowered function, and no loop around it; the kernel keeps a
+    step's scores in VMEM, so the program's temporaries are far from the
+    2.1 GB that float32 scores of 512 x table width x heads would take (or
+    the 0.7 GB of the whole context expanded to per-head keys and values),
+    and under one layer of the pool.  The one-block chunk at four layers is
+    the case in which the TPU compiler once laid the whole pool out anew to
+    suit a whole-block scatter (a dynamic-update-slice to it) and copied it:
+    the chunk's rows are scattered row by row since (my chip run, PR 30)."""
     text, memory, layer_bytes = _latent_program(one_chip, "prefill", chunk,
                                                 layers)
     assert "input_output_alias" in text.splitlines()[0]
     assert memory.alias_size_in_bytes == layers * layer_bytes
     assert memory.temp_size_in_bytes < layer_bytes
     names = _op_names(text, "serve_prefill_s%d" % chunk)
-    assert any(n.startswith("mla_prefill_loop/while") for n in names)
+    calls = [n for n in names if n.endswith("/pallas_call")]
+    assert set(calls) == {"mla_prefill_attention/jit(_latent_prefill)/"
+                          "latent_prefill_attn/pallas_call"}
+    assert text.count("tpu_custom_call") == layers
+    assert not any("mla_prefill_loop" in n for n in names)
     assert any("moe_loop/while/body/moe_experts/" in n for n in names)
 
 

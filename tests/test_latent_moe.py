@@ -251,6 +251,110 @@ def test_decode_kernel_matches_its_body(interpreted, monkeypatch, dtype,
     np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
 
 
+# -- (c') the prefill kernel, through the interpreter -------------------------
+
+
+@pytest.fixture
+def interpreted_prefill(monkeypatch):
+    """The prefill kernel's gate sees the interpreter; four blocks (32
+    positions) to a step of its walk and two heads to a group, so that a
+    table of 128 positions walks several steps and both buffer slots, and
+    four heads make two groups."""
+    monkeypatch.setattr(kernel, "_INTERPRET", True)
+    monkeypatch.setattr(kernel, "_PREFILL_STEP_TOKENS", 32)
+    monkeypatch.setattr(kernel, "_PREFILL_HEADS", 2)
+
+
+@pytest.mark.parametrize("dtype,c,starts", [
+    ("float32", 8, [0]),              # a one-block chunk, an empty prefix
+    ("bfloat16", 8, [0]),
+    ("float32", 64, [0]),             # several steps on the diagonal
+    ("bfloat16", 64, [64]),           # steps before the chunk, then on it
+    ("float32", 16, [40]),            # starts inside a step of the walk
+    ("bfloat16", 16, [40]),
+    ("float32", 32, [64]),            # starts on a step's edge
+    ("bfloat16", 32, [64]),
+    ("float32", 32, [96]),            # the table's last entry is live
+    ("bfloat16", 32, [96]),
+    ("float32", 16, [24, 112]),       # two rows, each its own prefix
+    ("bfloat16", 16, [24, 112]),
+], ids=lambda v: "-".join(map(str, v)) if isinstance(v, list) else str(v))
+def test_prefill_kernel_matches_the_loop(interpreted_prefill, monkeypatch,
+                                         dtype, c, starts):
+    """`latent_prefill_attn` through the interpreter against the `lax` loop
+    over the same pool.  Live rows only: the rows of a block past the
+    chunk's end and every block the chunk has not reached hold NaN, and
+    contribute nothing."""
+    dt = jnp.float32 if dtype == "float32" else jnp.bfloat16
+    rs = np.random.RandomState(13)
+    h, nope, rope, vd, rank, width = 4, 16, 8, 16, 128, 256
+    b, starts = len(starts), np.array(starts, np.int32)
+    tables = own_table(b)
+    pool = np.full((2, 1 + b * TABLE, BS, width), np.nan, np.float32)
+    for r in range(b):
+        n = starts[r] + c                       # positions written so far
+        live = tables[r, :-(-n // BS)]
+        rows = np.full((len(live) * BS, width), np.nan, np.float32)
+        rows[:n] = 0.0
+        rows[:n, :rank + rope] = rs.randn(n, rank + rope)
+        pool[1, live] = rows.reshape(len(live), BS, width)
+    pool[:, 0] = rs.randn(2, BS, width)              # the trash block
+    q_nope = jnp.asarray(rs.randn(b, c, h, nope), dt)
+    q_pe = jnp.asarray(rs.randn(b, c, h, rope), dt)
+    w_kvb = jnp.asarray(rs.randn(h * (nope + vd), rank) * 0.1, dt)
+    pool = jnp.asarray(pool, dt)
+    kw = dict(rank=rank, v_dim=vd, scale=0.11)
+    assert la.latent_prefill_kernel_applies(pool, rank, c)
+    got = jax.jit(lambda *a: la.latent_prefill_attention(
+        a[0], a[1], a[2], 1, a[3], a[4], a[5], **kw))(
+            q_nope, q_pe, pool, tables, starts, w_kvb)
+    assert got.shape == (b, c, h * vd) and got.dtype == dt
+    monkeypatch.setattr(kernel, "_INTERPRET", False)   # the loop
+    assert not la.latent_prefill_kernel_applies(pool, rank, c)
+    monkeypatch.setattr(la, "PREFILL_BLOCK_TOKENS", 48)
+    want = la.latent_prefill_attention(q_nope, q_pe, pool, 1, tables, starts,
+                                       w_kvb, **kw)
+    got, want = (np.asarray(x, np.float32) for x in (got, want))
+    assert np.isfinite(got).all() and np.isfinite(want).all()
+    # the same products, rounded at the same places, summed in steps of
+    # another size; in bfloat16 both round the result to 8 bits
+    tol = 2e-5 if dtype == "float32" else 1.6e-2
+    np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("why", ["one_device", "mesh_of_two", "ragged_pool",
+                                 "ragged_chunk", "int8_pool"])
+def test_the_prefill_kernels_gate(monkeypatch, why):
+    """The kernel is taken by what the code can observe: a TPU backend, a
+    one-device program, a float pool and a chunk of whole tiles."""
+    from mxnet_tpu.parallel.mesh import MeshContext
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    pool = jnp.zeros((2, 4, 16, 640), jnp.bfloat16)
+    assert not la.latent_prefill_kernel_applies(pool[0], 512, 64)
+    if why == "one_device":
+        assert la.latent_prefill_kernel_applies(pool, 512, 64)
+        with MeshContext(Mesh(np.array(jax.devices()[:1]), ("model",))):
+            assert la.latent_prefill_kernel_applies(pool, 512, 64)
+    elif why == "mesh_of_two":
+        with MeshContext(Mesh(np.array(jax.devices()[:2]), ("model",))):
+            assert not la.latent_prefill_kernel_applies(pool, 512, 64)
+    elif why == "ragged_pool":
+        # a width, a compression or a block that is not whole tiles
+        assert not la.latent_prefill_kernel_applies(pool[..., :576], 512, 64)
+        assert not la.latent_prefill_kernel_applies(pool, 448, 64)
+        assert not la.latent_prefill_kernel_applies(pool[:, :, :8], 512, 64)
+    elif why == "ragged_chunk":
+        assert not la.latent_prefill_kernel_applies(pool, 512, 8)
+        assert la.latent_prefill_kernel_applies(
+            pool.astype(jnp.float32), 512, 8)
+    else:
+        assert not la.latent_prefill_kernel_applies(
+            pool.astype(jnp.int8), 512, 64)
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert not la.latent_prefill_kernel_applies(pool, 512, 64)
+
+
 # -- (d) the shares add up ----------------------------------------------------
 
 

@@ -92,6 +92,41 @@ def test_latent_programs_carry_their_name_and_scopes(latent_engine, build,
     assert _scopes(text) >= LATENT_SCOPES | attention
 
 
+def test_latent_prefill_lowered_for_a_tpu_is_its_kernel(monkeypatch):
+    """Lowered for a TPU as a one-device program, `serve_prefill_s*` of the
+    latent model calls one lowered function a layer under
+    `mla_prefill_attention`, whose body is the kernel `latent_prefill_attn`,
+    and holds no loop: what `mla_prefill_attn_ms_per_chunk.kimi` and
+    `mla_prefill_roofline.kimi` read on the chip.  (The CPU programs above
+    keep the `lax` loop and both of its scopes.)"""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    layers, bs = 2, 8
+    model = LatentMoEKVModel(61, 64, layers, 32, 2, 12, 128, 8, 4, 8, 48, 16,
+                             8, (2, 6), 2, routed_scaling_factor=2.0)
+
+    def serve_prefill_s8(params, pool, tokens, start, length, tables):
+        return model.prefill_paged(params, pool, tokens, start, length,
+                                   tables)
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    params = {k: jax.ShapeDtypeStruct(v, jnp.float32)
+              for k, v in model.param_shapes().items()}
+    pool = jax.ShapeDtypeStruct((layers, 32, bs, model.pool_width),
+                                jnp.float32)
+    text = jax.jit(serve_prefill_s8).trace(
+        params, pool, ints(1, bs), ints(1), ints(1), ints(1, 8)).lower(
+            lowering_platforms=("tpu",)).as_text(debug_info=True)
+    names = set(re.findall(r'loc\("([^"]+)"', text))
+    assert "jit(serve_prefill_s8)/mla_prefill_attention/" \
+        "jit(_latent_prefill)" in names
+    assert "latent_prefill_attn/pallas_call" in names
+    assert len(re.findall(r"call @_latent_prefill\b", text)) == layers
+    assert text.count("tpu_custom_call") == 1          # one lowered function
+    assert not any("mla_prefill_loop" in n for n in names)
+
+
 def test_pool_programs_carry_their_names(engine):
     assert engine._compiled_cow().as_text().startswith(
         "HloModule jit_serve_cow,")
