@@ -132,8 +132,25 @@ class DotProductAttention(OpDef):
 register(DotProductAttention, aliases=("Attention",))
 
 
+def _kv_head_split(what, e, num_heads, kv_heads):
+    """(K/V heads, query heads to a K/V head, head size) of a cache row
+    ``e`` wide: grouped-query attention has ``num_heads`` query heads read
+    ``kv_heads`` cached ones, query head ``h`` reading K/V head
+    ``h // group``; ``kv_heads`` None is the one-to-one form."""
+    kv_heads = num_heads if kv_heads is None else int(kv_heads)
+    if kv_heads < 1 or num_heads % kv_heads != 0:
+        raise MXNetError("%s: num_heads %d is not a multiple of kv_heads %d"
+                         % (what, num_heads, kv_heads))
+    if e % kv_heads != 0:
+        raise MXNetError(
+            "%s: embed %d not divisible by num_heads %d"
+            % (what, e, kv_heads))
+    return kv_heads, num_heads // kv_heads, e // kv_heads
+
+
 @jax.named_scope("decode_attention")
-def decode_attention(q, k_cache, v_cache, pos, num_heads, *, scale=None):
+def decode_attention(q, k_cache, v_cache, pos, num_heads, *, scale=None,
+                     kv_heads=None):
     """Single-token attention over a per-sequence K/V cache (serving decode
     step).
 
@@ -150,7 +167,11 @@ def decode_attention(q, k_cache, v_cache, pos, num_heads, *, scale=None):
               row's own K/V must already be written at ``pos[b]`` (the
               query attends to itself and the past, matching the training
               kernels' causal mask at that position)
-    Returns (batch, embed).
+    kv_heads: cached heads, where fewer than ``num_heads`` (grouped-query
+              attention): the caches are ``kv_heads * head`` wide, q
+              ``num_heads * head``, and query head ``h`` reads K/V head
+              ``h // (num_heads // kv_heads)``
+    Returns (batch, embed) — q's width.
 
     Continuous batching gives every row its OWN position, so the validity
     mask is per-row (`j <= pos[b]`), not a shared triangle.  `jax.numpy`
@@ -160,15 +181,14 @@ def decode_attention(q, k_cache, v_cache, pos, num_heads, *, scale=None):
     the pools the kernel does not take (`paged_decode_attention`).
     """
     b, s, e = k_cache.shape
-    if e % num_heads != 0:
-        raise MXNetError(
-            "decode_attention: embed %d not divisible by num_heads %d"
-            % (e, num_heads))
-    hd = e // num_heads
+    kv_heads, group, hd = _kv_head_split("decode_attention", e, num_heads,
+                                         kv_heads)
     if scale is None:
         scale = 1.0 / float(hd) ** 0.5
-    qh = q.reshape(b, num_heads, hd)
-    kh = k_cache.reshape(b, s, num_heads, hd)
+    # a K/V head's ``group`` query heads side by side where they differ
+    qh = q.reshape((b, num_heads, hd) if group == 1
+                   else (b, kv_heads, group, hd))
+    kh = k_cache.reshape(b, s, kv_heads, hd)
     valid = (jnp.arange(s, dtype=jnp.int32)[None, :]
              <= pos.astype(jnp.int32)[:, None])  # (b, s)
     # never-attended rows (j > pos) hold stale garbage — zero their V
@@ -177,17 +197,19 @@ def decode_attention(q, k_cache, v_cache, pos, num_heads, *, scale=None):
     # let a stale quantization scale poison a fresh sequence; for
     # finite garbage this is bit-identical to the unguarded product)
     vh = jnp.where(valid[:, :, None, None],
-                   v_cache.reshape(b, s, num_heads, hd).astype(jnp.float32),
+                   v_cache.reshape(b, s, kv_heads, hd).astype(jnp.float32),
                    0.0)
     # scores (b, h, s) in f32: one row of the attention matrix per head
     scores = jnp.einsum(
-        "bhd,bshd->bhs", qh.astype(jnp.float32), kh.astype(jnp.float32),
+        "bhd,bshd->bhs" if group == 1 else "bkgd,bskd->bkgs",
+        qh.astype(jnp.float32), kh.astype(jnp.float32),
         preferred_element_type=jnp.float32) * scale
-    scores = jnp.where(valid[:, None, :], scores, -jnp.inf)
+    scores = jnp.where(valid[:, None, :] if group == 1
+                       else valid[:, None, None, :], scores, -jnp.inf)
     p = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bhs,bshd->bhd", p, vh,
-                     preferred_element_type=jnp.float32)
-    return out.reshape(b, e).astype(q.dtype)
+    out = jnp.einsum("bhs,bshd->bhd" if group == 1 else "bkgs,bskd->bkgd",
+                     p, vh, preferred_element_type=jnp.float32)
+    return out.reshape(q.shape).astype(q.dtype)
 
 
 @jax.named_scope("kv_gather")
@@ -232,9 +254,10 @@ def gather_paged_kv(pool, layer, which, block_tables):
     return ctx.reshape((b, m * pool.shape[3]) + pool.shape[4:])
 
 
-def paged_decode_kernel_applies(pool, num_heads):
+def paged_decode_kernel_applies(pool, num_heads, kv_heads=None):
     """Whether `paged_decode_attention` over ``pool`` (an unquantised
-    ``(layers, 2, n_blocks, block_size, embed)`` pool) runs as the Pallas
+    ``(layers, 2, n_blocks, block_size, embed)`` pool of ``kv_heads``
+    heads a row, ``num_heads`` where None) runs as the Pallas
     kernel, from what the code can see where the program is traced: the
     kernel's own conditions (`pallas_kernels.paged_attention.applies`: a
     TPU backend or the interpreter, f32 or bf16 blocks of whole tiles,
@@ -246,13 +269,16 @@ def paged_decode_kernel_applies(pool, num_heads):
 
     mesh = get_mesh()
     return (mesh is None or mesh.size == 1) \
-        and paged_attention_mod.applies(pool, num_heads)
+        and paged_attention_mod.applies(
+            pool, num_heads if kv_heads is None else kv_heads)
 
 
 def paged_decode_attention(q, pool, layer, block_tables, pos, num_heads,
-                           *, scale=None):
+                           *, scale=None, kv_heads=None):
     """`decode_attention` of every row over layer ``layer`` of a paged K/V
-    pool ``(layers, 2, n_blocks, block_size, embed)``.
+    pool ``(layers, 2, n_blocks, block_size, embed)``; with ``kv_heads``
+    fewer than ``num_heads`` the pool's rows are ``kv_heads`` heads wide and
+    q ``num_heads`` (grouped-query attention).
 
     Where `paged_decode_kernel_applies` holds, the Pallas kernel
     `paged_decode_attn` walks each row's live blocks in the pool's own
@@ -273,17 +299,20 @@ def paged_decode_attention(q, pool, layer, block_tables, pos, num_heads,
     That output is garbage by construction and is discarded in-graph
     (the scan emits the ``-2`` dead sentinel instead); it cannot
     contaminate live rows because every row's softmax is independent."""
-    if paged_decode_kernel_applies(pool, num_heads):
+    if paged_decode_kernel_applies(pool, num_heads, kv_heads):
         with jax.named_scope("decode_attention"):
             return paged_attention_mod.paged_decode_attn(
-                q, pool, layer, block_tables, pos, num_heads, scale=scale)
+                q, pool, layer, block_tables, pos, num_heads, scale=scale,
+                kv_heads=kv_heads)
     kc = gather_paged_kv(pool, layer, 0, block_tables)
     vc = gather_paged_kv(pool, layer, 1, block_tables)
-    return decode_attention(q, kc, vc, pos, num_heads, scale=scale)
+    return decode_attention(q, kc, vc, pos, num_heads, scale=scale,
+                            kv_heads=kv_heads)
 
 
 @jax.named_scope("chunk_attention")
-def chunk_attention(q, k_cache, v_cache, start, num_heads, *, scale=None):
+def chunk_attention(q, k_cache, v_cache, start, num_heads, *, scale=None,
+                    kv_heads=None):
     """Chunked-prefill attention: a c-token query chunk at absolute
     positions ``start .. start+c-1`` attends to the cached prefix plus
     itself (causal within the chunk).
@@ -299,19 +328,23 @@ def chunk_attention(q, k_cache, v_cache, start, num_heads, *, scale=None):
     k_cache:  (b, S, embed)   — keys, the chunk's own rows already written
     v_cache:  (b, S, embed)
     start:    (b,) int        — absolute position of each row's chunk
-    Returns (b, c, embed).  f32 softmax statistics like the siblings.
+    kv_heads: cached heads where fewer than ``num_heads`` (grouped-query
+              attention, as in `decode_attention`): the caches are then
+              ``kv_heads * head`` wide and q ``num_heads * head``
+    Returns (b, c, embed) — q's width.  f32 softmax statistics like the
+    siblings.
     """
     b, c, e = q.shape
     s = k_cache.shape[1]
-    if e % num_heads != 0:
-        raise MXNetError(
-            "chunk_attention: embed %d not divisible by num_heads %d"
-            % (e, num_heads))
-    hd = e // num_heads
+    kv_heads, group, hd = _kv_head_split("chunk_attention",
+                                         k_cache.shape[2], num_heads,
+                                         kv_heads)
     if scale is None:
         scale = 1.0 / float(hd) ** 0.5
-    qh = q.reshape(b, c, num_heads, hd)
-    kh = k_cache.reshape(b, s, num_heads, hd)
+    # a K/V head's ``group`` query heads side by side where they differ
+    qh = q.reshape((b, c, num_heads, hd) if group == 1
+                   else (b, c, kv_heads, group, hd))
+    kh = k_cache.reshape(b, s, kv_heads, hd)
     start = start.astype(jnp.int32)
     # rows past the chunk's own last position (j >= start+c) are stale
     # garbage no query attends: zero their V explicitly so a softmax-0
@@ -321,19 +354,22 @@ def chunk_attention(q, k_cache, v_cache, start, num_heads, *, scale=None):
     written = (jnp.arange(s, dtype=jnp.int32)[None, :]
                < (start + c)[:, None])                   # (b, s)
     vh = jnp.where(written[:, :, None, None],
-                   v_cache.reshape(b, s, num_heads, hd).astype(jnp.float32),
+                   v_cache.reshape(b, s, kv_heads, hd).astype(jnp.float32),
                    0.0)
     scores = jnp.einsum(
-        "bchd,bshd->bhcs", qh.astype(jnp.float32), kh.astype(jnp.float32),
+        "bchd,bshd->bhcs" if group == 1 else "bckgd,bskd->bkgcs",
+        qh.astype(jnp.float32), kh.astype(jnp.float32),
         preferred_element_type=jnp.float32) * scale
     # query i (absolute position start+i) sees cache rows j <= start+i
     qpos = start[:, None] + \
         jnp.arange(c, dtype=jnp.int32)[None, :]          # (b, c)
     valid = (jnp.arange(s, dtype=jnp.int32)[None, None, :]
              <= qpos[:, :, None])                        # (b, c, s)
-    scores = jnp.where(valid[:, None], scores, -jnp.inf)
+    scores = jnp.where(valid[:, None] if group == 1
+                       else valid[:, None, None], scores, -jnp.inf)
     p = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bhcs,bshd->bchd", p, vh,
+    out = jnp.einsum("bhcs,bshd->bchd" if group == 1
+                     else "bkgcs,bskd->bckgd", p, vh,
                      preferred_element_type=jnp.float32)
     return out.reshape(b, c, e).astype(q.dtype)
 

@@ -44,6 +44,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .lm_parts import rope_inv_freq
 from .pallas_kernels import latent_attention as latent_attention_mod
 
 _NEG = -1e30
@@ -68,7 +69,7 @@ def yarn_inv_freq(dim, theta, scaling=None):
     and that over ``factor`` (stretched: fewer than ``beta_slow`` turns) by
     the linear ramp between the two correction dims, as the published
     DeepSeek-V3 code computes it."""
-    extra = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    extra = rope_inv_freq(dim, theta)
     if not scaling or scaling.get("factor", 1) <= 1:
         return extra
     factor = float(scaling["factor"])
@@ -103,22 +104,6 @@ def rope_factor(scaling=None):
         return 1.0
     return yarn_mscale(scaling["factor"], scaling.get("mscale", 1)) \
         / yarn_mscale(scaling["factor"], scaling.get("mscale_all_dim", 0))
-
-
-def rope(x, positions, inv_freq, factor=1.0):
-    """Rotate the last axis of ``x`` (..., dim) by ``positions`` (the shape of
-    ``x`` without its last axis, or broadcastable to it).  Dim ``i`` pairs
-    with dim ``i + dim // 2`` (the half-split layout; the published
-    checkpoints store the pairs interleaved, which is this up to a fixed
-    permutation of the projection's columns)."""
-    half = x.shape[-1] // 2
-    ang = positions.astype(jnp.float32)[..., None] \
-        * jnp.asarray(inv_freq, jnp.float32)
-    cos, sin = jnp.cos(ang) * factor, jnp.sin(ang) * factor
-    x1 = x[..., :half].astype(jnp.float32)
-    x2 = x[..., half:].astype(jnp.float32)
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                           axis=-1).astype(x.dtype)
 
 
 def _one_device_program():

@@ -1,14 +1,15 @@
 """One chip's share of a sparse expert layer (serving).
 
-The layer of the DeepSeek-V3 block: a router over ALL ``n_routed`` experts
-(sigmoid scores, a per-expert selection bias that chooses but does not
-weigh, the ``top_k`` largest, weights renormalised and scaled), a shared
-expert every token passes through, and the routed experts' SwiGLU FFNs.  A
-wide expert-parallel deployment gives each chip a contiguous range of the
-routed experts; this module computes what ONE such chip adds:
+The layer of the DeepSeek-V3 block and of its relatives: a router over ALL
+``n_routed`` experts (sigmoid scores, a per-expert selection bias that
+chooses but does not weigh, the ``top_k`` largest, weights renormalised and
+scaled), the routed experts' SwiGLU FFNs and, where the model has one, a
+shared expert every token passes through.  A wide expert-parallel deployment
+gives each chip a contiguous range of the routed experts; this module
+computes what ONE such chip adds:
 
     y = sum over the token's chosen experts e with lo <= e < hi of
-        w_e * E_e(u)     +     E_shared(u)
+        w_e * E_e(u)     [+ E_shared(u)]
 
 and leaves out what the experts held elsewhere would add (their terms
 arrive by the deployment's exchange, which a one-chip program does not
@@ -60,20 +61,22 @@ def swiglu(x, w_gate, w_up, w_down):
 
 
 @jax.named_scope("moe_router")
-def route(u, w_router, bias, top_k, scale):
+def route(u, w_router, bias, top_k, scale, eps=1e-20):
     """(chosen experts (n, top_k) int32, their weights (n, top_k) float32).
 
     ``sc = sigmoid(u W_g)`` in float32, as the published gate computes it
     (`Precision.HIGHEST`: a near-tie must not flip between the program and
     its reference); the ``top_k`` largest of ``sc + bias`` are chosen,
     their weights are ``sc`` WITHOUT the bias, renormalised to sum to one
-    and multiplied by ``scale`` (`routed_scaling_factor`)."""
+    (``w / (sum(w) + eps)``: the published gates differ in ``eps`` alone,
+    1e-20 in DeepSeek-V3's and 1e-6 in LFM2's) and multiplied by ``scale``
+    (`routed_scaling_factor`)."""
     sc = jax.nn.sigmoid(jnp.dot(
         u.astype(jnp.float32), w_router.astype(jnp.float32).T,
         precision=lax.Precision.HIGHEST))
     _, idx = lax.top_k(sc + bias.astype(jnp.float32), top_k)
     w = jnp.take_along_axis(sc, idx, axis=1)
-    w = w / (jnp.sum(w, axis=1, keepdims=True) + 1e-20)
+    w = w / (jnp.sum(w, axis=1, keepdims=True) + eps)
     return idx.astype(jnp.int32), w * scale
 
 
@@ -142,17 +145,23 @@ def held_share(u, idx, w, w_gate, w_up, w_down, experts_held, valid=None):
     return acc[:n], counts
 
 
-def expert_layer(u, w_router, bias, experts, shared, *, top_k, scale,
-                 experts_held, valid=None):
+def expert_layer(u, w_router, bias, experts, shared=None, *, top_k, scale,
+                 experts_held, valid=None, eps=None):
     """This chip's share of the layer's output for rows ``u`` (n, d), in
     ``u``'s dtype, and the held experts' row counts.
 
     experts: (w_gate, w_up, w_down) banks of the held experts
-    shared:  (w_gate, w_up, w_down) of the shared expert, (out, in)
+    shared:  (w_gate, w_up, w_down) of the shared expert, (out, in), or
+             None for a layer that has none
+    eps:     `route`'s, in the weights' renormalisation (None: its own)
     """
-    idx, w = route(u, w_router, bias, top_k, scale)
+    idx, w = route(u, w_router, bias, top_k, scale) if eps is None \
+        else route(u, w_router, bias, top_k, scale, eps)
     routed, counts = held_share(u, idx, w, *experts,
                                 experts_held=experts_held, valid=valid)
+    if shared is None:
+        with jax.named_scope("moe_combine"):
+            return routed.astype(u.dtype), counts
     with jax.named_scope("moe_shared"):
         common = swiglu(u, *shared)
     with jax.named_scope("moe_combine"):

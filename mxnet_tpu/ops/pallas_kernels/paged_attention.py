@@ -27,6 +27,17 @@ lanes of head ``h`` and zeros elsewhere, so ``q_bd @ K^T`` is every head's
 score row in one matmul and ``p @ V`` masked by the same pattern is every
 head's output.
 
+Grouped-query attention (``kv_heads`` fewer than ``num_heads``: ``group``
+query heads read one cached head).  The pool's rows are ``kv_heads`` heads
+wide and the block-diagonal query has ``num_heads`` rows over them: row
+``j * kv_heads + k`` holds the query of head ``k * group + j`` in the lanes of
+K/V head ``k``, so the same two matmuls score and sum every query head
+against the cached head it reads.  Rows of one K/V head share lanes, so the
+rows cannot be folded into one in the kernel: the wrapper lays the query out
+(`_grouped_query`) and folds the ``(rows, embed)`` result back
+(`_ungroup`), both in XLA, over a few KB a row.  With ``kv_heads ==
+num_heads`` neither exists and the program is the one-to-one kernel's.
+
 Arithmetic.  bf16 x bf16 products are exact in float32 and the MXU sums them
 in float32, so with a bf16 pool K and V go to the matmuls as they are; the
 probabilities stay float32, split into three bf16 terms whose sum is the
@@ -110,12 +121,13 @@ def _split3(p):
 
 def _kernel(tables_ref, pos_ref, layer_ref, q_ref, pool_ref, o_ref,
             k_buf, v_buf, sem, acc_ref, m_ref, l_ref, slot_ref, *,
-            n_table, block_size, chunk_blocks, num_heads, scale):
+            n_table, block_size, chunk_blocks, num_heads, scale, group=1):
     r = pl.program_id(0)
     n_rows = pl.num_programs(0)
     layer = layer_ref[0]
     hp, e = acc_ref.shape
-    hd = e // num_heads
+    kv_heads = num_heads // group
+    hd = e // kv_heads
     t = chunk_blocks * block_size
     exact = k_buf.dtype == jnp.bfloat16 and q_ref.dtype == jnp.bfloat16
     copies = functools.partial(
@@ -151,12 +163,18 @@ def _kernel(tables_ref, pos_ref, layer_ref, q_ref, pool_ref, o_ref,
     # head h owns lanes [h * hd, (h + 1) * hd)
     lane = lax.broadcasted_iota(jnp.int32, (hp, e), 1)
     head = lax.broadcasted_iota(jnp.int32, (hp, e), 0) * hd
+    if group > 1:
+        # row j * kv_heads + k reads K/V head k
+        head = head % e
     own = (lane >= head) & (lane < head + hd)
-    # (selects are made in float32: a mask of 32-bit lanes does not lay out
-    # over packed bf16 rows)
-    q_bd = jnp.where(own, q_ref[0].astype(jnp.float32), 0.0)  # (hp, e)
-    if exact:
-        q_bd = q_bd.astype(jnp.bfloat16)
+    if group == 1:
+        # (selects are made in float32: a mask of 32-bit lanes does not lay
+        # out over packed bf16 rows)
+        q_bd = jnp.where(own, q_ref[0].astype(jnp.float32), 0.0)  # (hp, e)
+        if exact:
+            q_bd = q_bd.astype(jnp.bfloat16)
+    else:
+        q_bd = q_ref[0]               # laid out by `_grouped_query`
     precision = None if exact else lax.Precision.HIGHEST
 
     def body(i, _):
@@ -214,24 +232,54 @@ def _kernel(tables_ref, pos_ref, layer_ref, q_ref, pool_ref, o_ref,
 
     # each head's own lanes of its accumulator row, over its sum
     out = jnp.where(own, acc_ref[...] / l_ref[...], 0.0)
-    o_ref[0] = jnp.sum(out, axis=0, keepdims=True).astype(o_ref.dtype)
+    if group == 1:
+        o_ref[0] = jnp.sum(out, axis=0, keepdims=True).astype(o_ref.dtype)
+    else:
+        o_ref[0] = out.astype(o_ref.dtype)     # folded by `_ungroup`
+
+
+def _grouped_query(q, kv_heads, group, hd, hp):
+    """(b, num_heads * hd) queries as the kernel's block-diagonal rows
+    (b, hp, kv_heads * hd): row ``j * kv_heads + k`` holds head ``k * group
+    + j`` in the lanes of K/V head ``k``, zeros elsewhere and in the rows
+    past ``num_heads``."""
+    b = q.shape[0]
+    qg = q.reshape(b, kv_heads, group, hd).transpose(0, 2, 1, 3)
+    eye = jnp.eye(kv_heads, dtype=q.dtype)
+    rows = (qg[:, :, :, None, :] * eye[None, None, :, :, None]).reshape(
+        b, group * kv_heads, kv_heads * hd)
+    return jnp.pad(rows, ((0, 0), (0, hp - group * kv_heads), (0, 0)))
+
+
+def _ungroup(out, kv_heads, group, hd):
+    """The kernel's (b, hp, kv_heads * hd) rows, each zero outside its own
+    head's lanes, as (b, num_heads * hd) head-major."""
+    b = out.shape[0]
+    rows = out[:, :group * kv_heads].reshape(b, group, kv_heads,
+                                             kv_heads * hd)
+    # one row of a group's ``kv_heads`` is non-zero in a lane: an exact sum
+    own = jnp.sum(rows.astype(jnp.float32), axis=2).astype(out.dtype)
+    return own.reshape(b, group, kv_heads, hd).transpose(0, 2, 1, 3) \
+        .reshape(b, group * kv_heads * hd)
 
 
 def paged_decode_attn(q, pool, layer, block_tables, pos, num_heads, *,
-                      scale=None):
+                      scale=None, kv_heads=None):
     """Single-query attention of every row over its live blocks of layer
-    ``layer`` of the paged pool (`applies(pool, num_heads)` must hold).
+    ``layer`` of the paged pool (`applies(pool, kv_heads)` must hold;
+    ``kv_heads`` None is ``num_heads``).
 
-    q:            (b, embed)
-    pool:         (layers, 2, n_blocks, block_size, embed), read in place
+    q:            (b, num_heads * head)
+    pool:         (layers, 2, n_blocks, block_size, kv_heads * head), read
+                  in place
     layer:        int (static or traced)
     block_tables: (b, m) int32
     pos:          (b,) int32: the position the query occupies; its K/V row
                   is already in the pool
-    Returns (b, embed) in q's dtype, equal to
+    Returns q's shape and dtype, equal to
     `decode_attention(q, gather_paged_kv(pool, layer, 0, block_tables),
-    gather_paged_kv(pool, layer, 1, block_tables), pos, num_heads)` up to
-    the order of the float32 sums.
+    gather_paged_kv(pool, layer, 1, block_tables), pos, num_heads,
+    kv_heads=kv_heads)` up to the order of the float32 sums.
     """
     hd = q.shape[1] // num_heads
     if scale is None:
@@ -242,7 +290,9 @@ def paged_decode_attn(q, pool, layer, block_tables, pos, num_heads, *,
                          block_tables.astype(jnp.int32),
                          pos.astype(jnp.int32), num_heads=num_heads,
                          scale=float(scale), chunk_blocks=chunk_blocks,
-                         interpret=_INTERPRET)
+                         interpret=_INTERPRET,
+                         group=1 if kv_heads is None
+                         else num_heads // int(kv_heads))
 
 
 # A function jitted on its own: the layer is an operand, so a model's layers
@@ -250,20 +300,27 @@ def paged_decode_attn(q, pool, layer, block_tables, pos, num_heads, *,
 # (paid at every start, to look the program up in the compile cache) holds
 # the kernel once, not once a layer.
 @functools.partial(jax.jit, static_argnames=("num_heads", "scale",
-                                             "chunk_blocks", "interpret"))
+                                             "chunk_blocks", "interpret",
+                                             "group"))
 def _paged_decode(q, pool, layer, block_tables, pos, *, num_heads, scale,
-                  chunk_blocks, interpret):
-    b, e = q.shape
+                  chunk_blocks, interpret, group=1):
+    b = q.shape[0]
+    e = pool.shape[4]
     m = block_tables.shape[1]
     bs = pool.shape[3]
     t = chunk_blocks * bs
     # head rows padded to whole sublane tiles of the matmul operands
     hp = -(-num_heads // 16) * 16
-    row = pl.BlockSpec((1, 1, e), lambda r, *_: (r, 0, 0))
+    kv_heads = num_heads // group
+    # grouped: the query laid out as the kernel's rows, and as many rows out
+    rows = 1 if group == 1 else hp
+    q_rows = None if group == 1 else _grouped_query(q, kv_heads, group,
+                                                    e // kv_heads, hp)
+    row = pl.BlockSpec((1, rows, e), lambda r, *_: (r, 0, 0))
     out = pl.pallas_call(
         functools.partial(_kernel, n_table=m, block_size=bs,
                           chunk_blocks=chunk_blocks, num_heads=num_heads,
-                          scale=scale),
+                          scale=scale, group=group),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,       # tables, positions, the layer
             grid=(b,),
@@ -278,12 +335,14 @@ def _paged_decode(q, pool, layer, block_tables, pos, *, num_heads, scale,
                 pltpu.VMEM((hp, 1), jnp.float32),         # running sum
                 pltpu.SMEM((1,), jnp.int32),              # slot of next row
             ]),
-        out_shape=jax.ShapeDtypeStruct((b, 1, e), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, rows, e), q.dtype),
         # rows in order: each starts the next row's first copies
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_decode_attn",
-    )(block_tables.reshape(-1), pos, layer.reshape(1), q.reshape(b, 1, e),
-      pool)
-    return out.reshape(b, e)
+    )(block_tables.reshape(-1), pos, layer.reshape(1),
+      q.reshape(b, 1, e) if group == 1 else q_rows, pool)
+    if group == 1:
+        return out.reshape(b, e)
+    return _ungroup(out, kv_heads, group, e // kv_heads)

@@ -99,6 +99,7 @@ See docs/serving.md.
 from .autoscale import AutoScaler, autoscale_enabled
 from .decode import TransformerKVModel
 from .latent import LatentMoEKVModel
+from .shortconv import ShortConvMoEKVModel
 from .gateway import ServeGateway, gateway_enabled, http_status
 from .engine import ServeRequest, ServingEngine, ReplicaRouter
 from .handoff import HandoffTicket, disagg_enabled
@@ -113,7 +114,8 @@ from .errors import (ServeError, ServeTimeout, ServeOverload,
                      ServeCacheInvalidated, ServeEngineDead,
                      ServeQuantError)
 
-__all__ = ["TransformerKVModel", "LatentMoEKVModel", "ServeRequest", "ServingEngine",
+__all__ = ["TransformerKVModel", "LatentMoEKVModel", "ShortConvMoEKVModel",
+           "ServeRequest", "ServingEngine",
            "ReplicaRouter", "HandoffTicket", "disagg_enabled",
            "ServeGateway", "gateway_enabled", "http_status",
            "AutoScaler", "autoscale_enabled",

@@ -691,8 +691,17 @@ class ServingEngine:
             nb = (self.max_batch + 1) * self._n_table
         self.n_blocks = nb
         self._alloc = BlockAllocator(nb, bs)
-        self._cache = model.init_block_pool(nb, bs,
-                                            device=self._kv_device())
+        # per-sequence state that is not a run of token blocks
+        # (docs/serving.md "A third kind of state"): a model that has one
+        # (`state_slot_bytes`) gets a slot a batch row, and one more that
+        # padding rows write to; the slot IS the row, taken at admission
+        # and held until the sequence retires or is preempted.  The state
+        # rides with the pool as one donated value; the programs take the
+        # rows' slot indices beside their block tables.  Anyone else's
+        # programs are what they were.
+        self._state_slots = self.max_batch + 1 \
+            if getattr(model, "state_slot_bytes", None) else 0
+        self._cache = self._new_cache()
         # whether the decode programs attend with the paged Pallas
         # kernel: decided here, once, as their traces will (the pool,
         # the backend, this replica's mesh), for the `iteration`
@@ -710,6 +719,13 @@ class ServingEngine:
             if prefix_pool is None else prefix_pool)
         prefix_on = _env_flag("MXNET_SERVE_PREFIX") if prefix is None \
             else bool(prefix)
+        if "prefix" in getattr(model, "unsupported", ()):
+            # a prefix hit skips the chunks that would build the state a
+            # block does not hold: off unless asked for, refused if asked
+            if prefix is None and "MXNET_SERVE_PREFIX" not in os.environ:
+                prefix_on = False
+            elif prefix_on:
+                self._refuse("prefix", "prefix / MXNET_SERVE_PREFIX")
         # host-DRAM block tier (MXNET_SERVE_TIER, default OFF: =0 is
         # the PR-12 evict-and-recompute behavior bit-for-bit).  The
         # tier rides the prefix index — without it there is nothing
@@ -874,6 +890,33 @@ class ServingEngine:
                       "host_s": 0.0, "hidden_s": 0.0,
                       "fetch_wait_s": 0.0}
 
+    def _new_cache(self):
+        """The zeroed paged pool (also the rebuild's allocation), with the
+        per-sequence state beside it where the model has one."""
+        pool = self.model.init_block_pool(self.n_blocks, self.block_size,
+                                          device=self._kv_device())
+        if not self._state_slots:
+            return pool
+        return pool, self.model.init_state(self._state_slots,
+                                           device=self._device)
+
+    def _slots(self, rows, b):
+        """The trailing launch operand of a model with per-sequence state:
+        each row's slot, the spare slot for a bucket's padding rows.  ()
+        for everyone else."""
+        if not self._state_slots:
+            return ()
+        slots = np.full((b,), self._state_slots - 1, np.int32)
+        slots[:len(rows)] = rows
+        return (slots,)
+
+    def _split_state(self, rest):
+        """A program's operands after its block tables, as (the keyword its
+        model takes the slots by, the sampling arrays)."""
+        if not self._state_slots:
+            return {}, rest
+        return {"slots": rest[0]}, rest[1:]
+
     def _refuse(self, option, how):
         """Raise if the model lists ``option`` among those it cannot serve
         yet (`LatentMoEKVModel.unsupported`): by name, at construction,
@@ -885,6 +928,13 @@ class ServingEngine:
 
     # -- program building --------------------------------------------------
     _SAMPLE_NAMES = ("temp", "top_k", "top_p", "seed")
+    _PREFILL_NAMES = ("tokens", "start", "length", "tables")
+    _DECODE_NAMES = ("token", "pos", "tables")
+
+    def _tail_names(self, samp):
+        """Watchdog names of a launch's operands after its block tables."""
+        return ("slots",) * bool(self._state_slots) \
+            + self._SAMPLE_NAMES[:len(samp)]
 
     def _sample_placeholders(self, b):
         """Per-row sampling arrays for lowering/watch signatures — empty
@@ -929,9 +979,10 @@ class ServingEngine:
         def build():
             def prog(params, pool, tokens, start, length, tables, *samp):
                 tape = []
+                slots, samp = self._split_state(samp)
                 logits, pool = self.model.prefill_paged(
                     params, pool, tokens, start, length, tables,
-                    moe_tape=tape)
+                    moe_tape=tape, **slots)
                 return (self._pick(logits, samp, start + length),
                         pool) + self._moe_out(tape)
 
@@ -942,7 +993,8 @@ class ServingEngine:
             zero = self._put(np.zeros((1,), np.int32))
             one = self._put(np.ones((1,), np.int32))
             tables = self._put(np.zeros((1, self._n_table), np.int32))
-            samp = tuple(self._put(a) for a in self._sample_placeholders(1))
+            samp = tuple(self._put(a) for a in self._slots((), 1)
+                         + self._sample_placeholders(1))
             return fn.lower(self._params, self._cache, toks, zero,
                             one, tables, *samp).compile()
 
@@ -952,8 +1004,10 @@ class ServingEngine:
         def build():
             def prog(params, pool, token, pos, tables, *samp):
                 tape = []
+                slots, samp = self._split_state(samp)
                 logits, pool = self.model.decode_paged(
-                    params, pool, token, pos, tables, moe_tape=tape)
+                    params, pool, token, pos, tables, moe_tape=tape,
+                    **slots)
                 return (self._pick(logits, samp, pos + 1),
                         pool) + self._moe_out(tape)
 
@@ -964,7 +1018,8 @@ class ServingEngine:
             tables = self._put(np.zeros((b_bucket, self._n_table),
                                         np.int32))
             samp = tuple(self._put(a)
-                         for a in self._sample_placeholders(b_bucket))
+                         for a in self._slots((), b_bucket)
+                         + self._sample_placeholders(b_bucket))
             return fn.lower(self._params, self._cache, z, z, tables,
                             *samp).compile()
 
@@ -1311,16 +1366,15 @@ class ServingEngine:
         one = np.ones((1,), np.int32)
         samp = self._sample_placeholders(1)
         tables = np.zeros((1, self._n_table), np.int32)
-        return ((toks, one, one, tables) + samp,
-                ("tokens", "start", "length", "tables")
-                + self._SAMPLE_NAMES[:len(samp)])
+        return ((toks, one, one, tables) + self._slots((), 1) + samp,
+                self._PREFILL_NAMES + self._tail_names(samp))
 
     def _decode_watch_arrays(self, b):
         z = np.zeros((b,), np.int32)
         samp = self._sample_placeholders(b)
         tables = np.zeros((b, self._n_table), np.int32)
-        return ((z, z, tables) + samp,
-                ("token", "pos", "tables") + self._SAMPLE_NAMES[:len(samp)])
+        return ((z, z, tables) + self._slots((), b) + samp,
+                self._DECODE_NAMES + self._tail_names(samp))
 
     def _mega_watch_arrays(self, b):
         z = np.zeros((b,), np.int32)
@@ -2111,8 +2165,7 @@ class ServingEngine:
             self._tier.clear()
             telemetry.set_gauge(self._gauge + "host_blocks_used", 0)
         self._alloc.reset()
-        self._cache = self.model.init_block_pool(
-            self.n_blocks, self.block_size, device=self._kv_device())
+        self._cache = self._new_cache()
         if self._drafter is not None:
             self._drafter.on_cache_rebuild()
         self._block_gauges()
@@ -2708,10 +2761,12 @@ class ServingEngine:
             length_d = self._put(np.array([chunk], np.int32))
             table_d = self._put(table)
             samp = self._samp_device([req], 1)
+            tail = tuple(self._put(a)
+                         for a in self._slots((pf.row,), 1)) + samp
             self._watch("prefill",
-                        (toks_d, start_d, length_d, table_d) + samp,
-                        ("tokens", "start", "length", "tables")
-                        + self._SAMPLE_NAMES[:len(samp)], bucket)
+                        (toks_d, start_d, length_d, table_d) + tail,
+                        self._PREFILL_NAMES + self._tail_names(samp),
+                        bucket)
             compiled = self._compiled_prefill(bucket)
             if chaos.serve_launch_error():
                 raise chaos.ChaosError("chaos: injected prefill launch "
@@ -2723,7 +2778,7 @@ class ServingEngine:
         try:
             tok, self._cache = self._unpack(compiled(
                 self._params, self._cache, toks_d, start_d, length_d,
-                table_d, *samp))
+                table_d, *tail))
         except Exception as e:
             kind = self._classify_failure(e)
             if kind == "device":
@@ -2743,6 +2798,9 @@ class ServingEngine:
             # would otherwise cost accept rate on every token after them
             self._drafter.on_prefill_chunk(toks_d, start_d, length_d,
                                            table_d)
+        if self._state_slots and not pf.done:
+            # a sequence's first chunk starts its slot's state from nothing
+            self._count("state_resets")
         pf.done += chunk
         self.stats["prefill_chunks"] += 1
         self.stats["prefill_tokens"] += chunk  # the suffix-only witness
@@ -3265,6 +3323,11 @@ class ServingEngine:
             self._grow_active()
             self._iter["blocks_live"], self._iter["blocks_parked"] = \
                 self._block_gauges(full=True)
+            if self._state_slots:
+                # a slot is live from admission to retirement
+                live = self.max_batch - len(self._free)
+                self._iter["state_slots_live"] = live
+                telemetry.set_gauge(self._gauge + "state_slots_live", live)
 
     def _advance_staged(self):
         """Chunk dispatch.  Restores staged last iteration land BEFORE
@@ -3372,11 +3435,10 @@ class ServingEngine:
                 pos[i] = seq.pos
                 tables[i, :len(seq.blocks)] = seq.blocks
             samp = self._samp_device([s.req for s in seqs], b)
-            args = (self._put(token), self._put(pos),
-                    self._put(tables)) + samp
+            args = tuple(self._put(a) for a in (token, pos, tables)
+                         + self._slots(rows, b)) + samp
             self._watch("decode", args,
-                        ("token", "pos", "tables")
-                        + self._SAMPLE_NAMES[:len(samp)], b)
+                        self._DECODE_NAMES + self._tail_names(samp), b)
             compiled = self._compiled_decode(b)
         self._iter.update(
             rows=n, bucket=b, attn_kernel=self._attn_kernel,

@@ -41,23 +41,13 @@ import jax.numpy as jnp
 
 from ..base import MXNetError
 from ..ops import moe
+from ..ops.lm_parts import proj as _proj, rms_norm, rope
 from ..ops.latent_attention import (latent_decode_attention,
                                     latent_decode_kernel_applies,
-                                    latent_prefill_attention, rope,
+                                    latent_prefill_attention,
                                     rope_factor, softmax_scale,
                                     yarn_inv_freq)
 from .decode import TransformerKVModel
-
-
-def rms_norm(x, gamma, eps):
-    xf = x.astype(jnp.float32)
-    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    return (y * gamma.astype(jnp.float32)).astype(x.dtype)
-
-
-def _proj(x, w):
-    """``x @ w.T`` for a weight stored (out, in), float32 sums."""
-    return jnp.dot(x, w.T, preferred_element_type=jnp.float32).astype(x.dtype)
 
 
 class LatentMoEKVModel:

@@ -656,3 +656,100 @@ def test_abstract_trainer_refuses_lower_without_abstract():
                                   "softmax_label": (2, 64)})
     with pytest.raises(MXNetError, match="abstract"):
         tr.lower_step()
+
+
+# -- the grouped-query pool and the conv state at the served widths ----------
+
+
+def _shortconv_program(one_chip, what, n, kinds, n_blocks=4609, bs=64,
+                       slots=65):
+    """`serve_decode_b<n>` or `serve_prefill_s<n>` of the LFM2-MoE block at
+    its published widths over ``kinds`` layers (the first two dense),
+    compiled for the described chip with (pool, state) donated: (its text,
+    its memory analysis, the model)."""
+    from mxnet_tpu.base import bfloat16
+    from mxnet_tpu.serving import ShortConvMoEKVModel
+
+    model = ShortConvMoEKVModel(65536, 4608, kinds, 2048, 32, 8, 3, 7168,
+                                1792, 32, 4, num_dense_layers=2,
+                                dtype=bfloat16)
+    params = {k: _bf16(v, one_chip) for k, v in model.param_shapes().items()}
+    cache = (_bf16((model.attn_layers, 2, n_blocks, bs, model.kv_width),
+                   one_chip),
+             _bf16((model.conv_layers, slots, 2, model.hidden), one_chip))
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    if what == "decode":
+        def prog(params, cache, token, pos, tables, slots):
+            logits, cache = model.decode_paged(params, cache, token, pos,
+                                               tables, slots=slots)
+            return jnp.argmax(logits, axis=-1), cache
+
+        args = (ints(n), ints(n), ints(n, model.seq_len // bs), ints(n))
+    else:
+        def prog(params, cache, tokens, start, length, tables, slots):
+            logits, cache = model.prefill_paged(params, cache, tokens, start,
+                                                length, tables, slots=slots)
+            return jnp.argmax(logits, axis=-1), cache
+
+        args = (ints(1, n), ints(1), ints(1), ints(1, model.seq_len // bs),
+                ints(1))
+    prog.__name__ = "serve_%s_%s%d" % (what, "b" if what == "decode" else "s",
+                                       n)
+    comp = jax.jit(prog, donate_argnums=(1,)).lower(params, cache,
+                                                    *args).compile()
+    return comp.as_text(), comp.memory_analysis(), model
+
+
+KINDS = ["conv", "conv", "full_attention", "conv", "conv", "conv",
+         "full_attention", "conv"]
+
+
+@pytest.mark.parametrize("batch", [1, 64])
+def test_grouped_query_decode_reads_the_pool_through_the_kernel(
+        on_tpu, one_chip, monkeypatch, batch):
+    """A decode launch over the 8-head pool under 32 query heads, at the
+    served widths: one `paged_decode_attn` custom call an attention layer
+    under `decode_attention`, from one lowered function; pool and state
+    donated and updated in place; no temporary that grows with the pool or
+    with rows x table width x 512 (the gathered context the `jax.numpy` body
+    would make)."""
+    from mxnet_tpu.ops.pallas_kernels import paged_attention
+
+    monkeypatch.setattr(paged_attention, "_INTERPRET", False)
+    program = "serve_decode_b%d" % batch
+    text, memory, model = _shortconv_program(one_chip, "decode", batch,
+                                             KINDS)
+    assert model.paged_decode_kernel(
+        (jax.ShapeDtypeStruct((2, 2, 4609, 64, 512), jnp.bfloat16), None))
+    calls = [n for n in _op_names(text, program)
+             if n.endswith("/pallas_call")]
+    assert set(calls) == {"decode_attention/jit(_paged_decode)/"
+                          "paged_decode_attn/pallas_call"}
+    assert text.count("tpu_custom_call") == model.attn_layers == 2
+    assert "input_output_alias" in text.splitlines()[0]
+    pool_bytes = 2 * 2 * 4609 * 64 * 512 * 2
+    state_bytes = 6 * 65 * 2 * 2048 * 2
+    assert memory.alias_size_in_bytes == pool_bytes + state_bytes
+    gathered = batch * 4608 * 512 * 2
+    assert memory.temp_size_in_bytes < min(pool_bytes // 2, 64 * gathered)
+    scopes = {part for n in _op_names(text, program)
+              for part in n.split("/")}
+    assert {"short_conv", "conv_state", "qk_norm", "rope", "moe_experts",
+            "kv_scatter"} <= scopes
+
+
+def test_a_chunk_over_the_grouped_query_pool_copies_no_pool(on_tpu,
+                                                            one_chip):
+    """A 512-token chunk at the served widths: the chunk's K and V rows are
+    scattered by (block, offset) and the pool is donated, so the program's
+    temporaries are the float32 scores of 512 x the table's width x 32
+    heads and their like (0.3 GB a layer, not all layers at once), never a
+    copy of the pool (1.2 GB a layer here)."""
+    text, memory, model = _shortconv_program(one_chip, "prefill", 512, KINDS)
+    assert "input_output_alias" in text.splitlines()[0]
+    layer_pool = 2 * 4609 * 64 * 512 * 2
+    assert memory.alias_size_in_bytes >= 2 * layer_pool
+    assert memory.temp_size_in_bytes < 0.6 * layer_pool

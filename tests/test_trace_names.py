@@ -18,7 +18,7 @@ import mxnet_tpu as mx
 from mxnet_tpu import models
 from mxnet_tpu.parallel import SPMDTrainer, make_mesh
 from mxnet_tpu.serving import (LatentMoEKVModel, ServingEngine,
-                               TransformerKVModel)
+                               ShortConvMoEKVModel, TransformerKVModel)
 
 SERVING_SCOPES = {"embed", "qkv_proj", "kv_scatter", "kv_gather", "attn_out",
                   "ffn", "lm_head", "sampler"}
@@ -90,6 +90,68 @@ def test_latent_programs_carry_their_name_and_scopes(latent_engine, build,
     text = getattr(latent_engine, build)(bucket).as_text()
     assert text.startswith("HloModule %s," % module)
     assert _scopes(text) >= LATENT_SCOPES | attention
+
+
+SHORTCONV_SCOPES = {"embed", "short_conv", "conv_state", "qkv_proj",
+                    "qk_norm", "rope", "kv_scatter", "attn_out", "ffn",
+                    "moe_router", "moe_dispatch", "moe_experts",
+                    "moe_combine", "moe_loop", "lm_head", "sampler"}
+
+
+@pytest.fixture(scope="module")
+def shortconv_engine():
+    model = ShortConvMoEKVModel(
+        61, 32, ["conv", "full_attention", "conv"], 32, 4, 2, 3, 48, 16, 8,
+        2, num_dense_layers=1)
+    return ServingEngine(model, model.init_params(np.random.RandomState(3)),
+                         max_batch=4, block_size=4, n_blocks=32,
+                         prefill_buckets=[8], decode_buckets=[2],
+                         name="shortconv_names")
+
+
+@pytest.mark.parametrize("build,module,attention", [
+    ("_compiled_decode", "jit_serve_decode_b2",
+     {"decode_attention", "kv_gather"}),
+    ("_compiled_prefill", "jit_serve_prefill_s8",
+     {"chunk_attention", "kv_gather"}),
+])
+def test_shortconv_programs_carry_their_name_and_scopes(shortconv_engine,
+                                                        build, module,
+                                                        attention):
+    """The third model class under the engine's own program names: what the
+    `.lfm2` metrics' readers look for (`benchmark/metrics/*.lfm2.json`).  No
+    shared expert, so no `moe_shared`."""
+    bucket = 8 if build == "_compiled_prefill" else 2
+    text = getattr(shortconv_engine, build)(bucket).as_text()
+    assert text.startswith("HloModule %s," % module)
+    scopes = _scopes(text)
+    assert scopes >= SHORTCONV_SCOPES | attention
+    assert "moe_shared" not in scopes
+
+
+def test_the_state_seams_span_attribute_and_counters_are_named(
+        shortconv_engine):
+    """`state_slots_live` on the `iteration` record and as a gauge, and the
+    `state_resets` counter: what `state_slots_live_peak_share.lfm2` reads."""
+    import time
+
+    from mxnet_tpu import telemetry, tracing
+
+    reg = telemetry.registry()
+    before = reg.counter("serve.shortconv_names.state_resets").value
+    t0 = time.perf_counter()
+    req = shortconv_engine.submit([5, 6, 7, 8, 9], max_new_tokens=3)
+    shortconv_engine.run_until_idle(timeout=120)
+    assert len(req.result(timeout=5)) == 3
+    records = [r["attrs"] for r in tracing.window(
+        "shortconv_names", t0, time.perf_counter())
+        if r["phase"] == "iteration"]
+    assert records and all(a["state_slots_live"] == 1 for a in records)
+    assert all({"expert_rows", "expert_hits", "expert_load_max"} <= set(a)
+               for a in records)
+    assert reg.counter("serve.shortconv_names.state_resets").value \
+        == before + 1
+    assert reg.gauge("serve.shortconv_names.state_slots_live").value == 1
 
 
 def test_latent_prefill_lowered_for_a_tpu_is_its_kernel(monkeypatch):
@@ -330,6 +392,26 @@ def test_paged_decode_attention_is_named_either_way(for_tpu, pool_dtype,
         assert _kernels(text) == set()
         assert 'kv_gather/gather"' in text
         assert 'decode_attention/' in text
+
+
+def test_grouped_query_decode_is_the_paged_kernel_and_no_gather(for_tpu):
+    """`gqa_decode_attn_ms_per_launch.lfm2` reads the scope
+    `decode_attention`: over a pool of fewer K/V heads the same kernel runs
+    under it, with the query's layout and the result's fold beside it in the
+    function the layers share, and nothing gathers the table's width."""
+    from mxnet_tpu.ops.attention import paged_decode_attention
+
+    text = for_tpu(
+        lambda q, pool, tables, pos: paged_decode_attention(
+            q, pool, 1, tables, pos, 8, kv_heads=2),
+        jax.ShapeDtypeStruct((4, 512), jnp.bfloat16),
+        jax.ShapeDtypeStruct((2, 2, 16, 32, 128), jnp.bfloat16),
+        jax.ShapeDtypeStruct((4, 8), jnp.int32),
+        jax.ShapeDtypeStruct((4,), jnp.int32))
+    assert _kernels(text) == {"paged_decode_attn"}
+    assert '"paged_decode_attn/pallas_call"' in text
+    assert 'decode_attention/jit(_paged_decode)"' in text
+    assert "kv_gather" not in text
 
 
 def test_latent_decode_kernel_is_named_in_its_lowered_call(for_tpu):

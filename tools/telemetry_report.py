@@ -42,13 +42,14 @@ SERVE_COUNTERS = ("serve.requests", "serve.completed", "serve.tokens",
                   "serve.greedy_requests", "serve.sampled_requests",
                   "serve.prefix_hits", "serve.prefix_bootstraps",
                   "serve.prefix_tokens", "serve.cow_copies",
-                  "serve.prefix_evictions")
+                  "serve.prefix_evictions", "serve.state_resets")
 # per-replica paged-cache gauges (serve.<name>.blocks_free/_frag plus the
-# prefix-sharing set blocks_shared/_parked and prefix_hit_rate): the
-# final value seen in the stream is the replica's end-of-run state
+# prefix-sharing set blocks_shared/_parked and prefix_hit_rate, and the
+# per-sequence state's state_slots_live): the final value seen in the
+# stream is the replica's end-of-run state
 SERVE_BLOCK_GAUGE_SUFFIXES = (".blocks_free", ".blocks_frag",
                               ".blocks_shared", ".blocks_parked",
-                              ".prefix_hit_rate")
+                              ".prefix_hit_rate", ".state_slots_live")
 
 # serving resilience accounting (docs/serving.md "Failure semantics"):
 # the SLO/failover counters + the failover/respawn event kinds
