@@ -21,8 +21,9 @@ without inserting a collective until the output projection's
 row-sharded matmul reduces.  Keep it that way — no op in this file may
 mix embed positions across the head boundary (e.g. a transpose to
 `(hd, h)` order), or sub-mesh serving silently gains all-to-alls.
-(`paged_decode_attention` runs as a Pallas kernel only in a program built
-for one device; a sharded replica's decode keeps the gather + einsum form.)
+(`paged_decode_attention` and `paged_chunk_attention` run as Pallas kernels
+only in a program built for one device; a sharded replica keeps the gather +
+einsum form.)
 """
 from __future__ import annotations
 
@@ -308,6 +309,50 @@ def paged_decode_attention(q, pool, layer, block_tables, pos, num_heads,
     vc = gather_paged_kv(pool, layer, 1, block_tables)
     return decode_attention(q, kc, vc, pos, num_heads, scale=scale,
                             kv_heads=kv_heads)
+
+
+def paged_chunk_kernel_applies(pool, num_heads, kv_heads, c, n_table):
+    """Whether `paged_chunk_attention` of a chunk of ``c`` queries over
+    tables of ``n_table`` entries of ``pool`` (an unquantised ``(layers, 2,
+    n_blocks, block_size, embed)`` pool of ``kv_heads`` heads a row,
+    ``num_heads`` where None) runs as the Pallas kernel, from what the code
+    can see where the program is traced: the kernel's own conditions
+    (`pallas_kernels.paged_attention.prefill_applies`: those of
+    `paged_decode_kernel_applies`, a chunk of whole sublane tiles, and a
+    table whose K and V fit the kernel's VMEM) and a program built for one
+    device."""
+    from ..parallel.mesh import get_mesh
+
+    mesh = get_mesh()
+    return (mesh is None or mesh.size == 1) \
+        and paged_attention_mod.prefill_applies(
+            pool, num_heads if kv_heads is None else kv_heads, c, n_table)
+
+
+def paged_chunk_attention(q, pool, layer, block_tables, start, num_heads, *,
+                          scale=None, kv_heads=None):
+    """`chunk_attention` of a chunk of queries a row over layer ``layer`` of
+    a paged K/V pool ``(layers, 2, n_blocks, block_size, embed)`` whose
+    blocks already hold the chunk's own rows; ``kv_heads`` as in
+    `paged_decode_attention`.
+
+    Where `paged_chunk_kernel_applies` holds, the Pallas kernel
+    `paged_prefill_attn` walks each row's live blocks in the pool's own
+    dtype with the pool left whole in HBM, under the scope
+    `chunk_attention`.  Everywhere else (the CPU backend, a sharded engine,
+    widths the kernel does not take) the `jax.numpy` body runs: the table's
+    blocks gathered (`gather_paged_kv`), then `chunk_attention` over the
+    table-wide context, the kernel's reference in the tests."""
+    if paged_chunk_kernel_applies(pool, num_heads, kv_heads, q.shape[1],
+                                  block_tables.shape[1]):
+        with jax.named_scope("chunk_attention"):
+            return paged_attention_mod.paged_prefill_attn(
+                q, pool, layer, block_tables, start, num_heads, scale=scale,
+                kv_heads=kv_heads)
+    kc = gather_paged_kv(pool, layer, 0, block_tables)
+    vc = gather_paged_kv(pool, layer, 1, block_tables)
+    return chunk_attention(q, kc, vc, start, num_heads, scale=scale,
+                           kv_heads=kv_heads)
 
 
 @jax.named_scope("chunk_attention")

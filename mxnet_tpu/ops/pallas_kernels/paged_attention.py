@@ -1,4 +1,6 @@
-"""Paged decode attention (Pallas TPU kernel).
+"""Paged attention over the K/V-pair pool (Pallas TPU kernels): the decode
+step `paged_decode_attn` and, further down, the prefill chunk
+`paged_prefill_attn`.
 
 One generated token per row attends to that row's context in the serving
 engine's paged K/V pool.  The `jax.numpy` form (`ops.attention`:
@@ -53,6 +55,7 @@ output is discarded by the caller.
 from __future__ import annotations
 
 import functools
+import math
 import os
 
 import jax
@@ -109,6 +112,16 @@ def _chunk_copies(tables_ref, pos_ref, pool_ref, k_buf, v_buf, sem, layer,
     return out
 
 
+def _each_live_copy(copies, do, row, chunk, slot):
+    """``do(copy)`` for the K and the V copy of every block of ``copies(row,
+    chunk, slot)`` that the row has reached."""
+    for live, ck, cv in copies(row, chunk, slot):
+        @pl.when(live)
+        def _():
+            do(ck)
+            do(cv)
+
+
 def _split3(p):
     """float32 ``p`` as three bf16 terms, stacked on the row axis, whose
     float32 sum is ``p``."""
@@ -135,15 +148,10 @@ def _kernel(tables_ref, pos_ref, layer_ref, q_ref, pool_ref, o_ref,
         layer, n_table=n_table, block_size=block_size,
         chunk_blocks=chunk_blocks)
 
-    def each_live_copy(do, row, chunk, slot):
-        for live, ck, cv in copies(row, chunk, slot):
-            @pl.when(live)
-            def _():
-                do(ck)
-                do(cv)
-
-    start = functools.partial(each_live_copy, lambda copy: copy.start())
-    wait = functools.partial(each_live_copy, lambda copy: copy.wait())
+    start = functools.partial(_each_live_copy, copies,
+                              lambda copy: copy.start())
+    wait = functools.partial(_each_live_copy, copies,
+                             lambda copy: copy.wait())
 
     @pl.when(r == 0)
     def _():
@@ -346,3 +354,271 @@ def _paged_decode(q, pool, layer, block_tables, pos, *, num_heads, scale,
     if group == 1:
         return out.reshape(b, e)
     return _ungroup(out, kv_heads, group, e // kv_heads)
+
+
+# -- the prefill chunk --------------------------------------------------------
+#
+# A chunk of ``c`` queries a row attends to the row's cached prefix and to
+# itself, causally; its own K and V rows are already in the pool.  The
+# `jax.numpy` form (`ops.attention`: `gather_paged_kv` + `chunk_attention`)
+# gathers the table's every entry, promotes it to float32 and scores every
+# query against the table's whole width, whatever the prompt's length.  This
+# kernel walks entries ``0 .. min((start + c - 1) // block_size, m - 1)``
+# only, ``_PREFILL_STEP_TOKENS`` tokens a step, in the pool's own dtype.
+#
+# Grid (row, 128-lane tile of the pool's rows).  A tile holds ``128 // hd``
+# K/V heads, read by the ``group`` query heads each whose lanes the query's
+# block of the same index holds (head-major layouts on both sides).  The
+# row's live context is copied in once, whole blocks, by the row's first tile
+# (`_chunk_copies`: a step's copies fly while the step before is attended)
+# and stays in VMEM for the others, so the pool is read once a row however
+# many tiles it has.  A query head is laid in the lanes of the K/V head it
+# reads (rolled by half a tile where they differ), zeros elsewhere, so that
+# its scores are one matmul with the tile's keys and its values one with the
+# tile's values, of which it keeps its head's lanes.
+#
+# Arithmetic, the training flash kernels' and `latent_prefill_attn`'s:
+# scores, the softmax statistics and the accumulator are float32; a bf16
+# pool's K and V go to the MXU as they are, and the probabilities are rounded
+# to bf16 for the product with V.  A float32 pool takes float32 matmuls at
+# `Precision.HIGHEST`.  Positions past the chunk's last row score -inf and
+# their V rows are zeroed in the buffer, so a stale block (NaN included)
+# contributes nothing, as in `chunk_attention`.
+
+# tokens of the context attended in one step of a tile's walk, and queries
+# a block of a step: a block whose last query comes before the step's first
+# position is skipped, a quarter of the diagonal of a chunk of 512 (on the
+# chip 256 and 256 took 0.039 ms a GPT-2 layer for a chunk of 512 at
+# position 0, 128 and 128 took 0.054; PERF.md, PR 35)
+_PREFILL_STEP_TOKENS = 256
+_PREFILL_QUERY_ROWS = 256
+
+# what a row's live K and V context may take of VMEM: it is kept whole
+_PREFILL_CONTEXT_BYTES = 24 * 1024 * 1024
+
+# what the context, a tile's scratch and a step's scores may take of VMEM
+# (the default scoped limit is 16 MiB of the chip's 128)
+_PREFILL_VMEM_BYTES = 64 * 1024 * 1024
+
+
+def _prefill_step_blocks(block_size, n_table):
+    return max(1, min(_PREFILL_STEP_TOKENS // block_size, n_table))
+
+
+def prefill_applies(pool, kv_heads, c, n_table):
+    """Whether `paged_prefill_attn` can attend a chunk of ``c`` queries over
+    tables of ``n_table`` entries of ``pool`` here: what `applies` asks of
+    the pool, a chunk of whole sublane tiles, and a table whose K and V fit
+    the kernel's share of VMEM."""
+    if not applies(pool, kv_heads):
+        return False
+    bs, e = pool.shape[3:]
+    step = _prefill_step_blocks(bs, n_table)
+    rows = -(-n_table // step) * step * bs
+    return (c % (32 // pool.dtype.itemsize) == 0
+            and 2 * rows * e * pool.dtype.itemsize <= _PREFILL_CONTEXT_BYTES)
+
+
+def _lanes(x, src, dst, hd, lane):
+    """The ``hd`` lanes of ``x`` (float32, 128 wide) from ``src`` moved to
+    ``dst``, zeros in the others."""
+    if src != dst:
+        x = pltpu.roll(x, (dst - src) % 128, 1)
+    return jnp.where((lane >= dst) & (lane < dst + hd), x, 0.0)
+
+
+def _prefill_kernel(tables_ref, last_ref, start_ref, layer_ref, q_ref,
+                    pool_ref, o_ref, k_buf, v_buf, sem, q_bd, acc_ref, m_ref,
+                    l_ref, *, n_table, block_size, step_blocks, query_rows,
+                    hd, group, scale):
+    r, g = pl.program_id(0), pl.program_id(1)
+    c, qw = q_ref.shape[1:]
+    n_q = qw // hd                        # query heads of the tile
+    t = step_blocks * block_size
+    exact = k_buf.dtype == jnp.bfloat16 and q_ref.dtype == jnp.bfloat16
+    dot = functools.partial(
+        lax.dot_general, preferred_element_type=jnp.float32,
+        precision=None if exact else lax.Precision.HIGHEST)
+    copies = functools.partial(
+        _chunk_copies, tables_ref, last_ref, pool_ref, k_buf, v_buf, sem,
+        layer_ref[0], n_table=n_table, block_size=block_size,
+        chunk_blocks=step_blocks)
+    # step ``i``'s blocks go to the buffers' rows ``i`` under semaphores ``i``
+    start = functools.partial(_each_live_copy, copies,
+                              lambda copy: copy.start(), r)
+    wait = functools.partial(_each_live_copy, copies,
+                             lambda copy: copy.wait(), r)
+
+    first = start_ref[r]                 # the chunk's first position
+    last = last_ref[r]                   # its last, inside the table
+    n_steps = last // t + 1
+
+    @pl.when(g == 0)
+    def _():
+        start(0, 0)
+
+    # a power of two scales the query exactly; any other scales the scores
+    folded = scale == 2.0 ** round(math.log2(scale))
+    lane = lax.broadcasted_iota(jnp.int32, (c, 128), 1)
+    for j in range(n_q):
+        # (selects are made in float32: a mask of 32-bit lanes does not lay
+        # out over packed bf16 rows)
+        lo = j * hd // 128 * 128
+        x = q_ref[0, :, lo:lo + 128].astype(jnp.float32)
+        if folded:
+            x = x * scale
+        q_bd[j] = _lanes(x, j * hd % 128, j // group * hd, hd,
+                         lane).astype(q_bd.dtype)
+    m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    tile = pl.ds(pl.multiple_of(g * 128, 128), 128)
+
+    def step(i, _):
+        @pl.when(g == 0)
+        def _():
+            @pl.when(i + 1 < n_steps)
+            def _():
+                start(i + 1, i + 1)
+
+            wait(i, i)
+
+            @pl.when(i + 1 == n_steps)
+            def _():
+                # past the chunk's end the buffer holds a block's unwritten
+                # tail or an earlier row's context: exact zeros, so that a
+                # probability of 0 multiplies no garbage (0 * NaN)
+                j = i * t + lax.broadcasted_iota(jnp.int32, v_buf.shape[1:],
+                                                 0)
+                v_buf[i] = jnp.where(j <= last,
+                                     v_buf[i].astype(jnp.float32),
+                                     0.0).astype(v_buf.dtype)
+
+        k = k_buf[i, :, tile]
+        v = v_buf[i, :, tile]
+        if not exact:
+            k, v = k.astype(jnp.float32), v.astype(jnp.float32)
+        qb = query_rows
+        for a in range(c // qb):
+            rows = slice(a * qb, (a + 1) * qb)
+
+            # the block's last query sees the step's first row
+            @pl.when(i * t <= first + (a + 1) * qb - 1)
+            def _():
+                # query ``x`` of the block (position first + a * qb + x)
+                # sees the step's row ``y`` (position i * t + y) where
+                # y <= first + a * qb - i * t + x, and never past the
+                # table's last position (a short last chunk's bucket may
+                # reach beyond it)
+                limit = jnp.minimum(
+                    first + a * qb - i * t
+                    + lax.broadcasted_iota(jnp.int32, (qb, 1), 0),
+                    last - i * t)
+                seen = lax.broadcasted_iota(jnp.int32, (qb, t), 1) <= limit
+                for j in range(n_q):
+                    s = dot(q_bd[j, rows], k, (((1,), (1,)), ((), ())))
+                    if not folded:
+                        s = s * scale
+                    s = jnp.where(seen, s, -jnp.inf)             # (qb, t)
+                    m_prev = m_ref[j, rows]
+                    m_new = jnp.maximum(m_prev,
+                                        jnp.max(s, axis=1, keepdims=True))
+                    alpha = jnp.exp(m_prev - m_new)
+                    # (the statistics are kept in all 128 lanes: as a
+                    # (rows, 1) column they cost a relayout at every use,
+                    # 40 % of a step on the chip; PERF.md, PR 35)
+                    m_t = m_new[:, :1] if t % 128 else pltpu.repeat(
+                        m_new, t // 128, 1)
+                    p = jnp.exp(s - m_t)         # exactly 0 where unseen
+                    l_ref[j, rows] = alpha * l_ref[j, rows] + jnp.sum(
+                        p, axis=1, keepdims=True)
+                    acc_ref[j, rows] = alpha * acc_ref[j, rows] + dot(
+                        p.astype(v.dtype), v, (((1,), (0,)), ((), ())))
+                    m_ref[j, rows] = m_new
+
+        return ()
+
+    lax.fori_loop(0, n_steps, step, ())
+    # each head's lanes of its accumulator, over its sum, back where the
+    # query's layout has them
+    for u in range(qw // 128):
+        out = jnp.zeros((c, 128), jnp.float32)
+        for j in range(u * 128 // hd, (u + 1) * 128 // hd):
+            out = out + _lanes(acc_ref[j] / l_ref[j], j // group * hd,
+                               j * hd % 128, hd, lane)
+        o_ref[0, :, u * 128:(u + 1) * 128] = out.astype(o_ref.dtype)
+
+
+def paged_prefill_attn(q, pool, layer, block_tables, start, num_heads, *,
+                       scale=None, kv_heads=None):
+    """Causal attention of a chunk of queries a row over the row's live
+    blocks of layer ``layer`` of the paged pool (`prefill_applies(pool,
+    kv_heads, c, m)` must hold; ``kv_heads`` None is ``num_heads``).
+
+    q:            (b, c, num_heads * head)
+    pool:         (layers, 2, n_blocks, block_size, kv_heads * head), read
+                  in place; the chunk's own rows are already written
+    layer:        int (static or traced)
+    block_tables: (b, m) int32
+    start:        (b,) int32: the chunk's first absolute position
+    Returns q's shape and dtype, equal to `chunk_attention(q,
+    gather_paged_kv(pool, layer, 0, block_tables), gather_paged_kv(pool,
+    layer, 1, block_tables), start, num_heads, kv_heads=kv_heads)` up to the
+    order of the float32 sums and the probabilities' rounding.
+    """
+    kv_heads = num_heads if kv_heads is None else int(kv_heads)
+    hd = pool.shape[4] // kv_heads
+    if scale is None:
+        scale = 1.0 / float(hd) ** 0.5
+    return _paged_prefill(
+        q, pool, jnp.asarray(layer, jnp.int32),
+        block_tables.astype(jnp.int32), start.astype(jnp.int32),
+        hd=hd, group=num_heads // kv_heads, scale=float(scale),
+        step_blocks=_prefill_step_blocks(pool.shape[3],
+                                         block_tables.shape[1]),
+        query_rows=math.gcd(q.shape[1], _PREFILL_QUERY_ROWS),
+        interpret=_INTERPRET)
+
+
+# A function jitted on its own, the layer an operand (`_paged_decode`).
+@functools.partial(jax.jit, static_argnames=("hd", "group", "scale",
+                                             "step_blocks", "query_rows",
+                                             "interpret"))
+def _paged_prefill(q, pool, layer, block_tables, start, *, hd, group, scale,
+                   step_blocks, query_rows, interpret):
+    b, c, _ = q.shape
+    m, bs, e = block_tables.shape[1], pool.shape[3], pool.shape[4]
+    t = step_blocks * bs
+    steps = -(-m // step_blocks)
+    qw = 128 * group                     # a tile's query lanes
+    n_q = qw // hd
+    # the last position each row's chunk wrote, inside the table
+    last = jnp.minimum(start + c, m * bs) - 1
+    block = pl.BlockSpec((1, c, qw), lambda r, g, *_: (r, 0, g))
+    return pl.pallas_call(
+        functools.partial(_prefill_kernel, n_table=m, block_size=bs,
+                          step_blocks=step_blocks, query_rows=query_rows,
+                          hd=hd, group=group, scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,       # tables, last, start, the layer
+            grid=(b, e // 128),
+            in_specs=[block, pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=block,
+            scratch_shapes=[
+                pltpu.VMEM((steps, t, e), pool.dtype),    # the row's K
+                pltpu.VMEM((steps, t, e), pool.dtype),    # and V
+                pltpu.SemaphoreType.DMA((steps, 2)),      # [step, K | V]
+                pltpu.VMEM((n_q, c, 128), q.dtype),       # laid-out queries
+                pltpu.VMEM((n_q, c, 128), jnp.float32),   # accumulators
+                pltpu.VMEM((n_q, c, 128), jnp.float32),   # running maxima
+                pltpu.VMEM((n_q, c, 128), jnp.float32),   # running sums
+            ]),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        # a row's tiles in order: the first copies the context the others
+        # read
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_PREFILL_VMEM_BYTES),
+        interpret=interpret,
+        name="paged_prefill_attn",
+    )(block_tables.reshape(-1), last, start, layer.reshape(1), q, pool)

@@ -66,7 +66,8 @@ from jax.sharding import NamedSharding, PartitionSpec
 from ..base import MXNetError
 from ..ops.attention import (gather_paged_kv, paged_decode_attention,
                              paged_decode_kernel_applies, decode_attention,
-                             chunk_attention, verify_attention)
+                             chunk_attention, paged_chunk_attention,
+                             paged_chunk_kernel_applies, verify_attention)
 from ..ops.pallas_kernels.layer_norm import layer_norm
 from ..quant.codec import quantize, quantize_rows, resolve as quant_resolve
 
@@ -428,6 +429,15 @@ class TransformerKVModel:
         return scales is None and paged_decode_kernel_applies(
             pool, self.num_heads)
 
+    def paged_prefill_kernel(self, cache, c):
+        """Whether `prefill_paged` of a chunk of ``c`` tokens over
+        ``cache``, traced here, attends with the Pallas kernel
+        (`ops.attention.paged_chunk_attention`).  The int8 pool never
+        does."""
+        pool, scales = self._pool_parts(cache)
+        return scales is None and paged_chunk_kernel_applies(
+            pool, self.num_heads, None, c, -(-self.seq_len // pool.shape[3]))
+
     @jax.named_scope("kv_scatter")
     def _scatter_kv(self, pool, scales, layer, at, k, v):
         """Write one layer's new K and V rows into the pool at ``at`` (a
@@ -575,9 +585,10 @@ class TransformerKVModel:
         A short prompt is the degenerate single chunk (start 0), so one
         compiled program per chunk bucket serves both the single-shot
         and the streaming case — chunked prefill adds no shapes.
-        Attention runs `chunk_attention` over the gathered context
+        Attention is `paged_chunk_attention` over the row's blocks
         (cached prefix + the chunk itself), which is exactly the
-        training causal mask once start=0.
+        training causal mask once start=0; the int8 pool's rows are
+        gathered and dequantized for `chunk_attention` (`_gather_ctx`).
         """
         pool, scales = self._pool_parts(pool)
         b, c = tokens.shape
@@ -615,9 +626,12 @@ class TransformerKVModel:
             kw = k.reshape(b, nb, bs, e)
             vw = v.reshape(b, nb, bs, e)
             pool, scales = self._scatter_kv(pool, scales, i, (blk,), kw, vw)
-            kc = self._gather_ctx(pool, scales, i, 0, tables)  # (b,m*bs,e)
-            vc = self._gather_ctx(pool, scales, i, 1, tables)
-            attn = chunk_attention(q, kc, vc, start, h)
+            if scales is None:
+                attn = paged_chunk_attention(q, pool, i, tables, start, h)
+            else:
+                kc = self._gather_ctx(pool, scales, i, 0, tables)
+                vc = self._gather_ctx(pool, scales, i, 1, tables)
+                attn = chunk_attention(q, kc, vc, start, h)
             x = x + self._attn_out(params, attn.reshape(-1, e),
                                    p).reshape(b, c, e)
             hn = layer_norm(x, params[p + "ln2_gamma"],

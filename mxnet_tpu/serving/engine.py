@@ -709,6 +709,11 @@ class ServingEngine:
         self._attn_kernel = int(self._scoped(
             lambda: model.paged_decode_kernel(self._cache),
             "attn_kernel")())
+        # and which prefill programs attend with theirs, a bucket at a
+        # time, for the `prefill_chunk` span's
+        self._prefill_kernel = {s: int(self._scoped(
+            lambda s=s: model.paged_prefill_kernel(self._cache, s),
+            "attn_kernel")()) for s in self.prefill_buckets}
         self._prefilling = {}  # row -> _Prefill (insertion-ordered)
         # cross-request prefix sharing (MXNET_SERVE_PREFIX=0 restores
         # single-owner paging bit-for-bit; MXNET_SERVE_PREFIX_POOL
@@ -2807,7 +2812,8 @@ class ServingEngine:
         telemetry.inc("serve.prefill_chunks")
         tracing.add_span(req.id, "prefill_chunk", self.name, t_chunk,
                          time.perf_counter(), start=pf.done - chunk,
-                         tokens=chunk)
+                         tokens=chunk,
+                         attn_kernel=self._prefill_kernel[bucket])
         # publish the chunk's newly-FULL blocks (a block whose bucket
         # tail is padding garbage stays private: `done` counts only real
         # tokens, so it rounds down past any partially-written block)

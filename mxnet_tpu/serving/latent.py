@@ -45,6 +45,7 @@ from ..ops.lm_parts import proj as _proj, rms_norm, rope
 from ..ops.latent_attention import (latent_decode_attention,
                                     latent_decode_kernel_applies,
                                     latent_prefill_attention,
+                                    latent_prefill_kernel_applies,
                                     rope_factor, softmax_scale,
                                     yarn_inv_freq)
 from .decode import TransformerKVModel
@@ -220,6 +221,12 @@ class LatentMoEKVModel:
         """Whether `decode_paged` over ``cache``, traced here, attends with
         the Pallas kernel `latent_decode_attn`."""
         return latent_decode_kernel_applies(cache, self.kv_rank)
+
+    def paged_prefill_kernel(self, cache, c):
+        """Whether `prefill_paged` of a chunk of ``c`` tokens over
+        ``cache``, traced here, attends with the Pallas kernel
+        `latent_prefill_attn`."""
+        return latent_prefill_kernel_applies(cache, self.kv_rank, c)
 
     # -- the block's parts -------------------------------------------------
     @jax.named_scope("embed")

@@ -50,7 +50,8 @@ import jax.numpy as jnp
 
 from ..base import MXNetError
 from ..ops import moe
-from ..ops.attention import (chunk_attention, gather_paged_kv,
+from ..ops.attention import (paged_chunk_attention,
+                             paged_chunk_kernel_applies,
                              paged_decode_attention,
                              paged_decode_kernel_applies)
 from ..ops.lm_parts import proj, rms_norm, rope, rope_inv_freq
@@ -225,6 +226,15 @@ class ShortConvMoEKVModel:
         return paged_decode_kernel_applies(cache[0], self.num_heads,
                                            self.kv_heads)
 
+    def paged_prefill_kernel(self, cache, c):
+        """Whether `prefill_paged` of a chunk of ``c`` tokens over
+        ``cache``, traced here, attends with the Pallas kernel
+        `paged_prefill_attn`."""
+        pool = cache[0]
+        return paged_chunk_kernel_applies(
+            pool, self.num_heads, self.kv_heads, c,
+            -(-self.seq_len // pool.shape[3]))
+
     # -- the block's parts -------------------------------------------------
     @jax.named_scope("embed")
     def _embed(self, params, tokens):
@@ -346,10 +356,8 @@ class ShortConvMoEKVModel:
                 q, k, v = self._qkv(params, p, u.reshape(b * c, -1),
                                     positions)
                 pool = self._scatter_kv(pool, ai, blk, off, k, v)
-                attn = chunk_attention(
-                    q.reshape(b, c, -1),
-                    gather_paged_kv(pool, ai, 0, tables),
-                    gather_paged_kv(pool, ai, 1, tables), start,
+                attn = paged_chunk_attention(
+                    q.reshape(b, c, -1), pool, ai, tables, start,
                     self.num_heads, kv_heads=self.kv_heads)
                 op = self._attn_out(params, p, attn)
                 ai += 1

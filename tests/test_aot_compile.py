@@ -395,12 +395,13 @@ def test_decode_pool_is_read_as_the_tpu_compiler_names_it(on_tpu, one_chip,
     "int8_prefill_s512", "sharded_decode_b8"])
 def test_jnp_paths_read_the_table_from_the_pool_in_place(on_tpu, topo,
                                                          one_chip, case):
-    """Every program that reads a row's context with `gather_paged_kv` (the
-    serving cell's six prefill chunks, the speculative verify launch, the
-    int8 pool's decode and prefill, the tensor-sharded engine's decode), at
-    2 layers and 3,600 blocks: the pool (and the int8 pool's scales) is
-    donated and aliased in and out, the temporaries are under one layer's K
-    pool, and nothing of that size is made but by the in-place `kv_scatter`.
+    """Every program that reads a row's context from the pool (the serving
+    cell's six prefill chunks, through `paged_prefill_attn` since PR 35; the
+    speculative verify launch, the int8 pool's decode and prefill and the
+    tensor-sharded engine's decode, with `gather_paged_kv`), at 2 layers and
+    3,600 blocks: the pool (and the int8 pool's scales) is donated and
+    aliased in and out, the temporaries are under one layer's K pool, and
+    nothing of that size is made but by the in-place `kv_scatter`.
 
     Until PR 31 each of them sliced the layer's K and V pools out before the
     gather, and the TPU compiler made the slices: 2 x 147 MB of temporaries
@@ -425,8 +426,11 @@ def test_jnp_paths_read_the_table_from_the_pool_in_place(on_tpu, topo,
         aliased = 2 * layers * k_pool_bytes
         if kv_quant is not None:    # a float32 scale a row of 1,280 int8
             aliased += aliased // 1280 * 4
-        assert any("kv_gather" in n
-                   for n in _op_names(text, "serve_" + program))
+        names = _op_names(text, "serve_" + program)
+        kernel = what == "prefill" and kv_quant is None
+        assert any(("paged_prefill_attn" if kernel else "kv_gather") in n
+                   for n in names)
+        assert kernel != any("kv_gather" in n for n in names)
     assert "input_output_alias" in text.splitlines()[0]
     # (at least: the compiler pads the scales' and the shards' tiles)
     assert memory.alias_size_in_bytes >= aliased
@@ -741,13 +745,54 @@ def test_grouped_query_decode_reads_the_pool_through_the_kernel(
             "kv_scatter"} <= scopes
 
 
+def _f32_scores(text, c, width):
+    """The float32 values of the program whose shape holds both the chunk's
+    ``c`` and the table's ``width``: scores of the chunk against the whole
+    table."""
+    return [d for d in re.findall(r"f32\[([\d,]+)\]", text)
+            if {c, width} <= {int(x) for x in d.split(",")}]
+
+
+@pytest.mark.parametrize("model", ["gpt2-large", "lfm2"])
+def test_prefill_chunk_attends_through_the_paged_kernel(on_tpu, one_chip,
+                                                        monkeypatch, model):
+    """`serve_prefill_s512` at the two K/V-pair cells' widths (GPT-2 large's
+    20 heads of 64 over tables of 1,024 positions; LFM2's 32 query heads
+    over 8 K/V heads, tables of 4,608): one `paged_prefill_attn` custom call
+    an attention layer under `chunk_attention`, from one lowered function;
+    nothing gathers the table (`kv_gather`), and no value holds float32
+    scores of the chunk against the table's width (what `chunk_attention`
+    made: 42 MB a layer for GPT-2, 302 MB for LFM2)."""
+    from mxnet_tpu.ops.pallas_kernels import paged_attention
+
+    monkeypatch.setattr(paged_attention, "_INTERPRET", False)
+    if model == "lfm2":
+        text, memory, m = _shortconv_program(one_chip, "prefill", 512, KINDS)
+        layers, width, scores = m.attn_layers, 4608, 512 * 4608 * 32 * 4
+        assert m.paged_prefill_kernel(
+            (jax.ShapeDtypeStruct((2, 2, 4609, 64, 512), jnp.bfloat16),
+             None), 512)
+    else:
+        text, memory, _ = _serving_program(one_chip, "prefill", 512, 3600, 2)
+        layers, width, scores = 2, 1024, 512 * 1024 * 20 * 4
+    names = _op_names(text, "serve_prefill_s512")
+    calls = [n for n in names if n.endswith("/pallas_call")
+             and "paged_prefill_attn" in n]
+    assert calls == ["chunk_attention/jit(_paged_prefill)/"
+                     "paged_prefill_attn/pallas_call"] * layers
+    assert not any("kv_gather" in n for n in names)
+    assert "input_output_alias" in text.splitlines()[0]
+    assert not _f32_scores(text, 512, width)
+    assert memory.temp_size_in_bytes < scores
+
+
 def test_a_chunk_over_the_grouped_query_pool_copies_no_pool(on_tpu,
                                                             one_chip):
     """A 512-token chunk at the served widths: the chunk's K and V rows are
     scattered by (block, offset) and the pool is donated, so the program's
-    temporaries are the float32 scores of 512 x the table's width x 32
-    heads and their like (0.3 GB a layer, not all layers at once), never a
-    copy of the pool (1.2 GB a layer here)."""
+    temporaries are never a copy of the pool (1.2 GB a layer here).  (Until
+    PR 35 they held the float32 scores of 512 x the table's width x 32
+    heads, 0.3 GB a layer.)"""
     text, memory, model = _shortconv_program(one_chip, "prefill", 512, KINDS)
     assert "input_output_alias" in text.splitlines()[0]
     layer_pool = 2 * 4609 * 64 * 512 * 2

@@ -394,6 +394,38 @@ def test_paged_decode_attention_is_named_either_way(for_tpu, pool_dtype,
         assert 'decode_attention/' in text
 
 
+@pytest.mark.parametrize("pool_dtype,heads,kernel", [
+    (jnp.bfloat16, 2, True),    # GPT-2's layout: a K/V head a query head
+    (jnp.bfloat16, 8, True),    # LFM2's: 8 query heads over 2 K/V heads
+    (jnp.int8, 2, False),       # not the kernel's: the `jax.numpy` body
+])
+def test_paged_prefill_attention_is_named_either_way(for_tpu, pool_dtype,
+                                                     heads, kernel):
+    """`prefill_attn_ms_per_chunk`, `gqa_prefill_attn_ms_per_chunk.lfm2` and
+    their rooflines read the scopes `chunk_attention` and `kv_gather`: on a
+    TPU the prefill kernel runs under the first with a name of its own, and
+    a pool it does not take keeps both."""
+    from mxnet_tpu.ops.attention import paged_chunk_attention
+
+    text = for_tpu(
+        lambda q, pool, tables, start: paged_chunk_attention(
+            q, pool, 1, tables, start, heads,
+            kv_heads=None if heads == 2 else 2),
+        jax.ShapeDtypeStruct((1, 64, heads * 64), jnp.bfloat16),
+        jax.ShapeDtypeStruct((2, 2, 16, 32, 128), pool_dtype),
+        jax.ShapeDtypeStruct((1, 8), jnp.int32),
+        jax.ShapeDtypeStruct((1,), jnp.int32))
+    if kernel:
+        assert _kernels(text) == {"paged_prefill_attn"}
+        assert '"paged_prefill_attn/pallas_call"' in text
+        assert 'chunk_attention/jit(_paged_prefill)"' in text
+        assert "kv_gather" not in text
+    else:
+        assert _kernels(text) == set()
+        assert 'kv_gather/gather"' in text
+        assert 'chunk_attention/' in text
+
+
 def test_grouped_query_decode_is_the_paged_kernel_and_no_gather(for_tpu):
     """`gqa_decode_attn_ms_per_launch.lfm2` reads the scope
     `decode_attention`: over a pool of fewer K/V heads the same kernel runs
