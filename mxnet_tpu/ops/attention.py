@@ -29,6 +29,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from ..base import MXNetError
 from .registry import OpDef, Param, register
@@ -172,7 +173,8 @@ def decode_attention(q, k_cache, v_cache, pos, num_heads, *, scale=None,
               attention): the caches are ``kv_heads * head`` wide, q
               ``num_heads * head``, and query head ``h`` reads K/V head
               ``h // (num_heads // kv_heads)``
-    Returns (batch, embed) — q's width.
+    Returns (batch, embed) — q's width; ``num_heads * v_head`` where the V
+    cache's heads are narrower than K's (``kv_heads * v_head`` wide).
 
     Continuous batching gives every row its OWN position, so the validity
     mask is per-row (`j <= pos[b]`), not a shared triangle.  `jax.numpy`
@@ -184,6 +186,7 @@ def decode_attention(q, k_cache, v_cache, pos, num_heads, *, scale=None,
     b, s, e = k_cache.shape
     kv_heads, group, hd = _kv_head_split("decode_attention", e, num_heads,
                                          kv_heads)
+    vd = v_cache.shape[2] // kv_heads
     if scale is None:
         scale = 1.0 / float(hd) ** 0.5
     # a K/V head's ``group`` query heads side by side where they differ
@@ -198,7 +201,7 @@ def decode_attention(q, k_cache, v_cache, pos, num_heads, *, scale=None,
     # let a stale quantization scale poison a fresh sequence; for
     # finite garbage this is bit-identical to the unguarded product)
     vh = jnp.where(valid[:, :, None, None],
-                   v_cache.reshape(b, s, kv_heads, hd).astype(jnp.float32),
+                   v_cache.reshape(b, s, kv_heads, vd).astype(jnp.float32),
                    0.0)
     # scores (b, h, s) in f32: one row of the attention matrix per head
     scores = jnp.einsum(
@@ -210,7 +213,7 @@ def decode_attention(q, k_cache, v_cache, pos, num_heads, *, scale=None,
     p = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bhs,bshd->bhd" if group == 1 else "bkgs,bskd->bkgd",
                      p, vh, preferred_element_type=jnp.float32)
-    return out.reshape(q.shape).astype(q.dtype)
+    return out.reshape(b, num_heads * vd).astype(q.dtype)
 
 
 @jax.named_scope("kv_gather")
@@ -258,11 +261,14 @@ def gather_paged_kv(pool, layer, which, block_tables):
 def paged_decode_kernel_applies(pool, num_heads, kv_heads=None):
     """Whether `paged_decode_attention` over ``pool`` (an unquantised
     ``(layers, 2, n_blocks, block_size, embed)`` pool of ``kv_heads``
-    heads a row, ``num_heads`` where None) runs as the Pallas
-    kernel, from what the code can see where the program is traced: the
-    kernel's own conditions (`pallas_kernels.paged_attention.applies`: a
-    TPU backend or the interpreter, f32 or bf16 blocks of whole tiles,
-    heads of 64 or 128) and a program built for one device.  A
+    heads a row, ``num_heads`` where None; or the pair of K's and V's
+    arrays where their heads differ in width,
+    `pallas_kernels.paged_attention.pair`) runs as the Pallas kernel, from
+    what the code can see where the program is traced: the kernel's own
+    conditions (`pallas_kernels.paged_attention.applies`: a TPU backend or
+    the interpreter, f32 or bf16 blocks of whole tiles, a q/k and a v head
+    width it takes: V heads of 64 or 128, K heads as wide or, beside
+    narrower V heads, 192) and a program built for one device.  A
     tensor-sharded engine's program is partitioned by GSPMD, which refuses
     Mosaic kernels (`pallas_kernels/_spmd.py`); it keeps the `jax.numpy`
     body."""
@@ -305,8 +311,9 @@ def paged_decode_attention(q, pool, layer, block_tables, pos, num_heads,
             return paged_attention_mod.paged_decode_attn(
                 q, pool, layer, block_tables, pos, num_heads, scale=scale,
                 kv_heads=kv_heads)
-    kc = gather_paged_kv(pool, layer, 0, block_tables)
-    vc = gather_paged_kv(pool, layer, 1, block_tables)
+    k_pool, v_pool, v_at = paged_attention_mod.pair(pool)
+    kc = gather_paged_kv(k_pool, layer, 0, block_tables)
+    vc = gather_paged_kv(v_pool, layer, v_at, block_tables)
     return decode_attention(q, kc, vc, pos, num_heads, scale=scale,
                             kv_heads=kv_heads)
 
@@ -318,9 +325,11 @@ def paged_chunk_kernel_applies(pool, num_heads, kv_heads, c, n_table):
     ``num_heads`` where None) runs as the Pallas kernel, from what the code
     can see where the program is traced: the kernel's own conditions
     (`pallas_kernels.paged_attention.prefill_applies`: those of
-    `paged_decode_kernel_applies`, a chunk of whole sublane tiles, and a
-    table whose K and V fit the kernel's VMEM) and a program built for one
-    device."""
+    `paged_decode_kernel_applies` with a q/k and a v head alike, 64 or 128
+    wide, in one array, a chunk of whole sublane tiles, and a table whose K
+    and V fit the kernel's VMEM) and a program built for one device.  A pair
+    of K and V arrays of their own widths is never the kernel's: its chunks
+    are walked by `paged_chunk_stream`."""
     from ..parallel.mesh import get_mesh
 
     mesh = get_mesh()
@@ -339,20 +348,101 @@ def paged_chunk_attention(q, pool, layer, block_tables, start, num_heads, *,
     Where `paged_chunk_kernel_applies` holds, the Pallas kernel
     `paged_prefill_attn` walks each row's live blocks in the pool's own
     dtype with the pool left whole in HBM, under the scope
-    `chunk_attention`.  Everywhere else (the CPU backend, a sharded engine,
-    widths the kernel does not take) the `jax.numpy` body runs: the table's
-    blocks gathered (`gather_paged_kv`), then `chunk_attention` over the
-    table-wide context, the kernel's reference in the tests."""
+    `chunk_attention`.  A pair of K and V arrays of their own widths
+    (`pallas_kernels.paged_attention.pair`) is walked by
+    `paged_chunk_stream`, a step of the table at a time.  Everywhere else
+    (the CPU backend, a sharded engine, widths the kernel does not take) the
+    `jax.numpy` body runs: the table's blocks gathered (`gather_paged_kv`),
+    then `chunk_attention` over the table-wide context, the kernel's
+    reference in the tests."""
     if paged_chunk_kernel_applies(pool, num_heads, kv_heads, q.shape[1],
                                   block_tables.shape[1]):
         with jax.named_scope("chunk_attention"):
             return paged_attention_mod.paged_prefill_attn(
                 q, pool, layer, block_tables, start, num_heads, scale=scale,
                 kv_heads=kv_heads)
+    if isinstance(pool, (tuple, list)):
+        return paged_chunk_stream(q, pool, layer, block_tables, start,
+                                  num_heads, scale=scale, kv_heads=kv_heads)
     kc = gather_paged_kv(pool, layer, 0, block_tables)
     vc = gather_paged_kv(pool, layer, 1, block_tables)
     return chunk_attention(q, kc, vc, start, num_heads, scale=scale,
                            kv_heads=kv_heads)
+
+
+# positions of the table one step of `paged_chunk_stream` attends
+_STREAM_STEP_TOKENS = 1024
+
+
+@jax.named_scope("chunk_attention")
+def paged_chunk_stream(q, pool, layer, block_tables, start, num_heads, *,
+                       scale=None, kv_heads=None):
+    """`chunk_attention` of a chunk of queries a row over layer ``layer`` of
+    a paged pool whose blocks already hold the chunk's own rows, walked
+    `_STREAM_STEP_TOKENS` positions of the table at a step: each step gathers its
+    blocks' K and V (in the pool's dtype, at their own widths: ``pool`` may
+    be a `pallas_kernels.paged_attention.pair`), scores the chunk against
+    them in float32 and folds them into a running maximum, sum and
+    accumulator (the online softmax of the flash kernels; the probabilities
+    rounded to the pool's dtype for the product with V).  The loop's trip
+    count is the steps the rows' chunks reach, a value and not a shape, so a
+    chunk at the head of a long table walks one step; no value holds scores
+    wider than a step, whatever the table's width.  Positions past a row's
+    chunk score -inf and their V rows are zeroed, as in `chunk_attention`.
+    Returns (b, c, num_heads * v_head) in q's dtype."""
+    k_pool, v_pool, v_at = paged_attention_mod.pair(pool)
+    b, c, _ = q.shape
+    m = block_tables.shape[1]
+    bs = k_pool.shape[3]
+    kv_heads, group, hd = _kv_head_split("paged_chunk_stream",
+                                         k_pool.shape[4], num_heads,
+                                         kv_heads)
+    vd = v_pool.shape[4] // kv_heads
+    if scale is None:
+        scale = 1.0 / float(hd) ** 0.5
+    step_blocks = max(1, min(_STREAM_STEP_TOKENS // bs, m))
+    t = step_blocks * bs
+    n_steps = -(-m // step_blocks)
+    # entries past the table's width name the trash block, and are masked
+    tables = jnp.pad(block_tables.astype(jnp.int32),
+                     ((0, 0), (0, n_steps * step_blocks - m)))
+    start = start.astype(jnp.int32)
+    qpos = start[:, None] + jnp.arange(c, dtype=jnp.int32)[None]  # (b, c)
+    # the last position each row's chunk wrote, inside the table
+    last = jnp.minimum(start + c, m * bs) - 1
+    dt = k_pool.dtype
+    qh = q.reshape(b, c, kv_heads, group, hd).astype(dt)
+
+    def step(i, carry):
+        mx, l, acc = carry
+        ent = lax.dynamic_slice_in_dim(tables, i * step_blocks, step_blocks,
+                                       axis=1)
+        kc = k_pool[layer, 0, ent].reshape(b, t, kv_heads, hd)
+        vc = v_pool[layer, v_at, ent].reshape(b, t, kv_heads, vd)
+        j = i * t + jnp.arange(t, dtype=jnp.int32)
+        vc = jnp.where((j[None] <= last[:, None])[:, :, None, None], vc,
+                       jnp.zeros((), dt))
+        s = jnp.einsum("bckgd,btkd->bkgct", qh, kc,
+                       preferred_element_type=jnp.float32) * scale
+        seen = j[None, None, :] <= qpos[:, :, None]              # (b, c, t)
+        s = jnp.where(seen[:, None, None], s, -jnp.inf)
+        m_new = jnp.maximum(mx, jnp.max(s, axis=-1))
+        alpha = jnp.exp(mx - m_new)
+        p = jnp.exp(s - m_new[..., None])
+        l = alpha * l + jnp.sum(p, axis=-1)
+        acc = alpha[..., None] * acc + jnp.einsum(
+            "bkgct,btkd->bkgcd", p.astype(dt), vc,
+            preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    stats = (b, kv_heads, group, c)
+    _, l, acc = lax.fori_loop(
+        0, jnp.max(last) // t + 1, step,
+        (jnp.full(stats, -jnp.inf, jnp.float32),
+         jnp.zeros(stats, jnp.float32),
+         jnp.zeros(stats + (vd,), jnp.float32)))
+    out = (acc / l[..., None]).transpose(0, 3, 1, 2, 4)
+    return out.reshape(b, c, num_heads * vd).astype(q.dtype)
 
 
 @jax.named_scope("chunk_attention")
@@ -376,7 +466,8 @@ def chunk_attention(q, k_cache, v_cache, start, num_heads, *, scale=None,
     kv_heads: cached heads where fewer than ``num_heads`` (grouped-query
               attention, as in `decode_attention`): the caches are then
               ``kv_heads * head`` wide and q ``num_heads * head``
-    Returns (b, c, embed) — q's width.  f32 softmax statistics like the
+    Returns (b, c, embed) — q's width, or ``num_heads * v_head`` where the
+    V cache's heads are narrower than K's.  f32 softmax statistics like the
     siblings.
     """
     b, c, e = q.shape
@@ -384,6 +475,7 @@ def chunk_attention(q, k_cache, v_cache, start, num_heads, *, scale=None,
     kv_heads, group, hd = _kv_head_split("chunk_attention",
                                          k_cache.shape[2], num_heads,
                                          kv_heads)
+    vd = v_cache.shape[2] // kv_heads
     if scale is None:
         scale = 1.0 / float(hd) ** 0.5
     # a K/V head's ``group`` query heads side by side where they differ
@@ -399,7 +491,7 @@ def chunk_attention(q, k_cache, v_cache, start, num_heads, *, scale=None,
     written = (jnp.arange(s, dtype=jnp.int32)[None, :]
                < (start + c)[:, None])                   # (b, s)
     vh = jnp.where(written[:, :, None, None],
-                   v_cache.reshape(b, s, kv_heads, hd).astype(jnp.float32),
+                   v_cache.reshape(b, s, kv_heads, vd).astype(jnp.float32),
                    0.0)
     scores = jnp.einsum(
         "bchd,bshd->bhcs" if group == 1 else "bckgd,bskd->bkgcs",
@@ -416,7 +508,7 @@ def chunk_attention(q, k_cache, v_cache, start, num_heads, *, scale=None,
     out = jnp.einsum("bhcs,bshd->bchd" if group == 1
                      else "bkgcs,bskd->bckgd", p, vh,
                      preferred_element_type=jnp.float32)
-    return out.reshape(b, c, e).astype(q.dtype)
+    return out.reshape(b, c, num_heads * vd).astype(q.dtype)
 
 
 @jax.named_scope("verify_attention")

@@ -32,12 +32,19 @@ def rope_inv_freq(dim, theta):
     return 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
 
 
-def rope(x, positions, inv_freq, factor=1.0):
+def rope(x, positions, inv_freq, factor=1.0, rotary=None):
     """Rotate the last axis of ``x`` (..., dim) by ``positions`` (the shape of
     ``x`` without its last axis, or broadcastable to it).  Dim ``i`` pairs
     with dim ``i + dim // 2`` (the half-split, "rotate-half" layout; where a
     published checkpoint stores the pairs interleaved, that is this up to a
-    fixed permutation of the projection's columns)."""
+    fixed permutation of the projection's columns).  ``rotary`` (even, at
+    most ``dim``) rotates the first ``rotary`` dims alone, dim ``i`` with
+    ``i + rotary // 2``, and passes the rest as they are (partial rotary
+    positions); None is the whole axis."""
+    if rotary is not None and rotary < x.shape[-1]:
+        return jnp.concatenate(
+            [rope(x[..., :rotary], positions, inv_freq, factor),
+             x[..., rotary:]], axis=-1)
     half = x.shape[-1] // 2
     ang = positions.astype(jnp.float32)[..., None] \
         * jnp.asarray(inv_freq, jnp.float32)

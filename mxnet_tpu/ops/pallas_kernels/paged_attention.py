@@ -20,10 +20,13 @@ kernels.
 Layout.  The pool is ``(layers, 2, n_blocks, block_size, embed)`` as
 `serving.decode.TransformerKVModel.init_block_pool` makes it, K at index 0
 and V at 1 of the second axis, ``embed`` head-major (heads side by side in the
-lanes).  This kernel's copies (`_chunk_copies`) are the one place outside that
-module that knows it: the pool reaches the kernel whole, in HBM, and
-``pool_ref.at[layer, 0 | 1, block]`` is one `(block_size, embed)` DMA.  Nothing
-is transposed or split into heads in HBM.  In VMEM the heads are told apart by
+lanes); or, where K and V heads differ in width, a pair of ``(layers, 1,
+n_blocks, block_size, width)`` arrays, K's and V's, each at its own width
+(`serving.window.HybridWindowMoEKVModel.init_block_pool`; `pair`).  This
+kernel's copies (`_chunk_copies`) are the one place outside those modules that
+knows it: the pool reaches the kernel whole, in HBM, and ``k_ref.at[layer, 0,
+block]`` (``v_ref.at[layer, 1 | 0, block]``) is one `(block_size, width)` DMA.
+Nothing is transposed or split into heads in HBM.  In VMEM the heads are told apart by
 a block-diagonal query: row ``h`` of ``(heads, embed)`` holds the query's
 lanes of head ``h`` and zeros elsewhere, so ``q_bd @ K^T`` is every head's
 score row in one matmul and ``p @ V`` masked by the same pattern is every
@@ -74,29 +77,54 @@ _CHUNK_TOKENS = 256
 
 _HEAD_DIMS = (64, 128)
 
+# q/k heads the decode kernel takes: a V head's widths, and 192 beside a
+# narrower V head (its K rows are whole 128-lane tiles at 4 heads a row)
+_QK_HEAD_DIMS = (64, 128, 192)
+
+
+def pair(pool):
+    """(K's array, V's array, V's index on their second axis) of a paged
+    K/V pool: one ``(layers, 2, n_blocks, block_size, embed)`` array, K at 0
+    and V at 1, or a pair of ``(layers, 1, n_blocks, block_size, width)``
+    arrays, K's and V's, each at its own width."""
+    if isinstance(pool, (tuple, list)):
+        return pool[0], pool[1], 0
+    return pool, pool, 1
+
 
 def applies(pool, num_heads):
-    """Whether the kernel can attend over ``pool`` (an unquantised paged
-    pool) here: a TPU backend (or the interpreter), float32 or bfloat16
-    blocks that are whole sublane tiles, and an embed axis that is a whole
-    number of 64- or 128-wide heads and of 128-lane tiles."""
+    """Whether the decode kernel can attend over ``pool`` (an unquantised
+    paged pool, or a pair of them, `pair`) of ``num_heads`` heads a row
+    here: a TPU backend (or the interpreter), float32 or bfloat16 blocks
+    that are whole sublane tiles, and K and V rows that are whole 128-lane
+    tiles of whole heads: V heads 64 or 128 wide, K heads as wide as V's or
+    192 (the q/k width of a model whose V heads are narrower)."""
     if jax.default_backend() != "tpu" and not _INTERPRET:
         return False
-    if pool.ndim != 5 or pool.dtype not in (jnp.bfloat16, jnp.float32):
+    k_pool, v_pool, _ = pair(pool)
+    if any(p.ndim != 5 or p.dtype not in (jnp.bfloat16, jnp.float32)
+           for p in (k_pool, v_pool)):
         return False
-    bs, e = pool.shape[3:]
-    sublanes = 32 // pool.dtype.itemsize       # 8 for float32, 16 for bf16
-    return (e % num_heads == 0 and e // num_heads in _HEAD_DIMS
-            and e % 128 == 0 and bs % sublanes == 0)
+    if k_pool.dtype != v_pool.dtype or k_pool.shape[2:4] \
+            != v_pool.shape[2:4]:
+        return False
+    bs, e = k_pool.shape[3:]
+    ev = v_pool.shape[4]
+    sublanes = 32 // k_pool.dtype.itemsize     # 8 for float32, 16 for bf16
+    return (e % num_heads == 0 and ev % num_heads == 0
+            and ev // num_heads in _HEAD_DIMS
+            and e // num_heads in (_HEAD_DIMS if e == ev else _QK_HEAD_DIMS)
+            and e % 128 == 0 and ev % 128 == 0 and bs % sublanes == 0)
 
 
-def _chunk_copies(tables_ref, pos_ref, pool_ref, k_buf, v_buf, sem, layer,
-                  row, chunk, slot, *, n_table, block_size, chunk_blocks):
+def _chunk_copies(tables_ref, pos_ref, k_ref, v_ref, v_at, k_buf, v_buf,
+                  sem, layer, row, chunk, slot, *, n_table, block_size,
+                  chunk_blocks):
     """[(live, K copy, V copy)] for every block of chunk ``chunk`` of row
     ``row`` into buffer ``slot``.  ``live`` is whether the row has reached
     the block: entry index <= min(pos // block_size, n_table - 1).  The
-    pool's layout (`init_block_pool`) is known here and nowhere else in
-    the kernel."""
+    pool's layout (`init_block_pool`: K in ``k_ref`` at 0, V in ``v_ref`` at
+    ``v_at``) is known here and nowhere else in the kernel."""
     last = jnp.minimum(pos_ref[row] // block_size, n_table - 1)
     out = []
     for c in range(chunk_blocks):
@@ -105,9 +133,9 @@ def _chunk_copies(tables_ref, pos_ref, pool_ref, k_buf, v_buf, sem, layer,
         dst = pl.ds(c * block_size, block_size)
         out.append((
             ent <= last,
-            pltpu.make_async_copy(pool_ref.at[layer, 0, blk],
+            pltpu.make_async_copy(k_ref.at[layer, 0, blk],
                                   k_buf.at[slot, dst], sem.at[slot, 0]),
-            pltpu.make_async_copy(pool_ref.at[layer, 1, blk],
+            pltpu.make_async_copy(v_ref.at[layer, v_at, blk],
                                   v_buf.at[slot, dst], sem.at[slot, 1])))
     return out
 
@@ -132,20 +160,31 @@ def _split3(p):
     return jnp.concatenate([hi, mid, lo], axis=0)
 
 
-def _kernel(tables_ref, pos_ref, layer_ref, q_ref, pool_ref, o_ref,
-            k_buf, v_buf, sem, acc_ref, m_ref, l_ref, slot_ref, *,
-            n_table, block_size, chunk_blocks, num_heads, scale, group=1):
+def _kernel(tables_ref, pos_ref, layer_ref, q_ref, *refs, n_table,
+            block_size, chunk_blocks, num_heads, scale, group=1,
+            split=False):
+    if split:
+        # K and V each from its own array (`pair`)
+        k_ref, v_ref, o_ref, k_buf, v_buf, sem, acc_ref, m_ref, l_ref, \
+            slot_ref = refs
+        v_at = 0
+    else:
+        pool_ref, o_ref, k_buf, v_buf, sem, acc_ref, m_ref, l_ref, \
+            slot_ref = refs
+        k_ref = v_ref = pool_ref
+        v_at = 1
     r = pl.program_id(0)
     n_rows = pl.num_programs(0)
     layer = layer_ref[0]
-    hp, e = acc_ref.shape
+    hp, ev = acc_ref.shape
+    e = k_buf.shape[2]
     kv_heads = num_heads // group
     hd = e // kv_heads
     t = chunk_blocks * block_size
     exact = k_buf.dtype == jnp.bfloat16 and q_ref.dtype == jnp.bfloat16
     copies = functools.partial(
-        _chunk_copies, tables_ref, pos_ref, pool_ref, k_buf, v_buf, sem,
-        layer, n_table=n_table, block_size=block_size,
+        _chunk_copies, tables_ref, pos_ref, k_ref, v_ref, v_at, k_buf, v_buf,
+        sem, layer, n_table=n_table, block_size=block_size,
         chunk_blocks=chunk_blocks)
 
     start = functools.partial(_each_live_copy, copies,
@@ -168,13 +207,16 @@ def _kernel(tables_ref, pos_ref, layer_ref, q_ref, pool_ref, o_ref,
     l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # head h owns lanes [h * hd, (h + 1) * hd)
-    lane = lax.broadcasted_iota(jnp.int32, (hp, e), 1)
-    head = lax.broadcasted_iota(jnp.int32, (hp, e), 0) * hd
-    if group > 1:
-        # row j * kv_heads + k reads K/V head k
-        head = head % e
-    own = (lane >= head) & (lane < head + hd)
+    def owned(width, hd):
+        # head h owns lanes [h * hd, (h + 1) * hd)
+        lane = lax.broadcasted_iota(jnp.int32, (hp, width), 1)
+        head = lax.broadcasted_iota(jnp.int32, (hp, width), 0) * hd
+        if group > 1:
+            # row j * kv_heads + k reads K/V head k
+            head = head % width
+        return (lane >= head) & (lane < head + hd)
+
+    own = owned(e, hd)
     if group == 1:
         # (selects are made in float32: a mask of 32-bit lanes does not lay
         # out over packed bf16 rows)
@@ -204,7 +246,7 @@ def _kernel(tables_ref, pos_ref, layer_ref, q_ref, pool_ref, o_ref,
             # past the row's position the buffer holds a block's unwritten
             # tail or an earlier chunk's rows: exact zeros, so that a
             # probability of 0 multiplies no garbage (0 * NaN)
-            j = i * t + lax.broadcasted_iota(jnp.int32, (t, e), 0)
+            j = i * t + lax.broadcasted_iota(jnp.int32, (t, ev), 0)
             v_buf[slot] = jnp.where(j <= pos,
                                     v_buf[slot].astype(jnp.float32),
                                     0.0).astype(v_buf.dtype)
@@ -231,14 +273,17 @@ def _kernel(tables_ref, pos_ref, layer_ref, q_ref, pool_ref, o_ref,
             pv = lax.dot_general(p, v, (((1,), (0,)), ((), ())),
                                  precision=precision,
                                  preferred_element_type=jnp.float32)
-        acc_ref[...] = alpha * acc_ref[...] + pv              # (hp, e)
+        acc_ref[...] = alpha * acc_ref[...] + pv              # (hp, ev)
         m_ref[...] = m_new
         return ()
 
     lax.fori_loop(0, n_chunks, body, ())
     slot_ref[0] = (base + n_chunks) % 2
 
-    # each head's own lanes of its accumulator row, over its sum
+    # each head's own lanes of its accumulator row, over its sum (a V
+    # head's lanes, where they are narrower than its K head's)
+    if ev != e:
+        own = owned(ev, ev // kv_heads)
     out = jnp.where(own, acc_ref[...] / l_ref[...], 0.0)
     if group == 1:
         o_ref[0] = jnp.sum(out, axis=0, keepdims=True).astype(o_ref.dtype)
@@ -279,12 +324,14 @@ def paged_decode_attn(q, pool, layer, block_tables, pos, num_heads, *,
 
     q:            (b, num_heads * head)
     pool:         (layers, 2, n_blocks, block_size, kv_heads * head), read
-                  in place
+                  in place, or the pair of K's (layers, 1, ..., kv_heads *
+                  head) and V's (layers, 1, ..., kv_heads * v_head) (`pair`)
     layer:        int (static or traced)
     block_tables: (b, m) int32
     pos:          (b,) int32: the position the query occupies; its K/V row
                   is already in the pool
-    Returns q's shape and dtype, equal to
+    Returns (b, num_heads * v_head) in q's dtype (q's shape where K and V
+    heads are alike), equal to
     `decode_attention(q, gather_paged_kv(pool, layer, 0, block_tables),
     gather_paged_kv(pool, layer, 1, block_tables), pos, num_heads,
     kv_heads=kv_heads)` up to the order of the float32 sums.
@@ -292,7 +339,7 @@ def paged_decode_attn(q, pool, layer, block_tables, pos, num_heads, *,
     hd = q.shape[1] // num_heads
     if scale is None:
         scale = 1.0 / float(hd) ** 0.5
-    chunk_blocks = max(1, min(_CHUNK_TOKENS // pool.shape[3],
+    chunk_blocks = max(1, min(_CHUNK_TOKENS // pair(pool)[0].shape[3],
                               block_tables.shape[1]))
     return _paged_decode(q, pool, jnp.asarray(layer, jnp.int32),
                          block_tables.astype(jnp.int32),
@@ -313,9 +360,11 @@ def paged_decode_attn(q, pool, layer, block_tables, pos, num_heads, *,
 def _paged_decode(q, pool, layer, block_tables, pos, *, num_heads, scale,
                   chunk_blocks, interpret, group=1):
     b = q.shape[0]
-    e = pool.shape[4]
+    k_pool, v_pool, _ = pair(pool)
+    split = k_pool is not v_pool
+    e, ev = k_pool.shape[4], v_pool.shape[4]
     m = block_tables.shape[1]
-    bs = pool.shape[3]
+    bs = k_pool.shape[3]
     t = chunk_blocks * bs
     # head rows padded to whole sublane tiles of the matmul operands
     hp = -(-num_heads // 16) * 16
@@ -325,35 +374,39 @@ def _paged_decode(q, pool, layer, block_tables, pos, *, num_heads, scale,
     q_rows = None if group == 1 else _grouped_query(q, kv_heads, group,
                                                     e // kv_heads, hp)
     row = pl.BlockSpec((1, rows, e), lambda r, *_: (r, 0, 0))
+    out_row = row if ev == e else pl.BlockSpec((1, rows, ev),
+                                               lambda r, *_: (r, 0, 0))
     out = pl.pallas_call(
         functools.partial(_kernel, n_table=m, block_size=bs,
                           chunk_blocks=chunk_blocks, num_heads=num_heads,
-                          scale=scale, group=group),
+                          scale=scale, group=group, split=split),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,       # tables, positions, the layer
             grid=(b,),
-            in_specs=[row, pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=row,
+            in_specs=[row] + [pl.BlockSpec(memory_space=pl.ANY)]
+            * (1 + split),
+            out_specs=out_row,
             scratch_shapes=[
-                pltpu.VMEM((2, t, e), pool.dtype),        # K chunks
-                pltpu.VMEM((2, t, e), pool.dtype),        # V chunks
+                pltpu.VMEM((2, t, e), k_pool.dtype),      # K chunks
+                pltpu.VMEM((2, t, ev), v_pool.dtype),     # V chunks
                 pltpu.SemaphoreType.DMA((2, 2)),          # [slot, K | V]
-                pltpu.VMEM((hp, e), jnp.float32),         # accumulator
+                pltpu.VMEM((hp, ev), jnp.float32),        # accumulator
                 pltpu.VMEM((hp, 1), jnp.float32),         # running maximum
                 pltpu.VMEM((hp, 1), jnp.float32),         # running sum
                 pltpu.SMEM((1,), jnp.int32),              # slot of next row
             ]),
-        out_shape=jax.ShapeDtypeStruct((b, rows, e), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, rows, ev), q.dtype),
         # rows in order: each starts the next row's first copies
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_decode_attn",
     )(block_tables.reshape(-1), pos, layer.reshape(1),
-      q.reshape(b, 1, e) if group == 1 else q_rows, pool)
+      q.reshape(b, 1, e) if group == 1 else q_rows,
+      k_pool, *[v_pool] * split)
     if group == 1:
-        return out.reshape(b, e)
-    return _ungroup(out, kv_heads, group, e // kv_heads)
+        return out.reshape(b, ev)
+    return _ungroup(out, kv_heads, group, ev // kv_heads)
 
 
 # -- the prefill chunk --------------------------------------------------------
@@ -407,10 +460,11 @@ def _prefill_step_blocks(block_size, n_table):
 
 def prefill_applies(pool, kv_heads, c, n_table):
     """Whether `paged_prefill_attn` can attend a chunk of ``c`` queries over
-    tables of ``n_table`` entries of ``pool`` here: what `applies` asks of
-    the pool, a chunk of whole sublane tiles, and a table whose K and V fit
-    the kernel's share of VMEM."""
-    if not applies(pool, kv_heads):
+    tables of ``n_table`` entries of ``pool`` here: one array whose K and V
+    heads are alike (not a `pair` of widths), what `applies` asks of it, a
+    chunk of whole sublane tiles, and a table whose K and V fit the kernel's
+    share of VMEM."""
+    if isinstance(pool, (tuple, list)) or not applies(pool, kv_heads):
         return False
     bs, e = pool.shape[3:]
     step = _prefill_step_blocks(bs, n_table)
@@ -440,8 +494,8 @@ def _prefill_kernel(tables_ref, last_ref, start_ref, layer_ref, q_ref,
         lax.dot_general, preferred_element_type=jnp.float32,
         precision=None if exact else lax.Precision.HIGHEST)
     copies = functools.partial(
-        _chunk_copies, tables_ref, last_ref, pool_ref, k_buf, v_buf, sem,
-        layer_ref[0], n_table=n_table, block_size=block_size,
+        _chunk_copies, tables_ref, last_ref, pool_ref, pool_ref, 1, k_buf,
+        v_buf, sem, layer_ref[0], n_table=n_table, block_size=block_size,
         chunk_blocks=step_blocks)
     # step ``i``'s blocks go to the buffers' rows ``i`` under semaphores ``i``
     start = functools.partial(_each_live_copy, copies,

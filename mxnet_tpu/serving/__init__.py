@@ -100,6 +100,7 @@ from .autoscale import AutoScaler, autoscale_enabled
 from .decode import TransformerKVModel
 from .latent import LatentMoEKVModel
 from .shortconv import ShortConvMoEKVModel
+from .window import HybridWindowMoEKVModel
 from .gateway import ServeGateway, gateway_enabled, http_status
 from .engine import ServeRequest, ServingEngine, ReplicaRouter
 from .handoff import HandoffTicket, disagg_enabled
@@ -115,6 +116,7 @@ from .errors import (ServeError, ServeTimeout, ServeOverload,
                      ServeQuantError)
 
 __all__ = ["TransformerKVModel", "LatentMoEKVModel", "ShortConvMoEKVModel",
+           "HybridWindowMoEKVModel",
            "ServeRequest", "ServingEngine",
            "ReplicaRouter", "HandoffTicket", "disagg_enabled",
            "ServeGateway", "gateway_enabled", "http_status",

@@ -2805,7 +2805,8 @@ class ServingEngine:
                                            table_d)
         if self._state_slots and not pf.done:
             # a sequence's first chunk starts its slot's state from nothing
-            self._count("state_resets")
+            self._count(getattr(self.model, "state_reset_counter",
+                                "state_resets"))
         pf.done += chunk
         self.stats["prefill_chunks"] += 1
         self.stats["prefill_tokens"] += chunk  # the suffix-only witness
@@ -3446,10 +3447,14 @@ class ServingEngine:
             self._watch("decode", args,
                         self._DECODE_NAMES + self._tail_names(samp), b)
             compiled = self._compiled_decode(b)
-        self._iter.update(
-            rows=n, bucket=b, attn_kernel=self._attn_kernel,
-            # table entries the rows' attention walks: their live blocks
-            ctx_blocks=int(np.sum(pos[:n] // self.block_size) + n))
+        # table entries the rows' attention walks: their live blocks
+        ctx = int(np.sum(pos[:n] // self.block_size) + n)
+        self._iter.update(rows=n, bucket=b, attn_kernel=self._attn_kernel,
+                          ctx_blocks=ctx)
+        if getattr(self.model, "ring_layers", 0):
+            # a model whose window layers keep no blocks: the blocks its
+            # full layers' attention reads, all of them
+            self._iter["full_ctx_blocks"] = ctx * self.model.full_layers
         t_launch = time.perf_counter()
         try:
             if chaos.serve_launch_error():

@@ -798,3 +798,236 @@ def test_a_chunk_over_the_grouped_query_pool_copies_no_pool(on_tpu,
     layer_pool = 2 * 4609 * 64 * 512 * 2
     assert memory.alias_size_in_bytes >= 2 * layer_pool
     assert memory.temp_size_in_bytes < 0.6 * layer_pool
+
+
+# -- the window/full hybrid at the served widths ------------------------------
+
+
+def _mimo_program(one_chip, what, n, kinds=(0, 1), n_blocks=2049, bs=64,
+                  slots=65):
+    """`serve_decode_b<n>` or `serve_prefill_s<n>` of the MiMo-V2 block at
+    its published widths (this chip's 16 of 256 experts, an eighth of the
+    vocabulary) over ``kinds`` layers (0 full, 1 window; the first dense),
+    tables of 16,384 positions, compiled for the described chip with (pool,
+    ring) donated: (its text, its memory analysis, the model)."""
+    from mxnet_tpu.base import bfloat16
+    from mxnet_tpu.serving import HybridWindowMoEKVModel
+
+    model = HybridWindowMoEKVModel(
+        19072, 16384, kinds, (0,) + (1,) * (len(kinds) - 1), 4096, 64, 4, 8,
+        192, 128, 64, 128, 16384, 2048, 256, (0, 16), 8, rope_theta=1e7,
+        window_rope_theta=1e4, value_scale=0.707, dtype=bfloat16)
+    params = {k: _bf16(v, one_chip) for k, v in model.param_shapes().items()}
+    shape = (model.full_layers, 1, n_blocks, bs)
+    rings = (model.ring_layers, slots, model.window, model.window_kv_heads)
+    cache = ((_bf16(shape + (model.k_width,), one_chip),
+              _bf16(shape + (model.v_width,), one_chip)),
+             (_bf16(rings[:3] + (rings[3] * model.head_dim,), one_chip),
+              _bf16(rings[:3] + (rings[3] * model.v_head_dim,), one_chip)))
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    if what == "decode":
+        def prog(params, cache, token, pos, tables, slots):
+            logits, cache = model.decode_paged(params, cache, token, pos,
+                                               tables, slots=slots)
+            return jnp.argmax(logits, axis=-1), cache
+
+        args = (ints(n), ints(n), ints(n, model.seq_len // bs), ints(n))
+    else:
+        def prog(params, cache, tokens, start, length, tables, slots):
+            logits, cache = model.prefill_paged(params, cache, tokens, start,
+                                                length, tables, slots=slots)
+            return jnp.argmax(logits, axis=-1), cache
+
+        args = (ints(1, n), ints(1), ints(1), ints(1, model.seq_len // bs),
+                ints(1))
+    prog.__name__ = "serve_%s_%s%d" % (what, "b" if what == "decode" else "s",
+                                       n)
+    comp = jax.jit(prog, donate_argnums=(1,)).lower(params, cache,
+                                                    *args).compile()
+    return comp.as_text(), comp.memory_analysis(), model
+
+
+@pytest.mark.parametrize("batch", [8, 64])
+def test_the_hybrid_decode_reads_its_full_layers_through_the_kernel(
+        on_tpu, one_chip, monkeypatch, batch):
+    """A decode launch at MiMo-V2's widths: one `paged_decode_attn` custom
+    call a full layer under `decode_attention`, at q/k 192 and v 128 with 16
+    query heads a K/V head, from one lowered function; the window layer
+    attends over its ring in `jax.numpy`; pool and rings donated and updated
+    in place; no temporary that grows with the pool or with rows x table
+    width x the K/V widths (the gathered context the `jax.numpy` body would
+    make)."""
+    from mxnet_tpu.ops.pallas_kernels import paged_attention
+
+    monkeypatch.setattr(paged_attention, "_INTERPRET", False)
+    program = "serve_decode_b%d" % batch
+    text, memory, model = _mimo_program(one_chip, "decode", batch,
+                                        kinds=(0, 1, 1))
+    calls = [n for n in _op_names(text, program)
+             if n.endswith("/pallas_call")]
+    assert set(calls) == {"decode_attention/jit(_paged_decode)/"
+                          "paged_decode_attn/pallas_call"}
+    assert text.count("tpu_custom_call") == model.full_layers == 1
+    assert "input_output_alias" in text.splitlines()[0]
+    pool_bytes = 2049 * 64 * (768 + 512) * 2
+    ring_bytes = 2 * 65 * 128 * 8 * 320 * 2
+    assert memory.alias_size_in_bytes == pool_bytes + ring_bytes
+    gathered = batch * 16384 * (768 + 512) * 2
+    assert memory.temp_size_in_bytes < min(pool_bytes // 2, gathered)
+    # the rings are gathered by row and written in place: no value of the
+    # program is the size of the rings (the compiler laid the whole of them
+    # out anew in every layer, 10.8 ms a launch on a v5e, while K and V were
+    # lanes of one array; at a bucket of one row it still lays them out once
+    # a launch, and the cell's buckets start at 8)
+    entry = text[text.index("\nENTRY "):]
+    laid_out = [m.group(0)[:200] for m in re.finditer(
+        r"\n\s+(?:ROOT )?%\S+ = \(?bf16\[2,65,128,\d+\][^\n]*", entry)
+        if not re.search(r" (parameter|bitcast|get-tuple-element)\(",
+                         m.group(0))
+        and "window_state/scatter" not in m.group(0)]
+    assert not laid_out, laid_out
+    scopes = {part for n in _op_names(text, program)
+              for part in n.split("/")}
+    assert {"window_attention", "window_state", "rope", "moe_experts",
+            "kv_scatter", "lm_head"} <= scopes
+
+
+def _table_wide_scores(text, c, width):
+    """The float32 values of three axes or more whose shape holds both the
+    chunk's ``c`` and the table's ``width``: scores of the chunk against
+    the whole table, a head or a group apart (a dense layer's (c,
+    intermediate) product is two axes, at a width that may equal the
+    table's)."""
+    return [d for d in re.findall(r"f32\[([\d,]+)\]", text)
+            if d.count(",") >= 2
+            and {c, width} <= {int(x) for x in d.split(",")}]
+
+
+@pytest.mark.parametrize("chunk", [512, 64])
+def test_the_hybrid_prefill_holds_no_table_wide_scores(on_tpu, one_chip,
+                                                       chunk):
+    """A chunk over a table of 16,384 positions at MiMo-V2's widths: the full
+    layer's attention walks the table a step of 1,024 positions at a time
+    (`paged_chunk_stream`, a loop under `chunk_attention`), so no value
+    holds float32 scores of the chunk against the table's width (2.1 GB at
+    64 heads x 512, what `chunk_attention` over the gathered table would
+    make), and the temporaries stay under a layer of the pool; the window
+    layer's band is under `window_attention`."""
+    program = "serve_prefill_s%d" % chunk
+    text, memory, _ = _mimo_program(one_chip, "prefill", chunk)
+    assert "input_output_alias" in text.splitlines()[0]
+    assert not _table_wide_scores(text, chunk, 16384)
+    names = _op_names(text, program)
+    assert not any("kv_gather" in n or "pallas_call" in n for n in names)
+    assert any(n.startswith("chunk_attention/while") for n in names)
+    assert any("window_attention" in n for n in names)
+    assert memory.temp_size_in_bytes < 64 * 512 * 16384 * 4 // 4
+
+
+# -- the programs of the other served models, unchanged ------------------------
+
+
+def _digest(text):
+    """A digest of a lowered program's text, each Mosaic kernel's
+    serialised body replaced by a digest of its operations without the
+    source locations the body carries (a line moved in a kernel's file
+    changes the body's bytes and not the kernel)."""
+    import base64
+    import hashlib
+    import json
+
+    from jaxlib.mlir import ir
+
+    def kernel(m):
+        cfg = json.loads(m.group(1).replace("\\22", '"'))
+        body = base64.b64decode(cfg["custom_call_config"]["body"])
+        ctx = ir.Context()
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            asm = ir.Module.parse(body).operation.get_asm(
+                enable_debug_info=False)
+        return 'backend_config = "%s"' % hashlib.sha256(
+            asm.encode()).hexdigest()
+
+    return hashlib.sha256(re.sub(
+        r'backend_config = "((?:[^"\\]|\\.)*)"', kernel,
+        text).encode()).hexdigest()[:16]
+
+
+def _lowered(one_chip, model, cache, what, n, slots=False):
+    """The text of `serve_decode_b<n>` or `serve_prefill_s<n>` of ``model``
+    over ``cache`` (shapes), lowered for the described chip."""
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    params = {k: _bf16(v, one_chip) for k, v in model.param_shapes().items()}
+    tail = {"slots": ints(n if what == "decode" else 1)} if slots else {}
+    width = model.seq_len // 64
+    if what == "decode":
+        def prog(params, cache, token, pos, tables, **kw):
+            logits, cache = model.decode_paged(params, cache, token, pos,
+                                               tables, **kw)
+            return jnp.argmax(logits, axis=-1), cache
+
+        args = (ints(n), ints(n), ints(n, width))
+    else:
+        def prog(params, cache, tokens, start, length, tables, **kw):
+            logits, cache = model.prefill_paged(params, cache, tokens, start,
+                                                length, tables, **kw)
+            return jnp.argmax(logits, axis=-1), cache
+
+        args = (ints(1, n), ints(1), ints(1), ints(1, width))
+    prog.__name__ = "serve_%s_%s%d" % (what, "b" if what == "decode" else "s",
+                                       n)
+    return jax.jit(prog, donate_argnums=(1,)).lower(params, cache, *args,
+                                                    **tail).as_text()
+
+
+def _todays_model(one_chip, which):
+    """(model, its cache's shapes, whether it takes slots) at the cells'
+    widths, two or three layers deep."""
+    from mxnet_tpu.base import bfloat16
+    from mxnet_tpu.serving import ShortConvMoEKVModel, TransformerKVModel
+
+    if which == "gpt2":
+        model = TransformerKVModel(50257, 1024, num_layers=2, num_heads=20,
+                                   num_embed=1280, dtype=bfloat16)
+        return model, _bf16((2, 2, 513, 64, 1280), one_chip), False
+    if which == "lfm2":
+        model = ShortConvMoEKVModel(65536, 4608, KINDS[:3], 2048, 32, 8, 3,
+                                    7168, 1792, 32, 4, num_dense_layers=2,
+                                    dtype=bfloat16)
+        return model, (_bf16((1, 2, 513, 64, 512), one_chip),
+                       _bf16((2, 65, 2, 2048), one_chip)), True
+    model = _latent_model(2)
+    return model, _bf16((2, 513, 64, model.pool_width), one_chip), False
+
+
+#: digests of the programs as the parent of the K/V pair pool's two widths
+#: lowered them (`_digest`)
+_TODAY = {
+    ("gpt2", "decode", 8): "efc3ab88f2af9e8a",
+    ("gpt2", "prefill", 512): "4e3bc85e130ec77e",
+    ("lfm2", "decode", 8): "003f4b149f78609a",
+    ("lfm2", "prefill", 512): "5532c5c08917e272",
+    ("kimi", "decode", 8): "bf54583811a56970",
+    ("kimi", "prefill", 512): "a851ae9522756a11",
+}
+
+
+@pytest.mark.parametrize("which,what,n", sorted(_TODAY))
+def test_the_other_models_lowered_programs_are_unchanged(
+        on_tpu, one_chip, monkeypatch, which, what, n):
+    """GPT-2's, LFM2's and Kimi's decode and prefill programs, lowered for
+    the chip at their cells' widths, are the ones they were before the
+    decode kernel took K and V at two widths, the chunk path a pair, and
+    `rope` a rotary width: kernels and all."""
+    from mxnet_tpu.ops.pallas_kernels import paged_attention
+
+    monkeypatch.setattr(paged_attention, "_INTERPRET", False)
+    model, cache, slots = _todays_model(one_chip, which)
+    got = _digest(_lowered(one_chip, model, cache, what, n, slots))
+    assert got == _TODAY[(which, what, n)], got

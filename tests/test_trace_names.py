@@ -17,8 +17,9 @@ import jax.numpy as jnp
 import mxnet_tpu as mx
 from mxnet_tpu import models
 from mxnet_tpu.parallel import SPMDTrainer, make_mesh
-from mxnet_tpu.serving import (LatentMoEKVModel, ServingEngine,
-                               ShortConvMoEKVModel, TransformerKVModel)
+from mxnet_tpu.serving import (HybridWindowMoEKVModel, LatentMoEKVModel,
+                               ServingEngine, ShortConvMoEKVModel,
+                               TransformerKVModel)
 
 SERVING_SCOPES = {"embed", "qkv_proj", "kv_scatter", "kv_gather", "attn_out",
                   "ffn", "lm_head", "sampler"}
@@ -152,6 +153,70 @@ def test_the_state_seams_span_attribute_and_counters_are_named(
     assert reg.counter("serve.shortconv_names.state_resets").value \
         == before + 1
     assert reg.gauge("serve.shortconv_names.state_slots_live").value == 1
+
+
+WINDOW_SCOPES = {"embed", "qkv_proj", "rope", "window_state",
+                 "window_attention", "kv_scatter", "attn_out", "ffn",
+                 "moe_router", "moe_dispatch", "moe_experts", "moe_combine",
+                 "moe_loop", "lm_head", "sampler"}
+
+
+@pytest.fixture(scope="module")
+def window_engine():
+    model = HybridWindowMoEKVModel(
+        61, 32, [0, 1, 1], [0, 1, 1], 32, 4, 1, 2, 16, 8, 8, 4, 48, 16, 8,
+        (0, 4), 2)
+    return ServingEngine(model, model.init_params(np.random.RandomState(3)),
+                         max_batch=4, block_size=4, n_blocks=32,
+                         prefill_buckets=[8], decode_buckets=[2],
+                         name="window_names")
+
+
+@pytest.mark.parametrize("build,module,attention", [
+    ("_compiled_decode", "jit_serve_decode_b2",
+     {"decode_attention", "kv_gather"}),
+    ("_compiled_prefill", "jit_serve_prefill_s8", {"chunk_attention"}),
+])
+def test_window_programs_carry_their_name_and_scopes(window_engine, build,
+                                                     module, attention):
+    """The fourth model class under the engine's own program names: what the
+    `.mimo` metrics' readers look for (`benchmark/metrics/*.mimo.json`):
+    the window layers' `window_attention` and `window_state`, the full
+    layers' `decode_attention` and `chunk_attention` (a chunk walks the
+    table in steps, so it gathers under `chunk_attention`, not
+    `kv_gather`), the partial `rope`.  No shared expert."""
+    bucket = 8 if build == "_compiled_prefill" else 2
+    text = getattr(window_engine, build)(bucket).as_text()
+    assert text.startswith("HloModule %s," % module)
+    scopes = _scopes(text)
+    assert scopes >= WINDOW_SCOPES | attention
+    assert "moe_shared" not in scopes
+
+
+def test_the_rings_attribute_and_counters_are_named(window_engine):
+    """`state_slots_live` and `full_ctx_blocks` on the `iteration` record,
+    and the `ring_resets` counter: what `state_slots_live_peak_share.mimo`
+    and `gqa_decode_roofline.mimo` read."""
+    import time
+
+    from mxnet_tpu import telemetry, tracing
+
+    reg = telemetry.registry()
+    before = reg.counter("serve.window_names.ring_resets").value
+    t0 = time.perf_counter()
+    req = window_engine.submit([5, 6, 7, 8, 9], max_new_tokens=3)
+    window_engine.run_until_idle(timeout=120)
+    assert len(req.result(timeout=5)) == 3
+    records = [r["attrs"] for r in tracing.window(
+        "window_names", t0, time.perf_counter())
+        if r["phase"] == "iteration"]
+    assert records and all(a["state_slots_live"] == 1 for a in records)
+    decodes = [a for a in records if "ctx_blocks" in a]
+    assert decodes and all(a["full_ctx_blocks"] == a["ctx_blocks"]
+                           for a in decodes)     # one full layer
+    assert reg.counter("serve.window_names.ring_resets").value \
+        == before + 1
+    assert reg.counter("serve.window_names.state_resets").value == 0
 
 
 def test_latent_prefill_lowered_for_a_tpu_is_its_kernel(monkeypatch):
