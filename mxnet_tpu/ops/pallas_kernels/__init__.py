@@ -7,9 +7,11 @@ the cases where explicit VMEM blocking beats XLA's default schedule —
 attention above all (the S x S score matrix must never touch HBM).
 
 `flash_attention` (training and prefill's full-sequence attention),
-`fused_ce` (the softmax cross-entropy head), `layer_norm`, and
+`fused_ce` (the softmax cross-entropy head), `layer_norm`,
 `paged_attention` (serving's single-token decode attention over the paged
-K/V pool, read in place: `ops.attention.paged_decode_attention`).
+K/V pool, read in place: `ops.attention.paged_decode_attention`), and
+`expert_ffn` (serving's routed experts, one call a layer:
+`ops.moe.held_share`).
 
 Every kernel has a pure-jnp blockwise fallback with identical math, used on
 non-TPU backends (the 8-device CPU test mesh) and as the reference in tests.

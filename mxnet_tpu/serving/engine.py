@@ -714,6 +714,12 @@ class ServingEngine:
         self._prefill_kernel = {s: int(self._scoped(
             lambda s=s: model.paged_prefill_kernel(self._cache, s),
             "attn_kernel")()) for s in self.prefill_buckets}
+        # whether the expert layers run as the Pallas kernel `expert_ffn`:
+        # decided here, once, as the programs' traces will (the banks, the
+        # backend, this replica's mesh; at the most rows a program routes),
+        # for the `iteration` record's `expert_kernel`
+        self._expert_kernel = int(self._moe and self._scoped(
+            lambda: self._expert_kernel_applies(), "expert_kernel")())
         self._prefilling = {}  # row -> _Prefill (insertion-ordered)
         # cross-request prefix sharing (MXNET_SERVE_PREFIX=0 restores
         # single-owner paging bit-for-bit; MXNET_SERVE_PREFIX_POOL
@@ -1269,6 +1275,21 @@ class ServingEngine:
         fn.__name__ = fn.__qualname__ = name
         return fn
 
+    def _expert_kernel_applies(self):
+        """`ops.moe.expert_kernel_applies` for the first expert layer's
+        banks, at the most rows a decode or prefill program routes; False
+        for a model whose experts are not `ops.moe`'s (`TransformerKVModel`'s
+        top-1 mixture, dispatched densely, names no ``moe_gate``)."""
+        from ..ops.moe import expert_kernel_applies
+
+        gates = sorted(k for k in self._params if k.endswith("moe_gate"))
+        if not gates:
+            return False
+        banks = tuple(self._params[gates[0][:-4] + m]
+                      for m in ("gate", "up", "down"))
+        rows = max(max(self.decode_buckets), max(self.prefill_buckets))
+        return expert_kernel_applies(rows, banks)
+
     def _moe_out(self, tape):
         """The MoE programs' extra output, ONE (2, E) array a launch: the
         per-expert routed-row counts summed over the tape's entries (one a
@@ -1319,14 +1340,16 @@ class ServingEngine:
         since the last record (a chunk launched by an iteration that wrote
         none counts in the next): ``expert_rows`` (row-expert pairs that
         fell on held experts), ``expert_load_max`` (the fullest expert's
-        rows) and ``expert_hits`` ((layer, expert) pairs with a row at
-        all: the expert matrices read)."""
+        rows), ``expert_hits`` ((layer, expert) pairs with a row at all:
+        the expert matrices read) and ``expert_kernel`` (1 where the expert
+        layers run as the Pallas kernel `expert_ffn`)."""
         if not self._moe:
             return {}
         rows, hits = self._moe_since
         out = {"expert_rows": int(rows.sum()),
                "expert_load_max": int(rows.max()),
-               "expert_hits": int(hits.sum())}
+               "expert_hits": int(hits.sum()),
+               "expert_kernel": self._expert_kernel}
         self._moe_since[:] = 0
         return out
 

@@ -298,6 +298,10 @@ def _op_names(text, program):
     return re.findall(r'op_name="jit\(%s\)/([^"]+)"' % program, text)
 
 
+#: an expert layer's products, one Pallas call (`ops.moe.held_share`)
+_EXPERT_CALL = "moe_experts/jit(_expert_ffn)/expert_ffn/pallas_call"
+
+
 _ITEMSIZE = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s32": 4,
              "u32": 4, "f32": 4}
 
@@ -529,9 +533,10 @@ def test_latent_decode_reads_the_pool_through_its_kernel(on_tpu, one_chip,
                                                          batch):
     """A decode launch over the latent pool at Kimi-K2.5's widths: one
     `latent_decode_attn` custom call a layer under `decode_attention`, from
-    one lowered function; the pool donated and updated in place; and no
-    temporary that grows with the pool or with rows x table width x 576 (the
-    gathered context the `jax.numpy` body would make)."""
+    one lowered function, beside the expert layer's `expert_ffn`; the pool
+    donated and updated in place; and no temporary that grows with the pool
+    or with rows x table width x 576 (the gathered context the `jax.numpy`
+    body would make)."""
     layers = 2
     program = "serve_decode_b%d" % batch
     text, memory, layer_bytes = _latent_program(one_chip, "decode", batch,
@@ -540,8 +545,9 @@ def test_latent_decode_reads_the_pool_through_its_kernel(on_tpu, one_chip,
              if n.endswith("/pallas_call")]
     # (the compiler names the copies it puts around a call after it too)
     assert set(calls) == {"decode_attention/jit(_latent_decode)/"
-                          "latent_decode_attn/pallas_call"}
-    assert text.count("tpu_custom_call") == layers
+                          "latent_decode_attn/pallas_call", _EXPERT_CALL}
+    # one dense layer, the others expert layers
+    assert text.count("tpu_custom_call") == layers + layers - 1
     assert "input_output_alias" in text.splitlines()[0]
     assert memory.alias_size_in_bytes == layers * layer_bytes
     gathered = batch * 16384 * 576 * 2
@@ -553,7 +559,8 @@ def test_latent_prefill_holds_no_table_wide_scores(on_tpu, one_chip, chunk,
                                                    layers):
     """A chunk over a table of 16,384 positions at Kimi-K2.5's widths: one
     `latent_prefill_attn` custom call a layer under `mla_prefill_attention`,
-    from one lowered function, and no loop around it; the kernel keeps a
+    from one lowered function, and no loop around it (nor around the expert
+    layer's products, one `expert_ffn` call); the kernel keeps a
     step's scores in VMEM, so the program's temporaries are far from the
     2.1 GB that float32 scores of 512 x table width x heads would take (or
     the 0.7 GB of the whole context expanded to per-head keys and values),
@@ -569,10 +576,10 @@ def test_latent_prefill_holds_no_table_wide_scores(on_tpu, one_chip, chunk,
     names = _op_names(text, "serve_prefill_s%d" % chunk)
     calls = [n for n in names if n.endswith("/pallas_call")]
     assert set(calls) == {"mla_prefill_attention/jit(_latent_prefill)/"
-                          "latent_prefill_attn/pallas_call"}
-    assert text.count("tpu_custom_call") == layers
-    assert not any("mla_prefill_loop" in n for n in names)
-    assert any("moe_loop/while/body/moe_experts/" in n for n in names)
+                          "latent_prefill_attn/pallas_call", _EXPERT_CALL}
+    assert text.count("tpu_custom_call") == layers + layers - 1
+    assert not any("mla_prefill_loop" in n or "moe_loop" in n
+                   for n in names)
 
 
 # -- whole train steps (toy widths: seconds each) ---------------------------
@@ -716,10 +723,10 @@ def test_grouped_query_decode_reads_the_pool_through_the_kernel(
         on_tpu, one_chip, monkeypatch, batch):
     """A decode launch over the 8-head pool under 32 query heads, at the
     served widths: one `paged_decode_attn` custom call an attention layer
-    under `decode_attention`, from one lowered function; pool and state
-    donated and updated in place; no temporary that grows with the pool or
-    with rows x table width x 512 (the gathered context the `jax.numpy` body
-    would make)."""
+    under `decode_attention`, from one lowered function, and an `expert_ffn`
+    call an expert layer; pool and state donated and updated in place; no
+    temporary that grows with the pool or with rows x table width x 512 (the
+    gathered context the `jax.numpy` body would make)."""
     from mxnet_tpu.ops.pallas_kernels import paged_attention
 
     monkeypatch.setattr(paged_attention, "_INTERPRET", False)
@@ -731,8 +738,8 @@ def test_grouped_query_decode_reads_the_pool_through_the_kernel(
     calls = [n for n in _op_names(text, program)
              if n.endswith("/pallas_call")]
     assert set(calls) == {"decode_attention/jit(_paged_decode)/"
-                          "paged_decode_attn/pallas_call"}
-    assert text.count("tpu_custom_call") == model.attn_layers == 2
+                          "paged_decode_attn/pallas_call", _EXPERT_CALL}
+    assert text.count("tpu_custom_call") == model.attn_layers + 6 == 8
     assert "input_output_alias" in text.splitlines()[0]
     pool_bytes = 2 * 2 * 4609 * 64 * 512 * 2
     state_bytes = 6 * 65 * 2 * 2048 * 2
@@ -855,8 +862,9 @@ def test_the_hybrid_decode_reads_its_full_layers_through_the_kernel(
         on_tpu, one_chip, monkeypatch, batch):
     """A decode launch at MiMo-V2's widths: one `paged_decode_attn` custom
     call a full layer under `decode_attention`, at q/k 192 and v 128 with 16
-    query heads a K/V head, from one lowered function; the window layer
-    attends over its ring in `jax.numpy`; pool and rings donated and updated
+    query heads a K/V head, from one lowered function, and an `expert_ffn`
+    call an expert layer; the window layer attends over its ring in
+    `jax.numpy`; pool and rings donated and updated
     in place; no temporary that grows with the pool or with rows x table
     width x the K/V widths (the gathered context the `jax.numpy` body would
     make)."""
@@ -869,8 +877,8 @@ def test_the_hybrid_decode_reads_its_full_layers_through_the_kernel(
     calls = [n for n in _op_names(text, program)
              if n.endswith("/pallas_call")]
     assert set(calls) == {"decode_attention/jit(_paged_decode)/"
-                          "paged_decode_attn/pallas_call"}
-    assert text.count("tpu_custom_call") == model.full_layers == 1
+                          "paged_decode_attn/pallas_call", _EXPERT_CALL}
+    assert text.count("tpu_custom_call") == model.full_layers + 2 == 3
     assert "input_output_alias" in text.splitlines()[0]
     pool_bytes = 2049 * 64 * (768 + 512) * 2
     ring_bytes = 2 * 65 * 128 * 8 * 320 * 2
@@ -921,10 +929,51 @@ def test_the_hybrid_prefill_holds_no_table_wide_scores(on_tpu, one_chip,
     assert "input_output_alias" in text.splitlines()[0]
     assert not _table_wide_scores(text, chunk, 16384)
     names = _op_names(text, program)
-    assert not any("kv_gather" in n or "pallas_call" in n for n in names)
+    # (no attention kernel: the expert layer's is the only one)
+    assert not any("kv_gather" in n or "pallas_call" in n
+                   and not n.startswith(_EXPERT_CALL) for n in names)
     assert any(n.startswith("chunk_attention/while") for n in names)
     assert any("window_attention" in n for n in names)
     assert memory.temp_size_in_bytes < 64 * 512 * 16384 * 4 // 4
+
+
+# -- the expert layers at the served widths ----------------------------------
+
+
+def _kernel_calls(text, program):
+    """The op names of the program's Mosaic kernels, one a call."""
+    return [m.group(1) for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line
+            for m in [re.search(r'op_name="jit\(%s\)/([^"]+)"' % program,
+                                line)] if m]
+
+
+@pytest.mark.parametrize("model,what,n", [
+    ("lfm2", "decode", 64), ("lfm2", "prefill", 512),
+    ("kimi", "decode", 64), ("kimi", "prefill", 512)])
+def test_each_expert_layer_is_one_kernel_call(on_tpu, one_chip, model, what,
+                                              n):
+    """LFM2's and Kimi's `serve_decode_b64` and `serve_prefill_s512` at the
+    cells' widths: one `expert_ffn` call an expert layer under
+    `moe_experts`, from one lowered function, and no `moe_loop` (the
+    `fori_loop` of a step a tile that the kernel takes the place of).  The
+    compile passing is Mosaic's check that the rows, the accumulator and
+    the weights' two slots fit the VMEM the kernel asks for."""
+    from mxnet_tpu.ops.pallas_kernels import expert_ffn as ef
+
+    if model == "lfm2":
+        text = _shortconv_program(one_chip, what, n, KINDS)[0]
+        experts, d, f, block = 6, 2048, 1792, 1792
+    else:
+        text = _latent_program(one_chip, what, n)[0]
+        experts, d, f, block = 1, 7168, 2048, 512
+    program = "serve_%s_%s%d" % (what, "b" if what == "decode" else "s", n)
+    calls = [c for c in _kernel_calls(text, program) if "expert_ffn" in c]
+    assert calls == [_EXPERT_CALL] * experts
+    assert not any("moe_loop" in c for c in _op_names(text, program))
+    # an LFM2 expert's three matrices stream whole, a Kimi one's in four
+    assert ef.ffn_block(d, f, 2) == block
+    assert ef._vmem_bytes(n, d, block, 2) <= ef._VMEM_BYTES
 
 
 # -- the programs of the other served models, unchanged ------------------------
@@ -1006,15 +1055,16 @@ def _todays_model(one_chip, which):
     return model, _bf16((2, 513, 64, model.pool_width), one_chip), False
 
 
-#: digests of the programs as the parent of the K/V pair pool's two widths
-#: lowered them (`_digest`)
+#: digests of the programs (`_digest`): GPT-2's as the parent of the K/V
+#: pair pool's two widths lowered them, LFM2's and Kimi's as they are since
+#: their expert layers run as the kernel `expert_ffn`
 _TODAY = {
     ("gpt2", "decode", 8): "efc3ab88f2af9e8a",
     ("gpt2", "prefill", 512): "4e3bc85e130ec77e",
-    ("lfm2", "decode", 8): "003f4b149f78609a",
-    ("lfm2", "prefill", 512): "5532c5c08917e272",
-    ("kimi", "decode", 8): "bf54583811a56970",
-    ("kimi", "prefill", 512): "a851ae9522756a11",
+    ("lfm2", "decode", 8): "084c7b792ff7e0a0",
+    ("lfm2", "prefill", 512): "edb25a7e4c3f7c0b",
+    ("kimi", "decode", 8): "76270e14e208675d",
+    ("kimi", "prefill", 512): "4345b5dda78e1134",
 }
 
 
@@ -1024,7 +1074,8 @@ def test_the_other_models_lowered_programs_are_unchanged(
     """GPT-2's, LFM2's and Kimi's decode and prefill programs, lowered for
     the chip at their cells' widths, are the ones they were before the
     decode kernel took K and V at two widths, the chunk path a pair, and
-    `rope` a rotary width: kernels and all."""
+    `rope` a rotary width (GPT-2's), and since the expert layers became one
+    kernel each (LFM2's and Kimi's): kernels and all."""
     from mxnet_tpu.ops.pallas_kernels import paged_attention
 
     monkeypatch.setattr(paged_attention, "_INTERPRET", False)
