@@ -125,11 +125,18 @@ def trace(logdir, create_perfetto_link=False):
         jax.profiler.stop_trace()
 
 
-def annotate(name):
+def annotate(name, **meta):
     """Named span on the host's line of the profiler's trace, on the same
     clock as the device's operations.  Costs a flag test when no trace is
-    being taken."""
-    return jax.profiler.TraceAnnotation(name)
+    being taken.  ``meta`` (name=number) rides on the span's event as its
+    stats; building it costs more than the span, so pass it only where
+    `taking_trace()` holds."""
+    return jax.profiler.TraceAnnotation(name, **meta)
+
+
+def taking_trace():
+    """Whether a profiler trace is being taken now (a flag test)."""
+    return jax.profiler.TraceAnnotation.is_enabled()
 
 
 def save_device_memory_profile(path, backend=None):

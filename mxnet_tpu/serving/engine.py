@@ -153,13 +153,46 @@ def _env_flag(name, default="1"):
     return os.environ.get(name, default).lower() not in ("0", "false", "no")
 
 
-def _phase(name):
-    """One phase of a scheduler iteration as a `sched.<name>` span on the
-    scheduler thread's line of the profiler's trace (docs/observability.md
-    "In the profiler's trace"): on the device trace's clock by
-    construction, a flag test when no trace is being taken.  The three
-    decode bodies use the same name where they do the same thing."""
-    return profiler.annotate("sched." + name)
+#: the leaf phases of the scheduler, whose host seconds each `iteration`
+#: record carries as `phase_ms` (docs/observability.md "Request tracing")
+SCHED_PHASES = ("sweep", "prefill", "admit", "grow", "pack", "launch",
+                "fetch", "publish", "account", "wait")
+_NO_PHASE_S = dict.fromkeys(SCHED_PHASES, 0.0)
+
+
+class _Phase:
+    """One phase of the scheduler, timed both ways: a `sched.<name>` span
+    on the scheduler thread's line of the profiler's trace
+    (docs/observability.md "In the profiler's trace"), on the device
+    trace's clock by construction, and its `perf_counter` seconds added
+    into ``acc``, the engine's table since the last `iteration` record
+    (None where the span store is off: a flag test, no clock read).  The
+    three decode bodies use the same name where they do the same thing.
+    ``meta`` rides on the span as its stats."""
+
+    __slots__ = ("_acc", "_name", "_ann", "_t")
+
+    def __init__(self, acc, name, **meta):
+        self._acc, self._name = acc, name
+        self._ann = profiler.annotate("sched." + name, **meta)
+
+    def __enter__(self):
+        self._ann.__enter__()
+        if self._acc is not None:
+            self._t = time.perf_counter()
+        return self
+
+    def split(self):
+        """Add the seconds so far and go on timing from now: the part of
+        the phase a record written inside it carries."""
+        now = time.perf_counter()
+        self._acc[self._name] += now - self._t
+        self._t = now
+
+    def __exit__(self, *exc):
+        if self._acc is not None:
+            self._acc[self._name] += time.perf_counter() - self._t
+        return self._ann.__exit__(*exc)
 
 
 class _EngineFatal(Exception):
@@ -811,6 +844,9 @@ class ServingEngine:
         self._qcond = threading.Condition(self._qlock)
         self._admitting = 0       # popped off _queue, prefill in flight
         self._iter = {}           # the iteration's counts, anew each step()
+        # host seconds by phase since the last `iteration` record
+        self._phase_s = dict.fromkeys(SCHED_PHASES, 0.0) \
+            if tracing.enabled() else None
         self._active = {}         # row -> _Seq (insertion-ordered)
         self._free = list(range(self.max_batch))
         self._stopped = threading.Event()
@@ -3306,50 +3342,78 @@ class ServingEngine:
         residue.  Only iterations that actually launched accumulate (an
         idle or admission-only iteration has no decode loop to
         attribute), and each leaves one replica-scoped `iteration` record
-        in the span store (docs/observability.md "Request tracing").  The
-        whole body is a `sched.iteration` span in a profiler trace.
+        in the span store (docs/observability.md "Request tracing"), with
+        the host seconds of every phase since the last record (an
+        iteration that launched nothing, and the loop's waits, carry over
+        into the next).  The whole body is a `sched.iteration` span in a
+        profiler trace, which carries the record's ``t0`` as `t0_ns`.
         Returns the number of sequences still active (0 = idle)."""
         t0 = time.perf_counter()
         h0 = self.stats["hidden_s"]
         c0 = self.stats["prefill_chunks"]
         r0 = self.stats["decode_rows"] + self.stats["prefill_tokens"]
-        # fold settled expert-load rows (all but the newest — it may
-        # still be in flight) into the per-expert gauges
-        self._drain_moe()
-        self._iter = {}
-        with _phase("iteration"):
+        # the anchor costs more than the span: built only while a trace
+        # is being taken
+        anchor = {"t0_ns": int(t0 * 1e9)} if profiler.taking_trace() \
+            else {}
+        # the span around the leaves, itself timed by no table
+        with _Phase(None, "iteration", **anchor):
+            with self._phase("account"):
+                # fold settled expert-load rows (all but the newest — it
+                # may still be in flight) into the per-expert gauges
+                self._drain_moe()
+            self._iter = {}
             if self._mega_m and not self._spec:
                 n = self._step_mega()
             else:
                 n = self._step()
-        if self._moe:
-            self.stats["moe_pairs_routed"] += self.model.moe_pairs_per_row \
-                * (self.stats["decode_rows"] + self.stats["prefill_tokens"]
-                   - r0)
-        dh = self.stats["hidden_s"] - h0
-        if dh > 0:
-            # a single-step iteration has fetched its launch's tokens, so
-            # every count pending is on the host's side of it
-            self._drain_moe(keep_last=bool(self._mega_m))
-            self._iter.update(self._moe_record())
-            t1 = time.perf_counter()
-            wall = t1 - t0
-            self.stats["wall_s"] += wall
-            self.stats["host_s"] += max(0.0, wall - dh)
-            telemetry.set_gauge(
-                self._gauge + "host_frac",
-                round(self.stats["host_s"] / self.stats["wall_s"], 4))
-            # the replica-scoped iteration record: the counts as taken
-            # where the work happened, in this iteration
-            tracing.add_span(
-                0, "iteration", self.name, t0, t1,
-                chunks=self.stats["prefill_chunks"] - c0, **self._iter)
+            with self._phase("account") as account:
+                if self._moe:
+                    self.stats["moe_pairs_routed"] += \
+                        self.model.moe_pairs_per_row \
+                        * (self.stats["decode_rows"]
+                           + self.stats["prefill_tokens"] - r0)
+                dh = self.stats["hidden_s"] - h0
+                if dh > 0:
+                    self._record_iteration(t0, dh, c0, account)
         return n
+
+    def _record_iteration(self, t0, dh, c0, account):
+        """The host_frac accounting of an iteration that launched, and its
+        `iteration` record, written inside its ``account`` phase."""
+        # a single-step iteration has fetched its launch's tokens, so
+        # every count pending is on the host's side of it
+        self._drain_moe(keep_last=bool(self._mega_m))
+        self._iter.update(self._moe_record())
+        t1 = time.perf_counter()
+        wall = t1 - t0
+        self.stats["wall_s"] += wall
+        self.stats["host_s"] += max(0.0, wall - dh)
+        telemetry.set_gauge(
+            self._gauge + "host_frac",
+            round(self.stats["host_s"] / self.stats["wall_s"], 4))
+        acc = self._phase_s
+        if acc is not None:
+            # the phase's part so far rides in this record, the rest in
+            # the next
+            account.split()
+            self._iter["phase_ms"] = {k: round(1e3 * v, 3)
+                                      for k, v in acc.items()}
+            acc.update(_NO_PHASE_S)
+        # the replica-scoped iteration record: the counts as taken where
+        # the work happened, in this iteration
+        tracing.add_span(
+            0, "iteration", self.name, t0, t1,
+            chunks=self.stats["prefill_chunks"] - c0, **self._iter)
+
+    def _phase(self, name):
+        """A leaf phase (one of `SCHED_PHASES`) of this engine."""
+        return _Phase(self._phase_s, name)
 
     def _grow(self):
         """Cover the active rows' next positions with blocks, then the
         iteration's full pool gauges."""
-        with _phase("grow"):
+        with self._phase("grow"):
             self._grow_active()
             self._iter["blocks_live"], self._iter["blocks_parked"] = \
                 self._block_gauges(full=True)
@@ -3364,7 +3428,7 @@ class ServingEngine:
         new prefill chunks and admissions: their transfers already
         overlapped the previous decode launch (handoff landings ride
         the same two-stage overlap)."""
-        with _phase("prefill"):
+        with self._phase("prefill"):
             self._advance_restores()
             self._advance_landings()
             self._advance_prefills()
@@ -3372,7 +3436,7 @@ class ServingEngine:
 
     def _admit(self):
         """Admit queued requests while a row is free."""
-        with _phase("admit"):
+        with self._phase("admit"):
             while self._free:
                 with self._qlock:
                     req = self._queue.popleft() if self._queue else None
@@ -3415,7 +3479,7 @@ class ServingEngine:
                 if evicted:
                     self._alloc.reclaim(evicted)
                     self._count_evictions(len(evicted))
-        with _phase("sweep"):
+        with self._phase("sweep"):
             self._sweep()
         self._advance_staged()
         self._admit()
@@ -3456,7 +3520,7 @@ class ServingEngine:
             return len(self._active) + self._pending_work()
         b = self._bucket_for(n, self.decode_buckets)
         seqs = [self._active[s] for s in rows]
-        with _phase("pack"):
+        with self._phase("pack"):
             token = np.zeros((b,), np.int32)
             pos = np.zeros((b,), np.int32)
             tables = np.full((b, self._n_table), TRASH_BLOCK, np.int32)
@@ -3482,7 +3546,7 @@ class ServingEngine:
         try:
             if chaos.serve_launch_error():
                 raise chaos.ChaosError("chaos: injected decode launch error")
-            with _phase("launch"):
+            with self._phase("launch"):
                 nxt, self._cache = self._unpack(
                     compiled(self._params, self._cache, *args))
         except Exception as e:
@@ -3492,7 +3556,7 @@ class ServingEngine:
             return len(self._active) + self._pending_work()
         self._launch_fails = 0
         t_fetch = time.perf_counter()
-        with _phase("fetch"):
+        with self._phase("fetch"):
             nxt = np.asarray(nxt)  # the one per-step host fetch (b ints)
         now = time.perf_counter()
         self.stats["fetch_wait_s"] += now - t_fetch
@@ -3505,7 +3569,7 @@ class ServingEngine:
         telemetry.inc("serve.tokens", n)
         telemetry.inc("serve.decode_padded", b - n)
         telemetry.set_gauge(self._gauge + "batch_occupancy", n / float(b))
-        with _phase("publish"):
+        with self._phase("publish"):
             for i, (row, seq) in enumerate(zip(rows, seqs)):
                 t = int(nxt[i])
                 if t < 0:
@@ -3558,7 +3622,7 @@ class ServingEngine:
             inflight = self._launch_mega()
         # -- overlap window: host work the device no longer waits on --
         t_sweep = time.perf_counter()
-        with _phase("sweep"):
+        with self._phase("sweep"):
             self._sweep()
         self._advance_staged()
         self._admit()
@@ -3601,7 +3665,7 @@ class ServingEngine:
             return None
         b = self._bucket_for(nrows, self.decode_buckets)
         seqs = [self._active[s] for s in rows]
-        with _phase("pack"):
+        with self._phase("pack"):
             token = np.zeros((b,), np.int32)
             pos = np.zeros((b,), np.int32)
             left = np.zeros((b,), np.int32)
@@ -3628,7 +3692,7 @@ class ServingEngine:
             if chaos.serve_launch_error():
                 raise chaos.ChaosError(
                     "chaos: injected megastep launch error")
-            with _phase("launch"):
+            with self._phase("launch"):
                 out, self._cache = self._unpack(
                     compiled(self._params, self._cache, *args))
         except Exception as e:
@@ -3648,7 +3712,7 @@ class ServingEngine:
         already-finished)."""
         rows, seqs, out, nrows, b, t_launch = inflight
         t_fetch = time.perf_counter()
-        with _phase("fetch"):
+        with self._phase("fetch"):
             out = np.asarray(out)  # the one per-megastep host fetch
         now = time.perf_counter()
         self.stats["fetch_wait_s"] += now - t_fetch
@@ -3666,7 +3730,7 @@ class ServingEngine:
         telemetry.set_gauge(self._gauge + "batch_occupancy",
                             nrows / float(b))
         emitted = retired = 0
-        with _phase("publish"):
+        with self._phase("publish"):
             for i, (row, seq) in enumerate(zip(rows, seqs)):
                 if self._active.get(row) is not seq:
                     # swept, preempted or vacated while in flight: its
@@ -3812,7 +3876,7 @@ class ServingEngine:
         k = self._spec_k
         c = k + 1
         seqs = [self._active[r] for r in rows]
-        with _phase("pack"):
+        with self._phase("pack"):
             token = np.zeros((b, c), np.int32)
             pos = np.zeros((b,), np.int32)
             length = np.ones((b,), np.int32)
@@ -3845,7 +3909,7 @@ class ServingEngine:
             if self._mega_m:
                 return self._decode_mega()
             return self._decode_plain()
-        with _phase("pack"):
+        with self._phase("pack"):
             if chaos.enabled() and chaos.serve_draft_junk():
                 # `draft_junk:P`: deterministically corrupt the round's
                 # proposals — parity must hold, only the accept rate drops
@@ -3869,7 +3933,7 @@ class ServingEngine:
             if chaos.serve_launch_error():
                 raise chaos.ChaosError("chaos: injected verify launch "
                                        "error")
-            with _phase("launch"):
+            with self._phase("launch"):
                 out, self._cache = self._unpack(
                     compiled(self._params, self._cache, *args))
         except Exception as e:
@@ -3877,7 +3941,7 @@ class ServingEngine:
             return len(self._active) + self._pending_work()
         self._launch_fails = 0
         t_fetch = time.perf_counter()
-        with _phase("fetch"):
+        with self._phase("fetch"):
             out = np.asarray(out)  # (b, k+2): picks then n_accepted
         now = time.perf_counter()
         self.stats["fetch_wait_s"] += now - t_fetch
@@ -3892,7 +3956,7 @@ class ServingEngine:
         telemetry.set_gauge(self._gauge + "batch_occupancy", n / float(b))
         emitted_total = 0
         seqs_n_new = [s.n_new for s in seqs]
-        with _phase("publish"):
+        with self._phase("publish"):
             for i, (row, seq) in enumerate(zip(rows, seqs)):
                 # drafts past this row's in-range span can never be emitted
                 # (their K/V went to the trash block); clamp acceptance so
@@ -3979,7 +4043,9 @@ class ServingEngine:
                     queued = bool(self._queue) or \
                         bool(self._handoff_inbox)
                 if not queued and not self._stopped.is_set():
-                    self._wake.wait(0.05)
+                    # outside any iteration: the engine has no work
+                    with self._phase("wait"):
+                        self._wake.wait(0.05)
 
     def _die(self, msg):
         """Scheduler death: release every admitted request's cache state

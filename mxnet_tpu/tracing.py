@@ -32,7 +32,9 @@ Span model (docs/observability.md "Request tracing"):
   ``spec_round``) reusing the PR-16 launch→fetch stamps, and one
   ``iteration`` span per scheduler iteration that launched, carrying the
   counts taken where the work happens (rows, bucket, chunks, queued,
-  blocks_live, blocks_parked).
+  blocks_live, blocks_parked) and ``phase_ms``, the host milliseconds of
+  each scheduler phase since the record before (so the records tile the
+  scheduler's time, its waits for work included).
 
 The span store: every replica keeps a bounded ring of the last N span
 closes and events (`MXNET_TRACE_RING`, 16,384: minutes of serving).  It is
@@ -79,7 +81,8 @@ PHASES = (
     "megastep",       # replica: one m-step launch->fetch window
     "host_sweep",     # replica: the overlap-window host work
     "spec_round",     # replica: one draft->verify->accept round
-    "iteration",      # replica: one scheduler iteration that launched
+    "iteration",      # replica: one scheduler iteration that launched,
+    #                   with the host time of each phase since the last
     "gateway_send",   # leaf: gateway submit -> last SSE byte flushed
 )
 

@@ -48,6 +48,7 @@ from mxnet_tpu import chaos, telemetry, tracing
 from mxnet_tpu.analysis import run as lint_run
 from mxnet_tpu.serving import (ReplicaRouter, ServingEngine,
                                TransformerKVModel, ServeError)
+from mxnet_tpu.serving.engine import SCHED_PHASES
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TRACE_REPORT = os.path.join(REPO, "tools", "trace_report.py")
@@ -416,13 +417,13 @@ def test_kill_switch_bit_for_bit(model_and_params, monkeypatch):
         toks = [r.result(1) for r in reqs]
         retraces = [e for e in telemetry.events("retrace")
                     if str(e.get("site", "")).startswith("serving.")]
-        return toks, sink.records, retraces
+        return toks, sink.records, retraces, eng._phase_s
 
-    traced, traced_recs, traced_retraces = leg()
+    traced, traced_recs, traced_retraces, traced_s = leg()
     telemetry.reset()
     tracing.reset()
     monkeypatch.setenv("MXNET_SERVE_TRACING", "0")
-    off, off_recs, off_retraces = leg()
+    off, off_recs, off_retraces, off_s = leg()
 
     assert off == traced                      # bit-for-bit tokens
     assert traced_retraces == [] and off_retraces == []
@@ -430,6 +431,7 @@ def test_kill_switch_bit_for_bit(model_and_params, monkeypatch):
     assert not any(r.get("type") in ("span", "flight_recorder")
                    for r in off_recs)
     assert tracing._TRACER is None            # never even built
+    assert set(traced_s) == set(SCHED_PHASES) and off_s is None
     assert not any(k.startswith("serve.attr.")
                    for k in telemetry.registry()._hists)
 
@@ -715,7 +717,8 @@ def test_iteration_record_counts_what_the_iteration_did(model_and_params):
         a = s["attrs"]
         assert set(a) == {"rows", "bucket", "chunks", "queued",
                           "blocks_live", "blocks_parked", "attn_kernel",
-                          "ctx_blocks"}
+                          "ctx_blocks", "phase_ms"}
+        assert set(a["phase_ms"]) == set(SCHED_PHASES)
         # the table entries the rows' attention walks: their live blocks
         assert a["rows"] <= a["ctx_blocks"]
         assert 1 <= a["rows"] <= a["bucket"] and a["bucket"] in (2, 4)
@@ -773,7 +776,7 @@ def test_iteration_blocks_live_excludes_parked_blocks(model_and_params):
 
 def test_sched_phases_reach_the_profilers_trace(model_and_params, tmp_path):
     """The scheduler's phases are `TraceAnnotation`s: a profiler session
-    around a few iterations finds `sched.iteration` and its eight leaf
+    around a few iterations finds `sched.iteration` and its nine leaf
     phases on the host's plane (read with the benchmark's raw reader)."""
     import jax
 
@@ -802,7 +805,145 @@ def test_sched_phases_reach_the_profilers_trace(model_and_params, tmp_path):
                 names[name] = names.get(name, 0) + 1
     assert set(names) == {"sched." + p for p in (
         "iteration", "sweep", "prefill", "admit", "grow", "pack", "launch",
-        "fetch", "publish")}
+        "fetch", "publish", "account")}
     assert names["sched.launch"] == names["sched.fetch"] \
         == names["sched.publish"] == eng.stats["decode_steps"]
     assert names["sched.iteration"] >= names["sched.launch"]
+
+
+# ---------------------------------------------------------------------------
+# 11. every phase's host time, in the store and on the profiler's clock
+# ---------------------------------------------------------------------------
+
+def _served_by_its_thread(eng, prompts, gap_s):
+    """Submit ``prompts`` to a start()ed engine ``gap_s`` apart, so that it
+    waits for work between them; returns the window's `iteration`
+    records."""
+    eng.warmup()
+    t0 = time.perf_counter()
+    eng.start()
+    try:
+        reqs = []
+        for p in prompts:
+            reqs.append(eng.submit(p, max_new_tokens=4))
+            time.sleep(gap_s)
+        for r in reqs:
+            r.result(timeout=120)
+        time.sleep(0.1)          # waits, carried into no record
+    finally:
+        eng.stop()
+    return [s for s in tracing.window(eng.name, t0, time.perf_counter())
+            if s["phase"] == "iteration"]
+
+
+def test_iteration_records_tile_the_schedulers_time(model_and_params):
+    """Each record carries the host milliseconds of the ten phases since
+    the record before: the records after the first cover the time between
+    the first's close and the last's, waits for arrivals included, and
+    count none of it twice."""
+    model, params = model_and_params
+    eng = _engine(model, params, name="ph0", block_size=4, n_blocks=32,
+                  decode_buckets=[2, 4])
+    its = _served_by_its_thread(eng, [list(range(1, 10)), [20, 21, 22],
+                                      list(range(30, 36))], 0.2)
+    assert len(its) >= 6
+    assert all(set(s["attrs"]["phase_ms"]) == set(SCHED_PHASES)
+               for s in its)
+    span_ms = 1e3 * (its[-1]["t1"] - its[0]["t1"])
+    total = sum(sum(s["attrs"]["phase_ms"].values()) for s in its[1:])
+    assert 0.9 * span_ms <= total <= span_ms + 1.0
+    # the engine waited between the arrivals (0.4 s, of which serving a
+    # request takes a few tens of ms here), and said so in the next
+    # record; every launch was packed, launched, fetched and published
+    waits = sum(s["attrs"]["phase_ms"]["wait"] for s in its[1:])
+    assert waits >= 100.0
+    for s in its:
+        ms = s["attrs"]["phase_ms"]
+        assert all(v >= 0 for v in ms.values())
+        assert min(ms["pack"], ms["launch"], ms["fetch"], ms["publish"],
+                   ms["account"]) > 0
+
+
+def test_sched_wait_reaches_the_profilers_trace(model_and_params, tmp_path):
+    """A start()ed engine with no work waits inside a `sched.wait` span,
+    outside any `sched.iteration`."""
+    import jax
+
+    sys.path.insert(0, REPO)
+    from benchmark import xplane_raw
+
+    model, params = model_and_params
+    eng = _engine(model, params, name="ph1", block_size=4, n_blocks=32,
+                  decode_buckets=[2])
+    eng.warmup()
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        eng.start()
+        time.sleep(0.2)
+        req = eng.submit(list(range(1, 10)), max_new_tokens=4)
+        req.result(timeout=120)
+        time.sleep(0.2)
+    finally:
+        eng.stop()
+        jax.profiler.stop_trace()
+    path = xplane_raw.trace.find_xplane(str(tmp_path))
+    spans = {}
+    for plane in xplane_raw.planes(path, host_prefixes=("sched.",)):
+        for events in plane["lines"].values():
+            for s, d, meta in events:
+                spans.setdefault(meta["name"].split("#")[0], []).append(
+                    (s, s + d))
+    assert len(spans["sched.wait"]) >= 2
+    assert "sched.account" in spans and "sched.launch" in spans
+    for lo, hi in spans["sched.wait"]:
+        assert not any(a < hi and lo < b for a, b in spans["sched.iteration"])
+
+
+def test_iteration_span_carries_the_records_clock(model_and_params,
+                                                  tmp_path):
+    """`sched.iteration` carries the store's `t0` as its `t0_ns` stat: one
+    subtraction maps the store's clock onto the trace's, and every
+    launching iteration's record then lies within 1 ms of its span."""
+    import jax
+
+    model, params = model_and_params
+    eng = _engine(model, params, name="ph2", block_size=4, n_blocks=32,
+                  decode_buckets=[2])
+    eng.warmup()
+    t0 = time.perf_counter()
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        req = eng.submit(list(range(1, 10)), max_new_tokens=5)
+        eng.run_until_idle(timeout=120)
+    finally:
+        jax.profiler.stop_trace()
+    assert req.error is None
+    its = [s for s in tracing.window("ph2", t0, time.perf_counter())
+           if s["phase"] == "iteration"]
+    assert len(its) == eng.stats["decode_steps"] >= 4
+
+    sys.path.insert(0, REPO)
+    from benchmark import trace
+
+    data = jax.profiler.ProfileData.from_file(
+        trace.find_xplane(str(tmp_path)))
+    events = {}        # t0_ns -> (start, end) in the trace's nanoseconds
+    for plane in data.planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("sched.iteration"):
+                    stats = dict(ev.stats)
+                    events[int(stats["t0_ns"])] = (
+                        ev.start_ns, ev.start_ns + ev.duration_ns)
+    assert len(events) >= len(its)
+    # the clock's offset, from one span
+    anchor, (start, _) = min(events.items())
+    offset = start - anchor
+    for s in its:
+        lo, hi = events[int(s["t0"] * 1e9)]
+        assert abs(s["t0"] * 1e9 + offset - lo) < 1e6
+        assert abs(s["t1"] * 1e9 + offset - hi) < 1e6
