@@ -2241,9 +2241,11 @@ class ServingEngine:
     def _samp_device(self, reqs, b):
         """Per-row device sampling arrays for rows ``reqs`` padded to
         bucket ``b`` (padding rows: temperature 0 = greedy, output
-        discarded).  () when sampling programs are disabled."""
+        discarded), and how many rows sample (temperature > 0: the
+        program runs its sampling body only where that is not 0).
+        ((), 0) when sampling programs are disabled."""
         if not self._sampling:
-            return ()
+            return (), 0
         temp = np.zeros((b,), np.float32)
         tk = np.zeros((b,), np.int32)
         tp = np.ones((b,), np.float32)
@@ -2253,7 +2255,8 @@ class ServingEngine:
             tk[i] = r.top_k
             tp[i] = r.top_p
             seed[i] = r.seed
-        return tuple(self._put(a) for a in (temp, tk, tp, seed))
+        return (tuple(self._put(a) for a in (temp, tk, tp, seed)),
+                int(np.count_nonzero(temp > 0)))
 
     # -- admission / chunked prefill ---------------------------------------
     def _admit_one(self, req):
@@ -2824,7 +2827,7 @@ class ServingEngine:
             start_d = self._put(np.array([pf.done], np.int32))
             length_d = self._put(np.array([chunk], np.int32))
             table_d = self._put(table)
-            samp = self._samp_device([req], 1)
+            samp, sampled = self._samp_device([req], 1)
             tail = tuple(self._put(a)
                          for a in self._slots((pf.row,), 1)) + samp
             self._watch("prefill",
@@ -2872,7 +2875,7 @@ class ServingEngine:
         telemetry.inc("serve.prefill_chunks")
         tracing.add_span(req.id, "prefill_chunk", self.name, t_chunk,
                          time.perf_counter(), start=pf.done - chunk,
-                         tokens=chunk,
+                         tokens=chunk, sampled=sampled,
                          attn_kernel=self._prefill_kernel[bucket])
         # publish the chunk's newly-FULL blocks (a block whose bucket
         # tail is padding garbage stays private: `done` counts only real
@@ -3528,7 +3531,7 @@ class ServingEngine:
                 token[i] = seq.last
                 pos[i] = seq.pos
                 tables[i, :len(seq.blocks)] = seq.blocks
-            samp = self._samp_device([s.req for s in seqs], b)
+            samp, sampled = self._samp_device([s.req for s in seqs], b)
             args = tuple(self._put(a) for a in (token, pos, tables)
                          + self._slots(rows, b)) + samp
             self._watch("decode", args,
@@ -3537,7 +3540,7 @@ class ServingEngine:
         # table entries the rows' attention walks: their live blocks
         ctx = int(np.sum(pos[:n] // self.block_size) + n)
         self._iter.update(rows=n, bucket=b, attn_kernel=self._attn_kernel,
-                          ctx_blocks=ctx)
+                          ctx_blocks=ctx, sampled_rows=sampled)
         if getattr(self.model, "ring_layers", 0):
             # a model whose window layers keep no blocks: the blocks its
             # full layers' attention reads, all of them
@@ -3678,7 +3681,7 @@ class ServingEngine:
                 if seq.req.eos_id is not None:
                     eos[i] = int(seq.req.eos_id)
                 tables[i, :len(seq.blocks)] = seq.blocks
-            samp = self._samp_device([s.req for s in seqs], b)
+            samp, sampled = self._samp_device([s.req for s in seqs], b)
             args = (self._put(token), self._put(pos), self._put(left),
                     self._put(eos), self._put(tables)) + samp
             self._watch("megastep", args,
@@ -3686,7 +3689,8 @@ class ServingEngine:
                         + self._SAMPLE_NAMES[:len(samp)], b)
             compiled = self._compiled_mega(b)
         self._iter.update(rows=nrows, bucket=b,
-                          attn_kernel=self._attn_kernel)
+                          attn_kernel=self._attn_kernel,
+                          sampled_rows=sampled)
         t_launch = time.perf_counter()
         try:
             if chaos.serve_launch_error():
@@ -3888,7 +3892,7 @@ class ServingEngine:
                 tables[i, :len(seq.blocks)] = seq.blocks
             pos_d = self._put(pos)
             tables_d = self._put(tables)
-            samp = self._samp_device([s.req for s in seqs], b)
+            samp, sampled = self._samp_device([s.req for s in seqs], b)
             tok0 = token[:, 0].copy()
             dev = (self._put(tok0), pos_d, tables_d) \
                 if self._drafter.needs_device else None
@@ -3927,7 +3931,8 @@ class ServingEngine:
                         + self._SAMPLE_NAMES[:len(samp)], b)
             compiled = self._compiled_verify(b)
         # a verify launch attends with `verify_attention`, never the kernel
-        self._iter.update(rows=n, bucket=b, attn_kernel=0)
+        self._iter.update(rows=n, bucket=b, attn_kernel=0,
+                          sampled_rows=sampled)
         t_launch = time.perf_counter()
         try:
             if chaos.serve_launch_error():

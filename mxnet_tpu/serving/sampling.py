@@ -3,9 +3,14 @@
 The engine's compiled programs have FIXED shapes (the zero-retrace
 contract), so sampling configuration cannot branch the program: every
 knob is a per-row ARRAY argument and every mode runs through one traced
-body.  Greedy is temperature <= 0 (the argmax path, bit-identical to the
-PR-7 greedy programs); temperature / top-k / top-p compose the standard
-way (scale, then k-mask, then nucleus-mask, then categorical draw).
+program.  Greedy is temperature <= 0 (the argmax path, bit-identical to
+the PR-7 greedy programs); temperature / top-k / top-p compose the
+standard way (scale, then k-mask, then nucleus-mask, then categorical
+draw).  The program branches at run time on the batch's own
+temperatures: a batch with no sampled row takes the argmax alone and
+skips the vocabulary sort, the masks and the draw; a batch with any
+sampled row runs the whole sampling body for every row, and its greedy
+rows keep the same argmax.  Tokens are the same either way.
 
 Determinism is request-keyed, not batch-keyed: the draw for the token
 that will occupy absolute position P of request R uses
@@ -34,7 +39,7 @@ __all__ = ["sample_tokens"]
 
 def _mask_top_k_top_p(scaled, top_k, top_p):
     """Compose the top-k and nucleus masks off ONE descending sort (this
-    runs in every sampling-program decode step — a second full-vocab
+    runs in every decode step with a sampled row — a second full-vocab
     sort would be pure waste: masking to -inf only moves entries to the
     tail the first sort already built).  Top-k keeps the k largest
     (k <= 0 disables); top-p then keeps the smallest prefix of the
@@ -77,18 +82,29 @@ def sample_tokens(logits, temperature, top_k, top_p, seed, newpos):
     newpos:      (b,) int32 — the absolute position the sampled token
                  will occupy (prefill: prompt length; decode: pos + 1)
     Returns (b,) int32 token ids.
+
+    A batch with no row at temperature > 0, padding rows included, takes
+    the argmax alone (a run-time ``lax.cond``).  Under ``vmap`` the cond
+    would turn into a select that runs both branches: no caller maps
+    this function.
     """
     logits = logits.astype(jnp.float32)
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    t = jnp.where(temperature > 0, temperature, 1.0).astype(jnp.float32)
-    scaled = logits / t[:, None]
-    masked = _mask_top_k_top_p(scaled, top_k, top_p)
 
-    def draw(seed_i, pos_i, row):
-        key = jax.random.fold_in(jax.random.PRNGKey(seed_i), pos_i)
-        return jax.random.categorical(key, row)
+    def greedy(logits):
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
-    sampled = jax.vmap(draw)(seed.astype(jnp.uint32),
-                             newpos.astype(jnp.int32),
-                             masked).astype(jnp.int32)
-    return jnp.where(temperature > 0, sampled, greedy)
+    def sample(logits):
+        t = jnp.where(temperature > 0, temperature, 1.0).astype(jnp.float32)
+        scaled = logits / t[:, None]
+        masked = _mask_top_k_top_p(scaled, top_k, top_p)
+
+        def draw(seed_i, pos_i, row):
+            key = jax.random.fold_in(jax.random.PRNGKey(seed_i), pos_i)
+            return jax.random.categorical(key, row)
+
+        sampled = jax.vmap(draw)(seed.astype(jnp.uint32),
+                                 newpos.astype(jnp.int32),
+                                 masked).astype(jnp.int32)
+        return jnp.where(temperature > 0, sampled, greedy(logits))
+
+    return jax.lax.cond(jnp.any(temperature > 0), sample, greedy, logits)

@@ -717,8 +717,10 @@ def test_iteration_record_counts_what_the_iteration_did(model_and_params):
         a = s["attrs"]
         assert set(a) == {"rows", "bucket", "chunks", "queued",
                           "blocks_live", "blocks_parked", "attn_kernel",
-                          "ctx_blocks", "phase_ms"}
+                          "ctx_blocks", "sampled_rows", "phase_ms"}
         assert set(a["phase_ms"]) == set(SCHED_PHASES)
+        # greedy programs: no row samples
+        assert a["sampled_rows"] == 0
         # the table entries the rows' attention walks: their live blocks
         assert a["rows"] <= a["ctx_blocks"]
         assert 1 <= a["rows"] <= a["bucket"] and a["bucket"] in (2, 4)
