@@ -101,6 +101,7 @@ from .decode import TransformerKVModel
 from .latent import LatentMoEKVModel
 from .shortconv import ShortConvMoEKVModel
 from .window import HybridWindowMoEKVModel
+from .linear import HybridDeltaKVModel
 from .gateway import ServeGateway, gateway_enabled, http_status
 from .engine import ServeRequest, ServingEngine, ReplicaRouter
 from .handoff import HandoffTicket, disagg_enabled
@@ -116,7 +117,7 @@ from .errors import (ServeError, ServeTimeout, ServeOverload,
                      ServeQuantError)
 
 __all__ = ["TransformerKVModel", "LatentMoEKVModel", "ShortConvMoEKVModel",
-           "HybridWindowMoEKVModel",
+           "HybridWindowMoEKVModel", "HybridDeltaKVModel",
            "ServeRequest", "ServingEngine",
            "ReplicaRouter", "HandoffTicket", "disagg_enabled",
            "ServeGateway", "gateway_enabled", "http_status",

@@ -926,6 +926,10 @@ class ServingEngine:
                       # pairs the launches routed over all experts, and
                       # those of them that fell on experts held here
                       "moe_pairs_routed": 0, "moe_pairs_held": 0,
+                      # device bytes of one sequence's per-sequence state
+                      # (0 for a model that keeps none)
+                      "state_slot_bytes": int(model.state_slot_bytes())
+                      if self._state_slots else 0,
                       # decode-loop accounting behind the host_frac
                       # gauge: hidden_s spans launch-dispatch -> fetch-
                       # complete (host work inside it rides under the

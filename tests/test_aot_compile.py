@@ -1039,7 +1039,8 @@ def _todays_model(one_chip, which):
     """(model, its cache's shapes, whether it takes slots) at the cells'
     widths, two or three layers deep."""
     from mxnet_tpu.base import bfloat16
-    from mxnet_tpu.serving import ShortConvMoEKVModel, TransformerKVModel
+    from mxnet_tpu.serving import (HybridWindowMoEKVModel,
+                                   ShortConvMoEKVModel, TransformerKVModel)
 
     if which == "gpt2":
         model = TransformerKVModel(50257, 1024, num_layers=2, num_heads=20,
@@ -1051,13 +1052,24 @@ def _todays_model(one_chip, which):
                                     dtype=bfloat16)
         return model, (_bf16((1, 2, 513, 64, 512), one_chip),
                        _bf16((2, 65, 2, 2048), one_chip)), True
+    if which == "mimo":
+        model = HybridWindowMoEKVModel(
+            19072, 16384, (0, 1), (0, 1), 4096, 64, 4, 8, 192, 128, 64, 128,
+            16384, 2048, 256, (0, 16), 8, rope_theta=1e7,
+            window_rope_theta=1e4, value_scale=0.707, dtype=bfloat16)
+        pool = (1, 1, 257, 64)
+        return model, ((_bf16(pool + (768,), one_chip),
+                        _bf16(pool + (512,), one_chip)),
+                       (_bf16((1, 65, 128, 1536), one_chip),
+                        _bf16((1, 65, 128, 1024), one_chip))), True
     model = _latent_model(2)
     return model, _bf16((2, 513, 64, model.pool_width), one_chip), False
 
 
 #: digests of the programs (`_digest`): GPT-2's as the parent of the K/V
 #: pair pool's two widths lowered them, LFM2's and Kimi's as they are since
-#: their expert layers run as the kernel `expert_ffn`
+#: their expert layers run as the kernel `expert_ffn`, MiMo's as they were
+#: before the delta-rule model came beside it
 _TODAY = {
     ("gpt2", "decode", 8): "efc3ab88f2af9e8a",
     ("gpt2", "prefill", 512): "4e3bc85e130ec77e",
@@ -1065,20 +1077,134 @@ _TODAY = {
     ("lfm2", "prefill", 512): "edb25a7e4c3f7c0b",
     ("kimi", "decode", 8): "76270e14e208675d",
     ("kimi", "prefill", 512): "4345b5dda78e1134",
+    ("mimo", "decode", 8): "9134c9c04ff9a91e",
+    ("mimo", "prefill", 512): "68604b4472d61158",
 }
 
 
 @pytest.mark.parametrize("which,what,n", sorted(_TODAY))
 def test_the_other_models_lowered_programs_are_unchanged(
         on_tpu, one_chip, monkeypatch, which, what, n):
-    """GPT-2's, LFM2's and Kimi's decode and prefill programs, lowered for
-    the chip at their cells' widths, are the ones they were before the
-    decode kernel took K and V at two widths, the chunk path a pair, and
-    `rope` a rotary width (GPT-2's), and since the expert layers became one
-    kernel each (LFM2's and Kimi's): kernels and all."""
+    """GPT-2's, LFM2's, Kimi's and MiMo's decode and prefill programs,
+    lowered for the chip at their cells' widths, are the ones they were
+    before the decode kernel took K and V at two widths, the chunk path a
+    pair, and `rope` a rotary width (GPT-2's), since the expert layers
+    became one kernel each (LFM2's and Kimi's), and before the delta-rule
+    model's state came beside the pool (MiMo's): kernels and all."""
     from mxnet_tpu.ops.pallas_kernels import paged_attention
 
     monkeypatch.setattr(paged_attention, "_INTERPRET", False)
     model, cache, slots = _todays_model(one_chip, which)
     got = _digest(_lowered(one_chip, model, cache, what, n, slots))
     assert got == _TODAY[(which, what, n)], got
+
+
+# -- the delta-rule hybrid at the served widths --------------------------------
+
+
+def _delta_program(one_chip, what, n, n_blocks=257, slots=129):
+    """`serve_decode_b<n>` or `serve_prefill_s<n>` of the OLMo hybrid block
+    at its published widths, one linear layer and one full layer, tables of
+    5,120 positions, compiled for the described chip with (pool, state)
+    donated, with more slots than rows so that a copy of the state stands
+    out from the rows' own values: (its text, its memory analysis, the
+    model and the state's shapes)."""
+    from mxnet_tpu.base import bfloat16
+    from mxnet_tpu.serving import HybridDeltaKVModel
+
+    model = HybridDeltaKVModel(100352, 5120, ["linear_attention",
+                                              "full_attention"],
+                               3840, 30, 30, 30, 96, 192, 4, 11008,
+                               dtype=bfloat16)
+    params = {k: _bf16(v, one_chip) for k, v in model.param_shapes().items()}
+    conv, ssm = (jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+                 for a in jax.eval_shape(lambda: model.init_state(slots)))
+    cache = (_bf16((1, 2, n_blocks, 64, 3840), one_chip), (conv, ssm))
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    if what == "decode":
+        def prog(params, cache, token, pos, tables, slots):
+            logits, cache = model.decode_paged(params, cache, token, pos,
+                                               tables, slots=slots)
+            return jnp.argmax(logits, axis=-1), cache
+
+        args = (ints(n), ints(n), ints(n, 80), ints(n))
+    else:
+        def prog(params, cache, tokens, start, length, tables, slots):
+            logits, cache = model.prefill_paged(params, cache, tokens, start,
+                                                length, tables, slots=slots)
+            return jnp.argmax(logits, axis=-1), cache
+
+        args = (ints(1, n), ints(1), ints(1), ints(1, 80), ints(1))
+    prog.__name__ = "serve_%s_%s%d" % (what, "b" if what == "decode" else "s",
+                                       n)
+    comp = jax.jit(prog, donate_argnums=(1,)).lower(params, cache,
+                                                    *args).compile()
+    return comp.as_text(), comp.memory_analysis(), (model, conv, ssm)
+
+
+@pytest.mark.parametrize("batch", [8, 64])
+def test_the_delta_decode_updates_its_state_in_place(on_tpu, one_chip,
+                                                     monkeypatch, batch):
+    """A decode launch at Olmo-Hybrid's widths: the full layer attends with
+    one `paged_decode_attn` call at 30 heads of 128, the linear layer steps
+    its rows' slots with one `delta_decode_step` call; pool, conv tails and
+    the float32 state donated and aliased to the outputs; no value of the
+    program copies the state (a gather and scatter of its slots as slices,
+    or of its rows at 192 declared lanes, made the compiler copy the whole
+    of it) and the temporaries stay those of the batch's rows; the names
+    the `.olmo` metrics read are there."""
+    from mxnet_tpu.ops.pallas_kernels import paged_attention
+
+    monkeypatch.setattr(paged_attention, "_INTERPRET", False)
+    program = "serve_decode_b%d" % batch
+    text, memory, (model, conv, ssm) = _delta_program(one_chip, "decode",
+                                                      batch)
+    calls = [n for n in _op_names(text, program)
+             if n.endswith("/pallas_call")]
+    assert set(calls) == {"decode_attention/jit(_paged_decode)/"
+                          "paged_decode_attn/pallas_call",
+                          "delta_rule/jit(_step)/delta_decode_step/"
+                          "pallas_call"}
+    assert text.count("tpu_custom_call") == 2
+    assert "input_output_alias" in text.splitlines()[0]
+    pool_bytes = 2 * 257 * 64 * 3840 * 2
+    state_bytes = ssm.size * 4 + conv.size * 2
+    assert model.state_lanes == 256
+    assert state_bytes == 129 * model.state_slot_bytes()
+    # the conv tails' rows padded to whole tiles
+    assert 0 <= memory.alias_size_in_bytes - pool_bytes - state_bytes \
+        < conv.size * 2 // 16
+    # a copy of the state, whole or a layer's, would be a temporary of
+    # hundreds of MB
+    assert memory.temp_size_in_bytes < 64 * 1024 * 1024 < ssm.size
+    scopes = {part for n in _op_names(text, program)
+              for part in n.split("/")}
+    assert {"delta_rule", "linear_state", "short_conv", "qkv_proj",
+            "qk_norm", "kv_scatter", "attn_out", "ffn", "lm_head"} <= scopes
+
+
+def test_the_delta_prefill_walks_the_table_and_copies_no_state(on_tpu,
+                                                              one_chip):
+    """A chunk of 512 at Olmo-Hybrid's widths: K and V of a 5,120-position
+    table at 3,840 lanes (78.6 MB) exceed `paged_prefill_attn`'s VMEM, so
+    the full layer walks the table a step of 1,024 positions at a time
+    (`paged_chunk_stream`) and no value holds float32 scores of the chunk
+    against the table (what the table-wide gather would make); the chunked
+    rule runs as a loop under `delta_rule`; no value copies the state."""
+    program = "serve_prefill_s512"
+    text, memory, (model, conv, ssm) = _delta_program(one_chip, "prefill",
+                                                      512)
+    assert "input_output_alias" in text.splitlines()[0]
+    assert not _table_wide_scores(text, 512, 5120)
+    names = _op_names(text, program)
+    assert not any("kv_gather" in n or "pallas_call" in n for n in names)
+    assert any(n.startswith("chunk_attention/while") for n in names)
+    assert any(n.startswith("delta_rule/while") for n in names)
+    # no copy of the state (380 MB here): the temporaries are the chunk's
+    assert memory.temp_size_in_bytes < ssm.size * 4 // 3
+    scopes = {part for n in names for part in n.split("/")}
+    assert {"delta_rule", "linear_state", "short_conv", "chunk_attention",
+            "kv_scatter", "ffn", "lm_head"} <= scopes

@@ -17,9 +17,9 @@ import jax.numpy as jnp
 import mxnet_tpu as mx
 from mxnet_tpu import models
 from mxnet_tpu.parallel import SPMDTrainer, make_mesh
-from mxnet_tpu.serving import (HybridWindowMoEKVModel, LatentMoEKVModel,
-                               ServingEngine, ShortConvMoEKVModel,
-                               TransformerKVModel)
+from mxnet_tpu.serving import (HybridDeltaKVModel, HybridWindowMoEKVModel,
+                               LatentMoEKVModel, ServingEngine,
+                               ShortConvMoEKVModel, TransformerKVModel)
 
 SERVING_SCOPES = {"embed", "qkv_proj", "kv_scatter", "kv_gather", "attn_out",
                   "ffn", "lm_head", "sampler"}
@@ -217,6 +217,69 @@ def test_the_rings_attribute_and_counters_are_named(window_engine):
     assert reg.counter("serve.window_names.ring_resets").value \
         == before + 1
     assert reg.counter("serve.window_names.state_resets").value == 0
+
+
+DELTA_SCOPES = {"embed", "qkv_proj", "short_conv", "delta_rule",
+                "linear_state", "qk_norm", "kv_scatter", "attn_out", "ffn",
+                "lm_head", "sampler"}
+
+
+@pytest.fixture(scope="module")
+def delta_engine():
+    model = HybridDeltaKVModel(
+        61, 32, ["linear_attention", "full_attention", "linear_attention"],
+        32, 2, 2, 2, 8, 16, 4, 48, chunk_size=4)
+    return ServingEngine(model, model.init_params(np.random.RandomState(3)),
+                         max_batch=4, block_size=4, n_blocks=32,
+                         prefill_buckets=[8], decode_buckets=[2],
+                         name="delta_names")
+
+
+@pytest.mark.parametrize("build,module,attention", [
+    ("_compiled_decode", "jit_serve_decode_b2",
+     {"decode_attention", "kv_gather"}),
+    ("_compiled_prefill", "jit_serve_prefill_s8", {"chunk_attention"}),
+])
+def test_delta_programs_carry_their_name_and_scopes(delta_engine, build,
+                                                    module, attention):
+    """The fifth model class under the engine's own program names: what the
+    `.olmo` metrics' readers look for (`benchmark/metrics/*.olmo.json`):
+    the linear layers' `delta_rule` (the chunked form in prefill, the step
+    in decode), `linear_state` (the slots' read and write) and
+    `short_conv`, the full layers' `decode_attention` and
+    `chunk_attention` (a chunk walks the table in steps)."""
+    bucket = 8 if build == "_compiled_prefill" else 2
+    text = getattr(delta_engine, build)(bucket).as_text()
+    assert text.startswith("HloModule %s," % module)
+    scopes = _scopes(text)
+    assert scopes >= DELTA_SCOPES | attention
+    assert not {"rope", "moe_router"} & scopes
+
+
+def test_the_delta_state_slot_bytes_and_gauges_are_named(delta_engine):
+    """`state_slot_bytes` in the engine's stats (from the model),
+    `state_slots_live` on the `iteration` record and the `state_resets`
+    counter: what `state_slots_live_peak_share.olmo` reads and PERF.md's
+    memory accounting quotes."""
+    import time
+
+    from mxnet_tpu import telemetry, tracing
+
+    model = delta_engine.model
+    assert delta_engine.stats["state_slot_bytes"] \
+        == model.state_slot_bytes() > 0
+    reg = telemetry.registry()
+    before = reg.counter("serve.delta_names.state_resets").value
+    t0 = time.perf_counter()
+    req = delta_engine.submit([5, 6, 7, 8, 9], max_new_tokens=3)
+    delta_engine.run_until_idle(timeout=120)
+    assert len(req.result(timeout=5)) == 3
+    records = [r["attrs"] for r in tracing.window(
+        "delta_names", t0, time.perf_counter())
+        if r["phase"] == "iteration"]
+    assert records and all(a["state_slots_live"] == 1 for a in records)
+    assert any("ctx_blocks" in a for a in records)
+    assert reg.counter("serve.delta_names.state_resets").value == before + 1
 
 
 def test_latent_prefill_lowered_for_a_tpu_is_its_kernel(monkeypatch):
