@@ -55,6 +55,10 @@ class PipelineParallel:
         self.axis = axis
         self.num_stages = mesh.shape[axis]
         self.num_microbatches = num_microbatches or self.num_stages
+        # each traced and compiled once per input shape, sharding and dtype,
+        # not once per call; lr stays outside, so a new rate never recompiles
+        self.loss = jax.jit(self.loss)
+        self._value_and_grad = jax.jit(jax.value_and_grad(self.loss))
 
     def _forward_local(self, params, x_mb, labels_mb):
         """Runs inside shard_map: params are THIS stage's params (leading
@@ -130,7 +134,7 @@ class PipelineParallel:
     def grad_step(self, params_stacked, x, labels, lr=None):
         """value_and_grad through the schedule (the backward rotations are
         the transposed ppermutes XLA derives).  Optionally SGD-update."""
-        loss, grads = jax.value_and_grad(self.loss)(params_stacked, x, labels)
+        loss, grads = self._value_and_grad(params_stacked, x, labels)
         if lr is None:
             return loss, grads
         new_params = jax.tree.map(lambda p, g: p - lr * g.astype(p.dtype),

@@ -818,20 +818,17 @@ def test_sched_phases_reach_the_profilers_trace(model_and_params, tmp_path):
 # ---------------------------------------------------------------------------
 
 def _served_by_its_thread(eng, prompts, gap_s):
-    """Submit ``prompts`` to a start()ed engine ``gap_s`` apart, so that it
-    waits for work between them; returns the window's `iteration`
-    records."""
+    """Submit ``prompts`` to a start()ed engine one at a time, each
+    ``gap_s`` after the one before has been served, so that it waits for
+    work between them and no two share an iteration however slow the host
+    is; returns the window's `iteration` records."""
     eng.warmup()
     t0 = time.perf_counter()
     eng.start()
     try:
-        reqs = []
         for p in prompts:
-            reqs.append(eng.submit(p, max_new_tokens=4))
+            eng.submit(p, max_new_tokens=4).result(timeout=120)
             time.sleep(gap_s)
-        for r in reqs:
-            r.result(timeout=120)
-        time.sleep(0.1)          # waits, carried into no record
     finally:
         eng.stop()
     return [s for s in tracing.window(eng.name, t0, time.perf_counter())
@@ -854,8 +851,8 @@ def test_iteration_records_tile_the_schedulers_time(model_and_params):
     span_ms = 1e3 * (its[-1]["t1"] - its[0]["t1"])
     total = sum(sum(s["attrs"]["phase_ms"].values()) for s in its[1:])
     assert 0.9 * span_ms <= total <= span_ms + 1.0
-    # the engine waited between the arrivals (0.4 s, of which serving a
-    # request takes a few tens of ms here), and said so in the next
+    # the engine waited between the arrivals (0.2 s after each request
+    # was served, 0.4 s in all), and said so in the next
     # record; every launch was packed, launched, fetched and published
     waits = sum(s["attrs"]["phase_ms"]["wait"] for s in its[1:])
     assert waits >= 100.0
